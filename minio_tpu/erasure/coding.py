@@ -10,14 +10,17 @@ out over a thread pool with write-quorum accounting.
 Backend selection (reference analogue: MINIO_ERASURE_BACKEND in
 BASELINE.json's north star):
 - "host": C++ AVX2 PSHUFB codec (csrc/gf256_simd.cpp)
-- "tpu":  Pallas fused MXU kernel (ops/rs_pallas.py)
+- "tpu":  Pallas fused MXU kernel (ops/rs_pallas.py); constructing an
+  Erasure with it and no TPU attached raises
 - "mesh": multi-device jax.sharding.Mesh codec (parallel/mesh.py
   MeshRSCodec) — (B, K, S) batches shard over (blocks, shards) axes and
-  parity/heal come from ICI psum collectives; falls back to host when
-  fewer than 2 devices are visible or K does not divide the shards axis
+  parity/heal come from ICI psum collectives; raises when fewer than 2
+  devices are visible, host only when K does not divide the shards axis
 - "auto": TPU when a TPU is attached AND the span is big enough to
   amortise dispatch; host otherwise (small objects are latency-bound).
-Set via env MINIO_TPU_ERASURE_BACKEND.
+Set via env MINIO_TPU_ERASURE_BACKEND.  Which device there is comes from
+ops/device.info() and nowhere else; no caught exception ever turns a
+device dispatch into a host one.
 """
 
 from __future__ import annotations
@@ -31,13 +34,15 @@ from typing import BinaryIO, Sequence
 
 import numpy as np
 
-from minio_tpu.ops import gf256, hh_device, host
+from minio_tpu.ops import device, gf256, hh_device, host
 from minio_tpu.storage import errors
 from minio_tpu.utils.deadline import ctx_submit
+from minio_tpu.utils.logger import log
 from . import batcher as batcher_mod
 from . import stagestats
 
 BLOCK_SIZE_V2 = 1 << 20  # reference blockSizeV2, cmd/object-api-common.go:40
+BACKENDS = ("auto", "host", "tpu", "mesh")
 
 # Batch this many erasure blocks per device dispatch on the hot path.
 DEVICE_BATCH_BLOCKS = 32
@@ -190,48 +195,52 @@ def probe_verdicts() -> dict:
 
 
 class _DeviceCodec:
-    """Lazy singleton per (k, m): Pallas codec when a TPU is attached.
+    """Lazy singleton per (k, m): the Pallas codec of the attached TPU.
 
-    `get(k, m)` additionally runs a one-time calibration probe: the device
-    path is only selected for backend "auto" if a transfer-inclusive encode
-    actually beats the host codec on this machine.  A TPU reached over a
-    slow tunnel (high per-dispatch latency, low host<->device bandwidth)
-    loses the probe and the scheduler stays on the AVX2 host codec; a
-    co-located TPU wins it.  `get(k, m, probe=False)` (backend "tpu")
-    bypasses the verdict and always returns the codec when one exists.
+    Whether there is a TPU is `device.info()`'s answer and nothing
+    else: on platform "tpu" a codec that fails to build, compile or run
+    raises to the caller — no exception turns a device dispatch into a
+    host one.  `get(k, m)` (backend "auto") additionally runs a one-time
+    calibration probe and selects the device only if a transfer-inclusive
+    encode beats the host codec on this machine; the probe can lose on
+    time, never on an error.  `get(k, m, probe=False)` (backend "tpu")
+    bypasses the verdict and raises BackendUnavailable without a TPU.
     """
 
-    _cache: dict = {}  # (k, m) -> (codec | None, device_wins: bool)
+    _cache: dict = {}  # (k, m) -> (codec | None, device_wins: bool | None)
     _lock = threading.Lock()
 
     @classmethod
     def _probe(cls, codec, k: int, m: int) -> bool:
         """True if transfer-inclusive device encode beats the host codec."""
-        try:
-            host_codec = host.HostRSCodec(k, m)
-            shard = 128 * 1024
+        host_codec = host.HostRSCodec(k, m)
+        shard = 128 * 1024
 
-            def time_pair(nblocks: int) -> tuple[float, float]:
-                batch = np.zeros((nblocks, k, shard), dtype=np.uint8)
-                best_d = best_h = float("inf")
-                for _ in range(2):
-                    t0 = time.perf_counter()
-                    np.asarray(codec.encode(batch))
-                    best_d = min(best_d, time.perf_counter() - t0)
-                    t0 = time.perf_counter()
-                    host_codec.encode(batch)
-                    best_h = min(best_h, time.perf_counter() - t0)
-                return best_d, best_h
+        def time_pair(nblocks: int) -> tuple[float, float]:
+            batch = np.zeros((nblocks, k, shard), dtype=np.uint8)
+            best_d = best_h = float("inf")
+            for _ in range(2):
+                t0 = time.perf_counter()
+                np.asarray(codec.encode(batch))
+                best_d = min(best_d, time.perf_counter() - t0)
+                t0 = time.perf_counter()
+                host_codec.encode(batch)
+                best_h = min(best_h, time.perf_counter() - t0)
+            return best_d, best_h
 
-            dev_t, host_t = time_pair(8)
-            if dev_t > 4 * host_t:
-                return False
+        nblocks = 8
+        dev_t, host_t = time_pair(nblocks)
+        wins = dev_t <= 4 * host_t
+        if wins:
             # close call at 8 blocks: fixed dispatch latency may dominate;
             # re-probe at the steady-state batch size before deciding.
-            dev_t, host_t = time_pair(DEVICE_BATCH_BLOCKS)
-            return dev_t <= host_t
-        except Exception:
-            return False
+            nblocks = DEVICE_BATCH_BLOCKS
+            dev_t, host_t = time_pair(nblocks)
+            wins = dev_t <= host_t
+        log.info("erasure auto probe", config=f"{k}+{m}", blocks=nblocks,
+                 device_seconds=dev_t, host_seconds=host_t,
+                 verdict="device" if wins else "host")
+        return wins
 
     _mesh_cache: dict = {}  # (k, m) -> MeshRSCodec | None
 
@@ -240,22 +249,23 @@ class _DeviceCodec:
         """Multi-device mesh codec (backend "mesh"): shards (B, K, S)
         batches over a jax.sharding.Mesh (parallel/mesh.py), replacing the
         reference's per-drive goroutine fan-out with ICI collectives.
-        None when fewer than 2 devices are visible or K does not divide
-        over the shards axis (callers fall back to the host codec)."""
+        Raises BackendUnavailable when fewer than 2 devices are visible;
+        None when K does not divide over the shards axis (a geometry the
+        mesh cannot lay out — callers use the host codec for it)."""
         with cls._lock:
             key = (k, m)
             if key not in cls._mesh_cache:
-                codec = None
-                try:
-                    import jax
+                dev = device.info()
+                if dev.count < 2:
+                    raise device.BackendUnavailable(
+                        "erasure backend 'mesh' needs at least 2 devices; "
+                        f"JAX found {dev.count} x {dev.platform}")
+                from minio_tpu.parallel import mesh as pmesh
 
-                    from minio_tpu.parallel import mesh as pmesh
-
-                    if len(jax.devices()) > 1:
-                        codec = pmesh.MeshRSCodec(k, m)
-                except Exception:
-                    codec = None
-                cls._mesh_cache[key] = codec
+                mesh = pmesh.make_mesh()
+                cls._mesh_cache[key] = (
+                    pmesh.MeshRSCodec(k, m, mesh)
+                    if k % mesh.shape["shards"] == 0 else None)
             return cls._mesh_cache[key]
 
     @classmethod
@@ -264,19 +274,17 @@ class _DeviceCodec:
             key = (k, m)
             if key not in cls._cache:
                 codec = None
-                try:
-                    import jax
+                if device.info().platform == "tpu":
                     from minio_tpu.ops import rs_pallas
 
-                    if jax.default_backend() != "cpu":
-                        codec = rs_pallas.PallasRSCodec(k, m)
-                except Exception:
-                    codec = None
+                    codec = rs_pallas.PallasRSCodec(k, m)
                 # verdict computed lazily on the first probe=True caller;
                 # backend="tpu" callers never pay for it
                 cls._cache[key] = (codec, None)
             codec, wins = cls._cache[key]
             if not probe:
+                if codec is None:
+                    device.require_tpu("erasure backend 'tpu'")
                 return codec
             if codec is None:
                 return None
@@ -285,6 +293,19 @@ class _DeviceCodec:
                 wins = cls._probe(codec, k, m)
                 cls._cache[key] = (codec, wins)
             return codec if wins else None
+
+
+def steady_state_backend(k: int, m: int,
+                         block_size: int = BLOCK_SIZE_V2) -> str:
+    """"device" | "mesh" | "host": where a full batch of this geometry's
+    blocks is coded under the configured backend — the dispatch
+    encode_stream, degraded reads and heal make in steady state.  Under
+    "auto" on a TPU this runs the calibration probe.  A geometry whose
+    shard length the device kernel cannot tile (12+4: ceil(1 MiB / 12)
+    is not a multiple of 8192) answers "host" on any backend but mesh."""
+    e = Erasure(k, m, block_size)
+    return _backend_name(
+        e._device(block_size * DEVICE_BATCH_BLOCKS, e.shard_size))
 
 
 class _PaddedCodec:
@@ -332,6 +353,16 @@ class Erasure:
         self.backend = backend or os.environ.get(
             "MINIO_TPU_ERASURE_BACKEND", "auto"
         )
+        if self.backend not in BACKENDS:
+            raise errors.InvalidArgument(
+                f"unknown erasure backend {self.backend!r} "
+                f"(one of {', '.join(BACKENDS)})")
+        # an explicit device backend without its device is an error
+        # here, at construction — never a host run under its name
+        if self.m and self.backend == "tpu":
+            _DeviceCodec.get(self.k, self.m, probe=False)
+        elif self.m and self.backend == "mesh":
+            _DeviceCodec.get_mesh(self.k, self.m)
         # erasure-set id of the caller: the request batcher lays tick
         # batches out set-major so the mesh shards them by erasure set
         self.set_id = set_id
@@ -393,7 +424,13 @@ class Erasure:
                     return _PaddedCodec(codec, self.shard_size)
                 return None
             return codec
-        if shard_len % 8192 != 0:
+        # The single-chip kernel tiles shards in 8 KiB columns, and only
+        # a geometry's full-width shards go to it: a tail block or an
+        # inline object is one sub-MiB dispatch that cannot amortise a
+        # round trip, and every distinct shard length is another
+        # compile.  (Under "auto" DEVICE_MIN_BYTES already keeps those on
+        # the host; this makes "tpu" agree.)
+        if shard_len != self.shard_size or shard_len % 8192 != 0:
             return None
         if self.backend == "tpu":
             return _DeviceCodec.get(self.k, self.m, probe=False)

@@ -3,9 +3,8 @@
 Every stage of the PUT/GET pipeline (stream read, etag folding, erasure
 encode, bitrot hash, shard write, shard decode, response hand-off) folds
 its elapsed seconds in here, so the remaining gap between codec speed and
-client-visible throughput is attributable instead of argued about
-(BENCH_r05 showed a 5-7x codec-vs-e2e gap with no way to say where it
-went).  Exposed as `minio_dataplane_stage_seconds_total{stage=...}` by
+client-visible throughput is attributable instead of argued about.
+Exposed as `minio_dataplane_stage_seconds_total{stage=...}` by
 server/metrics.py and consumed by bench.py's object-layer breakdown.
 
 Stages overlap by design (the hasher folds batch N while the main thread

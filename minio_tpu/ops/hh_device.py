@@ -41,6 +41,7 @@ import os
 
 import numpy as np
 
+from . import device
 from .host import MAGIC_HH256_KEY
 
 __all__ = [
@@ -590,7 +591,7 @@ class Md5Fold:
 def fused_etag_available() -> bool:
     """Should put_data skip the hash-lane process and fold MD5 inline?
 
-    True when the fused-hash gate is on AND either a non-CPU device is
+    True when the fused-hash gate is on AND either a TPU is
     present (the fold rides the accelerator next to the fused tick
     program) or MINIO_TPU_FUSED_ETAG=1 forces it (tests / CPU
     validation).  MINIO_TPU_FUSED_ETAG=0 force-disables regardless.
@@ -602,8 +603,4 @@ def fused_etag_available() -> bool:
         return False
     if forced == "1":
         return True
-    try:
-        jax, _ = _jx()
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:
-        return False
+    return device.info().platform == "tpu"

@@ -7,25 +7,28 @@ Provides:
 - hh256 / HH256: bit-exact HighwayHash-256 for bitrot checksums
   (reference: minio/highwayhash used at cmd/bitrot.go:55).
 
-The library is built on first use (make -C csrc) if missing; pure-numpy
-fallbacks keep everything functional without a compiler.
+The library is built from the committed sources on the machine that
+loads it, on first use, into a file named by a content hash of those
+sources (`lib_path`), so a stale build or one made for another CPU is
+never opened.  Pure-numpy fallbacks keep tests functional without a
+compiler; `available()` says which codec a process has.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
 
 from . import gf256
 
-_CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "csrc")
-# sanitizer harness hook: load an alternate (asan/ubsan/tsan) build
-_LIBPATH = os.environ.get("MINIO_TPU_NATIVE_LIB") or os.path.join(
-    _CSRC, "libminio_tpu_host.so")
+_CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "csrc")
 _lock = threading.Lock()
 _lib = None
 _lib_tried = False
@@ -38,6 +41,48 @@ MAGIC_HH256_KEY = bytes(
 )
 
 
+def _lib_name() -> str:
+    """File name of the host library for the sources as they are now: a
+    content hash of csrc/*.cpp, *.h and the Makefile, so a changed
+    source or flag is a different file."""
+    digest = hashlib.sha256()
+    for src in sorted(os.listdir(_CSRC)):
+        if src.endswith((".cpp", ".h")) or src == "Makefile":
+            digest.update(src.encode() + b"\0")
+            with open(os.path.join(_CSRC, src), "rb") as f:
+                digest.update(f.read())
+    return f"libminio_tpu_host-{digest.hexdigest()[:16]}.so"
+
+
+def lib_path() -> str | None:
+    """Path of the host library for this checkout's sources, built here
+    if it is not there yet; None when it cannot be built.
+
+    The one rule every loader shares (this module, select/native.py,
+    tests/conftest.py).  MINIO_TPU_NATIVE_LIB (sanitizer harness: an
+    asan/ubsan/tsan build) overrides it."""
+    override = os.environ.get("MINIO_TPU_NATIVE_LIB")
+    if override:
+        return override
+    name = _lib_name()
+    path = os.path.join(_CSRC, name)
+    if not os.path.exists(path):
+        try:
+            proc = subprocess.run(
+                ["make", "-C", _CSRC, "-s", f"LIB={name}"],
+                capture_output=True, text=True, timeout=600)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            print(f"minio-tpu: native library build did not run: {e}",
+                  file=sys.stderr)
+            return None
+        if proc.returncode != 0:
+            print(f"minio-tpu: native library build failed "
+                  f"(make rc={proc.returncode}):\n{proc.stderr}",
+                  file=sys.stderr)
+            return None
+    return path
+
+
 def _load():
     # lint: allow(shared-state): per-process ctypes handle by design — each worker process must dlopen the codec itself
     global _lib, _lib_tried
@@ -45,30 +90,23 @@ def _load():
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        if not os.path.exists(_LIBPATH):
-            try:
-                # lint: allow(blocking-under-lock): one-time native build under the dedicated dlopen lock — the lock exists to serialize exactly this init
-                subprocess.run(
-                    ["make", "-C", _CSRC, "-s"], check=True, capture_output=True
-                )
-            except Exception:
-                return None
+        # lint: allow(blocking-under-lock): one-time native build under the dedicated dlopen lock — the lock exists to serialize exactly this init
+        path = lib_path()
+        if path is None:
+            return None
         try:
-            lib = ctypes.CDLL(_LIBPATH)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         lib.gf256_matmul.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
             ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t,
         ]
-        try:
-            lib.gf256_matmul_batch.argtypes = [
-                ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
-                ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t,
-                ctypes.c_size_t,
-            ]
-        except AttributeError:  # older build without the batched entry
-            pass
+        lib.gf256_matmul_batch.argtypes = [
+            ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t,
+            ctypes.c_size_t,
+        ]
         lib.hh256_state_size.restype = ctypes.c_int
         lib.hh256_init.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
         lib.hh256_update.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t]
@@ -141,14 +179,12 @@ class HostRSCodec:
         """(B, K, S) x mat -> (B, rows, S) in ONE C call (GIL released
         once for the whole batch; `out` writes parity in place, skipping
         a per-block copy).  Falls back to the per-block path without the
-        batched symbol or the native library."""
+        native library."""
         b, k, s = src.shape
         rows = mat.shape[0]
         if out is None:
             out = np.empty((b, rows, s), dtype=np.uint8)
-        if (self._lib is not None
-                and hasattr(self._lib, "gf256_matmul_batch")
-                and out.flags["C_CONTIGUOUS"]):
+        if self._lib is not None and out.flags["C_CONTIGUOUS"]:
             src = np.ascontiguousarray(src, dtype=np.uint8)
             self._lib.gf256_matmul_batch(
                 _as_c(np.ascontiguousarray(mat)), rows, k, _as_c(src),

@@ -30,10 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 from minio_tpu.ops import residency, rs_tpu
 

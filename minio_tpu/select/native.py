@@ -34,6 +34,8 @@ from typing import Iterator
 
 import numpy as np
 
+from minio_tpu.ops import host
+
 from . import eventstream as es
 from .records import _decomp
 from .sql import (AGGREGATES, Between, Bin, Cast, Col, Evaluator, Func,
@@ -59,13 +61,6 @@ _FN_CODES = {"lower": 1, "upper": 2, "trim": 3, "ltrim": 4, "rtrim": 5,
              "char_length": 6, "length": 6, "character_length": 6}
 _FN_SUBSTR = 7
 
-_CSRC = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "csrc")
-# MINIO_TPU_NATIVE_LIB points the loader at an alternate build of the
-# host library — the sanitizer harness uses it to swap in the
-# asan/ubsan/tsan variants (csrc/Makefile `make asan` etc.)
-_LIBPATH = os.environ.get("MINIO_TPU_NATIVE_LIB") or os.path.join(
-    _CSRC, "libminio_tpu_host.so")
 _lock = threading.Lock()
 _lib = None
 _lib_tried = False
@@ -82,8 +77,13 @@ def _load():
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
+        # the same file ops/host.py opens: one build rule, one library
+        # lint: allow(blocking-under-lock): one-time native build under the dedicated dlopen lock — the lock exists to serialize exactly this init
+        path = host.lib_path()
+        if path is None:
+            return None
         try:
-            lib = ctypes.CDLL(_LIBPATH)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         lib.sel_csv_scan.restype = _i64

@@ -95,6 +95,51 @@ def bitrot_self_test() -> None:
             "bitrot self-test failed: HighwayHash-256 checksum mismatch")
 
 
+def device_self_test(k: int, m: int, block_size: int) -> float:
+    """Encode and reconstruct through the device codec on the chip, at
+    the shape a deployment's steady state dispatches — one batch of
+    DEVICE_BATCH_BLOCKS blocks — and compare with the gf256 oracle.
+
+    The device half of the boot self-test: a kernel that fails to
+    compile, or computes wrong bytes, must stop the server as a broken
+    host codec does.  It is also the warm-up: the encode program and the
+    reconstruct programs for 1..m lost shards are compiled (or read from
+    the persistent cache) here, not inside the first request.  Returns
+    the seconds it took.  The caller has established that this geometry
+    dispatches to the device."""
+    import time
+
+    import numpy as np
+
+    from minio_tpu.erasure import coding
+    from minio_tpu.ops import gf256
+
+    t0 = time.perf_counter()
+    codec = coding._DeviceCodec.get(k, m, probe=False)
+    b, s = coding.DEVICE_BATCH_BLOCKS, block_size // k
+    batch = np.random.default_rng(k * 256 + m).integers(
+        0, 256, size=(b, k, s), dtype=np.uint8)
+    parity = np.asarray(codec.encode(batch))
+    flat = np.ascontiguousarray(batch.transpose(1, 0, 2)).reshape(k, b * s)
+    want = gf256.encode_np(flat, m).reshape(m, b, s).transpose(1, 0, 2)
+    if not np.array_equal(parity, want):
+        raise SelfTestError(
+            f"device erasure self-test failed for {k}+{m}: parity from "
+            f"the device differs from the gf256 oracle")
+    full = np.concatenate([batch, parity], axis=1)
+    for lost in range(1, m + 1):
+        wanted = tuple(range(lost))
+        avail = tuple(range(lost, lost + k))
+        rebuilt = np.asarray(codec.reconstruct(
+            np.ascontiguousarray(full[:, lost:lost + k]), avail, wanted))
+        if not np.array_equal(rebuilt, batch[:, :lost]):
+            raise SelfTestError(
+                f"device erasure self-test failed for {k}+{m}: "
+                f"reconstructing {lost} lost shard(s) on the device does "
+                f"not round-trip")
+    return time.perf_counter() - t0
+
+
 def run_self_tests() -> None:
     erasure_self_test()
     bitrot_self_test()

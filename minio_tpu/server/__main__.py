@@ -34,6 +34,8 @@ def _prefork_http_front(n: int, argv) -> int:
     parallelize across interpreters (the kernel load-balances new
     connections).  Worker 0 runs the background services; the rest
     start with --no-services so one node never runs N scanners.
+    Worker 0 also owns the chip — a TPU belongs to one process — so the
+    rest are pinned to the host codec before they import anything.
     Children are supervised: a died worker is reforked, SIGTERM/SIGINT
     fan out and the parent waits for a clean drain.
 
@@ -56,6 +58,8 @@ def _prefork_http_front(n: int, argv) -> int:
             signal.signal(signal.SIGTERM, signal.SIG_DFL)
             signal.signal(signal.SIGINT, signal.SIG_DFL)
             os.environ["_MINIO_TPU_HTTP_WORKER"] = str(i)
+            if i > 0:
+                os.environ["MINIO_TPU_ERASURE_BACKEND"] = "host"
             child_argv = list(argv) if argv is not None else sys.argv[1:]
             if i > 0 and "--no-services" not in child_argv:
                 child_argv = child_argv + ["--no-services"]
@@ -95,6 +99,47 @@ def _prefork_http_front(n: int, argv) -> int:
         except OSError:
             pass
     return 0
+
+
+def _init_device(backend: str):
+    """Initialise JAX for a backend that may use a device and say what
+    it found (ops/device.DeviceInfo); None for backend "host", which
+    never touches JAX.  Raises BackendUnavailable when the backend names
+    a device that is not there — the server refuses to boot instead of
+    serving from the host codec under a device backend's name."""
+    from minio_tpu.ops import device
+
+    if backend == "host":
+        return None
+    device.enable_compile_cache()
+    if backend == "tpu":
+        return device.require_tpu("MINIO_TPU_ERASURE_BACKEND=tpu")
+    return device.info()
+
+
+def _boot_erasure_plane(pools) -> dict:
+    """Resolve, per set geometry the deployment can write (STANDARD and
+    REDUCED_REDUNDANCY parity of every pool), where steady-state batches
+    are coded, and run the device self-test + warm-up for each geometry
+    that reaches the chip.  Returns {"geometry": {"k+m": where},
+    "deviceSelfTestSeconds": float} for the banner and admin info."""
+    from minio_tpu.erasure import coding
+    from minio_tpu.erasure.objects import PutObjectOptions
+    from minio_tpu.selftest import device_self_test
+
+    geoms = set()
+    for pool in pools.pools:
+        for es in pool.sets:
+            for sc in ("STANDARD", "REDUCED_REDUNDANCY"):
+                parity = es._parity_for(PutObjectOptions(storage_class=sc))
+                if parity:
+                    geoms.add((len(es.disks) - parity, parity))
+    where, seconds = {}, 0.0
+    for k, m in sorted(geoms):
+        where[f"{k}+{m}"] = coding.steady_state_backend(k, m)
+        if where[f"{k}+{m}"] == "device":
+            seconds += device_self_test(k, m, coding.BLOCK_SIZE_V2)
+    return {"geometry": where, "deviceSelfTestSeconds": round(seconds, 3)}
 
 
 def main(argv=None) -> int:
@@ -156,14 +201,25 @@ def main(argv=None) -> int:
 
     from aiohttp import web
 
+    import time
+
     from minio_tpu.distributed.node import ClusterNode
+    from minio_tpu.ops import host as host_codec
+    from minio_tpu.ops.device import BackendUnavailable
     from minio_tpu.selftest import SelfTestError, run_self_tests
 
     # refuse to serve IO with a broken codec/hash (reference
-    # erasureSelfTest/bitrotSelfTest fatal at boot)
+    # erasureSelfTest/bitrotSelfTest fatal at boot), and refuse a device
+    # backend whose device is not there
+    backend = os.environ.get("MINIO_TPU_ERASURE_BACKEND", "auto")
     try:
+        t0 = time.perf_counter()
         run_self_tests()
-    except SelfTestError as e:
+        t1 = time.perf_counter()
+        dev = _init_device(backend) if args.gateway is None else None
+        boot = {"hostSelfTestSeconds": round(t1 - t0, 3),
+                "jaxInitSeconds": round(time.perf_counter() - t1, 3)}
+    except (SelfTestError, BackendUnavailable) as e:
         print(f"minio-tpu: FATAL: {e}", file=sys.stderr)
         return 1
 
@@ -255,6 +311,27 @@ def main(argv=None) -> int:
         f"minio-tpu: {mode}, {len(node.local_drives)} local drives, "
         f"{len(pools_info)} pool(s) [{layout} drives], "
         f"S3 on http://{args.address}", file=sys.stderr,
+    )
+    try:
+        boot.update(_boot_erasure_plane(node.pools))
+    except (SelfTestError, BackendUnavailable) as e:
+        print(f"minio-tpu: FATAL: {e}", file=sys.stderr)
+        node.close()
+        return 1
+    node.s3.erasure_boot = boot
+    geometry = ", ".join(f"{g} -> {w}" for g, w in boot["geometry"].items())
+    if dev is not None:
+        on = f"{dev.count} x {dev.platform} ({dev.kind})"
+    elif os.environ.get("_MINIO_TPU_HTTP_WORKER", "0") != "0":
+        on = "no device (HTTP worker 0 owns the chip)"
+    else:
+        on = "no device (JAX not initialised)"
+    print(
+        f"minio-tpu: erasure backend {backend} on {on}: {geometry}; "
+        f"host codec "
+        f"{'native AVX2' if host_codec.available() else 'numpy fallback'}; "
+        f"device self-test + warm-up {boot['deviceSelfTestSeconds']} s",
+        file=sys.stderr,
     )
     if node.distributed:
         # peers may still be starting: retry bootstrap verification in the

@@ -1167,6 +1167,39 @@ class AdminMixin:
                           "background services are not running")
         return svcs
 
+    def _erasure_info(self) -> dict:
+        """Erasure codec backend: configured backend, the device JAX
+        found, per-backend dispatch/byte counters, auto-probe verdicts —
+        so an operator can tell which codec their PUTs actually use."""
+        from minio_tpu.erasure import coding as ec
+        from minio_tpu.ops import device, host as host_codec
+
+        backend = os.environ.get("MINIO_TPU_ERASURE_BACKEND", "auto")
+        out = {
+            "backend": backend,
+            "hostCodec": "native" if host_codec.available() else "numpy",
+            "dispatch": {k: dict(v)
+                         for k, v in ec.backend_stats.items()},
+            "deviceProbe": ec.probe_verdicts(),
+        }
+        if backend != "host":
+            # a host-pinned process never initialises JAX: the chip
+            # belongs to the one process that serves from it
+            dev = device.info()
+            out.update({
+                "platform": dev.platform,
+                "deviceKind": dev.kind,
+                "deviceCount": dev.count,
+                "peakBytesInUse": device.peak_bytes_in_use(),
+                "compileCacheDir": device.compile_cache_dir(),
+            })
+        # what the CLI resolved at boot (per-geometry codec, self-test
+        # and warm-up seconds); absent under an in-process harness
+        boot = getattr(self, "erasure_boot", None)
+        if boot is not None:
+            out["boot"] = boot
+        return out
+
     # ---------------------------------------------------------------- info
     async def admin_info(self, request: web.Request, body: bytes):
         si = await self._run(self.api.storage_info)
@@ -1199,18 +1232,9 @@ class AdminMixin:
 
         if isinstance(self.api, CacheLayer):
             info["cache"] = self.api.stats()
-        # erasure codec backend: configured backend, per-backend
-        # dispatch/byte counters, auto-probe verdicts — so an operator
-        # can tell which codec their PUTs actually use
-        from minio_tpu.erasure import coding as ec
-
-        info["erasure"] = {
-            "backend": os.environ.get("MINIO_TPU_ERASURE_BACKEND",
-                                      "auto"),
-            "dispatch": {k: dict(v)
-                         for k, v in ec.backend_stats.items()},
-            "deviceProbe": ec.probe_verdicts(),
-        }
+        # off the loop: the first caller may build the host library or
+        # initialise JAX
+        info["erasure"] = await self._run(self._erasure_info)
         # per-tenant QoS live stats (ISSUE 13): the health/admin view
         # of who is queued, admitted, shed and throttled right now
         qos = getattr(self, "qos", None)

@@ -65,11 +65,12 @@ def _run_select(expr, data, inp, out, tier):
 
 
 def _require_native() -> None:
+    from minio_tpu.ops import host
     from minio_tpu.select import native
 
     if native._load() is None:
         print("san_replay: native library failed to load "
-              f"({native._LIBPATH}); nothing to sanitize", file=sys.stderr)
+              f"({host.lib_path()}); nothing to sanitize", file=sys.stderr)
         sys.exit(3)
 
 

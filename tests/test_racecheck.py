@@ -502,9 +502,15 @@ class TestReplayDrills:
         bare = rc.TracedDict("services.georep.stats", {"pushed_objects": 0})
         monkeypatch.setattr(georep, "stats", bare)
 
+        both_alive = threading.Barrier(2)
+
         def racy():
+            # a thread that ends before the other starts hands it its
+            # ident, and the detector then sees one thread
+            both_alive.wait(30)
             for _ in range(200):
                 georep.stats["pushed_objects"] += 1
+            both_alive.wait(30)
 
         _run_threads(racy, racy)
         assert "services.georep.stats" in _keys(rc.TRACKER.findings()), (

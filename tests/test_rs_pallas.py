@@ -15,7 +15,7 @@ def _rand(b, k, s, seed=0):
 @pytest.mark.parametrize("k,m", [(2, 2), (4, 2), (8, 4)])
 def test_pallas_encode_matches_numpy(k, m):
     shards = _rand(2, k, S)
-    codec = rs_pallas.PallasRSCodec(k, m)
+    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
     got = np.asarray(codec.encode(shards))
     for b in range(2):
         np.testing.assert_array_equal(got[b], gf256.encode_np(shards[b], m))
@@ -24,7 +24,7 @@ def test_pallas_encode_matches_numpy(k, m):
 def test_pallas_encode_words_matches_bytes():
     k, m = 4, 2
     shards = _rand(1, k, S, seed=3)
-    codec = rs_pallas.PallasRSCodec(k, m)
+    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
     words = np.ascontiguousarray(shards).view(np.int32).reshape(1, k, S // 4)
     got_w = np.asarray(codec.encode_words(words)).view(np.uint8).reshape(1, m, S)
     got_b = np.asarray(codec.encode(shards))
@@ -34,7 +34,7 @@ def test_pallas_encode_words_matches_bytes():
 def test_pallas_reconstruct():
     k, m = 8, 4
     data = _rand(2, k, S, seed=5)
-    codec = rs_pallas.PallasRSCodec(k, m)
+    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
     full = np.asarray(codec.encode_blocks(data))
     kill = (0, 3, 8, 11)
     avail = tuple(i for i in range(k + m) if i not in kill)
@@ -44,8 +44,18 @@ def test_pallas_reconstruct():
         np.testing.assert_array_equal(reb[:, j], full[:, idx], err_msg=f"shard {idx}")
 
 
-def test_pallas_rejects_unaligned():
+def test_pallas_does_not_interpret_unasked():
+    """Without interpret=True the codec is the Mosaic kernel or nothing:
+    on a box with no TPU it must raise, never quietly run interpreted
+    under a device codec's name."""
     codec = rs_pallas.PallasRSCodec(4, 2)
+    assert codec._interpret is False
+    with pytest.raises(ValueError, match="interpret mode"):
+        np.asarray(codec.encode(_rand(1, 4, S)))
+
+
+def test_pallas_rejects_unaligned():
+    codec = rs_pallas.PallasRSCodec(4, 2, interpret=True)
     with pytest.raises(ValueError):
         codec.encode(_rand(1, 4, 1000))
 
@@ -53,7 +63,7 @@ def test_pallas_rejects_unaligned():
 def test_flat_encode_matches_numpy():
     k, m = 8, 4
     shards = _rand(1, k, S, seed=7)[0]  # (k, S)
-    codec = rs_pallas.PallasRSCodec(k, m)
+    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
     words = np.ascontiguousarray(shards).view(np.int32).reshape(k, S // 4)
     got = np.asarray(codec.encode_flat(words)).view(np.uint8).reshape(m, S)
     np.testing.assert_array_equal(got, gf256.encode_np(shards, m))
@@ -64,7 +74,7 @@ def test_flat_seed_zero_is_identity_and_seeded_differs():
 
     k, m = 4, 2
     shards = _rand(1, k, S, seed=9)[0]
-    codec = rs_pallas.PallasRSCodec(k, m)
+    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
     words = np.ascontiguousarray(shards).view(np.int32).reshape(k, S // 4)
     base = np.asarray(codec.encode_flat(words))
     seeded = np.asarray(
@@ -88,7 +98,7 @@ def test_flat_seed_zero_is_identity_and_seeded_differs():
 def test_flat_reconstruct():
     k, m = 8, 4
     data = _rand(1, k, S, seed=11)
-    codec = rs_pallas.PallasRSCodec(k, m)
+    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
     full = np.asarray(codec.encode_blocks(data))[0]  # (k+m, S)
     kill = (1, 5, 9)
     avail = tuple(i for i in range(k + m) if i not in kill)
