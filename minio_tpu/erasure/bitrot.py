@@ -226,16 +226,9 @@ class BitrotReader:
             self.r.seek(file_off)
             self._pos = offset
 
-    def read_blocks(self, offset: int, nblocks: int, block_len: int) -> np.ndarray:
-        """Read + verify `nblocks` frames of `block_len` logical bytes each
-        starting at logical `offset` in ONE file read and ONE batched hash
-        call, returning a (nblocks, block_len) uint8 view into the frame
-        buffer (rows strided past the interleaved hashes — zero extra
-        copies).  block_len == shard_size except for a stream's final
-        short block (then nblocks must be 1)."""
+    def _read_frames(self, offset: int, want: int) -> bytearray | bytes:
+        """`want` bytes of [hash|block] frames from logical `offset`."""
         self._seek_to(offset)
-        frame = self._hsize + block_len
-        want = nblocks * frame
         # fill a preallocated frame buffer via readinto when the source
         # supports it (one copy straight off the O_DIRECT staging buffer
         # or socket); read()-only streams (remote RPC shards) wrap the
@@ -271,24 +264,38 @@ class BitrotReader:
             got = len(raw)
         if got != want:
             raise errors.FileCorrupt("bitrot: truncated frame group")
+        return raw
+
+    def read_blocks(self, offset: int, nblocks: int, block_len: int) -> np.ndarray:
+        """Read + verify `nblocks` frames of `block_len` logical bytes each
+        starting at logical `offset` in ONE file read and ONE batched hash
+        call, returning a (nblocks, block_len) uint8 view into the frame
+        buffer (rows strided past the interleaved hashes — zero extra
+        copies).  block_len == shard_size except for a stream's final
+        short block (then nblocks must be 1)."""
+        frame = self._hsize + block_len
+        want = nblocks * frame
+        with stagestats.timed("shard_read", want):
+            raw = self._read_frames(offset, want)
         arr = np.frombuffer(raw, dtype=np.uint8).reshape(nblocks, frame)
         hashes = arr[:, : self._hsize]
         blocks = arr[:, self._hsize:]
-        try:
-            batched = (
-                host.hh256_batch(blocks)
-                if self.algo in ("highwayhash256S", "highwayhash256")
-                else None
-            )
-        except RuntimeError:
-            batched = None
-        if batched is not None:
-            ok = np.array_equal(batched, hashes)
-        else:
-            ok = all(
-                self._hash(blocks[i].data) == hashes[i].tobytes()
-                for i in range(nblocks)
-            )
+        with stagestats.timed("verify", nblocks * block_len):
+            try:
+                batched = (
+                    host.hh256_batch(blocks)
+                    if self.algo in ("highwayhash256S", "highwayhash256")
+                    else None
+                )
+            except RuntimeError:
+                batched = None
+            if batched is not None:
+                ok = np.array_equal(batched, hashes)
+            else:
+                ok = all(
+                    self._hash(blocks[i].data) == hashes[i].tobytes()
+                    for i in range(nblocks)
+                )
         if not ok:
             raise errors.FileCorrupt("bitrot: hash mismatch")
         self._pos = offset + nblocks * block_len

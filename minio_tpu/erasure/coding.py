@@ -488,8 +488,19 @@ class Erasure:
         dev = self._device(batch.nbytes, s)
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
-            return np.asarray(dev.encode(batch))
-        return self._host.encode(batch)
+            out = dev.encode(batch)
+            with stagestats.timed("fetch", b * self.m * s):
+                return np.asarray(out)
+        with stagestats.timed("host_codec", batch.nbytes):
+            return self._host.encode(batch)
+
+    def _host_encode(self, batch: np.ndarray,
+                     out: np.ndarray | None = None) -> np.ndarray:
+        """The host codec's encode inside the streaming pipeline: booked
+        as `encode`, the stage, and as its leaf `host_codec`."""
+        with stagestats.timed("encode", batch.nbytes), \
+                stagestats.timed("host_codec", batch.nbytes):
+            return self._host.encode(batch, out=out)
 
     def _encode_shards(self, batch: np.ndarray) -> np.ndarray:
         """(B, K, S) -> (B, M, S) parity, coalesced across concurrent
@@ -530,7 +541,8 @@ class Erasure:
             out = dev.encode(batch)
 
             def resolve_dev():
-                arr = np.asarray(out)
+                with stagestats.timed("fetch", b * self.m * s):
+                    arr = np.asarray(out)
                 stagestats.add("encode", time.perf_counter() - t0,
                                batch.nbytes)
                 return arr
@@ -548,10 +560,9 @@ class Erasure:
             step = -(-b // nshards)
 
             def enc_range(lo: int, hi: int) -> None:
-                with stagestats.timed("encode", (hi - lo) * k * s):
-                    # one batched C call per shard: parity lands in
-                    # place, the GIL is released for the whole span
-                    self._host.encode(batch[lo:hi], out=parity[lo:hi])
+                # one batched C call per shard: parity lands in place,
+                # the GIL is released for the whole span
+                self._host_encode(batch[lo:hi], parity[lo:hi])
 
             futs = [ctx_submit(pool, enc_range, lo, min(lo + step, b))
                     for lo in range(0, b, step)]
@@ -563,13 +574,8 @@ class Erasure:
 
             return resolve_host
         if pool is not None:
-            def run_host():
-                with stagestats.timed("encode", batch.nbytes):
-                    return self._host.encode(batch)
-
-            return ctx_submit(pool, run_host).result
-        with stagestats.timed("encode", batch.nbytes):
-            out = self._host.encode(batch)
+            return ctx_submit(pool, self._host_encode, batch).result
+        out = self._host_encode(batch)
         return lambda: out
 
     # -- fused encode + frame-hash plane (MINIO_TPU_FUSED_HASH) -------------
@@ -592,6 +598,12 @@ class Erasure:
             return None
         return self._device(nbytes, shard_len)
 
+    def _fused_launch(self, batch: np.ndarray):
+        """The fused program's jit call: it takes the host batch as it
+        is, so the hand-over to the device is inside the call."""
+        with stagestats.timed("launch", batch.nbytes):
+            return hh_device.fused_encode_hash(self.k, self.m)(batch)
+
     def _encode_hash_host_tiled(self, batch: np.ndarray, parity: np.ndarray,
                                 hashes: np.ndarray, lo: int, hi: int) -> None:
         """Host fallback fused schedule over blocks [lo, hi): encode a
@@ -605,8 +617,7 @@ class Erasure:
         for glo in range(lo, hi, group):
             ghi = min(glo + group, hi)
             if self.m:
-                with stagestats.timed("encode", (ghi - glo) * k * s):
-                    self._host.encode(batch[glo:ghi], out=parity[glo:ghi])
+                self._host_encode(batch[glo:ghi], parity[glo:ghi])
             with stagestats.timed("fused_hash", (ghi - glo) * rowset * s):
                 hashes[glo:ghi, :k] = self._hash_rows(
                     batch[glo:ghi].reshape(-1, s)).reshape(ghi - glo, k, 32)
@@ -630,8 +641,9 @@ class Erasure:
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
             t0 = time.perf_counter()
-            par, hsh = hh_device.fused_encode_hash(self.k, self.m)(batch)
-            parity, frames = np.asarray(par), np.asarray(hsh)
+            par, hsh = self._fused_launch(batch)
+            with stagestats.timed("fetch", par.nbytes + hsh.nbytes):
+                parity, frames = np.asarray(par), np.asarray(hsh)
             stagestats.add("encode", time.perf_counter() - t0, batch.nbytes)
             # the hash plane rode the encode launch: book its bytes with
             # zero seconds — one pass is the point
@@ -659,11 +671,12 @@ class Erasure:
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
             t0 = time.perf_counter()
-            par, hsh = hh_device.fused_encode_hash(self.k, self.m)(batch)
+            par, hsh = self._fused_launch(batch)
 
             def resolve_dev():
-                parity = np.asarray(par)
-                frames = np.asarray(hsh)
+                with stagestats.timed("fetch", par.nbytes + hsh.nbytes):
+                    parity = np.asarray(par)
+                    frames = np.asarray(hsh)
                 stagestats.add("encode", time.perf_counter() - t0,
                                batch.nbytes)
                 stagestats.add("fused_hash", 0.0, b * (k + self.m) * s)
@@ -705,8 +718,11 @@ class Erasure:
         dev = self._device(batch.nbytes, s)
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
-            return np.asarray(dev.reconstruct(batch, available, wanted))
-        return self._host.reconstruct(batch, available, wanted)
+            out = dev.reconstruct(batch, available, wanted)
+            with stagestats.timed("fetch", b * len(wanted) * s):
+                return np.asarray(out)
+        with stagestats.timed("host_codec", batch.nbytes):
+            return self._host.reconstruct(batch, available, wanted)
 
     def _reconstruct_shards(self, batch: np.ndarray, available: tuple,
                             wanted: tuple) -> np.ndarray:
@@ -1062,14 +1078,19 @@ class Erasure:
                     # to per-block gf256.split + stack, which cost two
                     # copies and nfull python round trips)
                     per = -(-bs // self.k)
-                    batch = np.zeros((nfull, self.k * per), dtype=np.uint8)
-                    batch[:, :bs] = data_arr[: nfull * bs].reshape(nfull, bs)
+                    with stagestats.timed("assemble", nfull * bs):
+                        batch = np.zeros((nfull, self.k * per),
+                                         dtype=np.uint8)
+                        batch[:, :bs] = data_arr[: nfull * bs].reshape(
+                            nfull, bs)
                     flush_batch(slot, batch.reshape(nfull, self.k, per),
                                 bs, hfut)
                     first = False
                 tail = got - nfull * bs
                 if tail:
-                    shards = gf256.split(data_arr[nfull * bs:got], self.k)
+                    with stagestats.timed("assemble", tail):
+                        shards = gf256.split(
+                            data_arr[nfull * bs:got], self.k)
                     flush_batch(slot, shards[None, ...], tail,
                                 hfut if first else None)
                 if got < want:
@@ -1143,34 +1164,47 @@ class Erasure:
                 for i in active
             }
             active = []
-            for i, fut in futs.items():
-                try:
-                    got[i] = fut.result()
-                except Exception:
-                    broken.add(i)
+            # this thread's wait for the pool to bring the shards, the
+            # queueing in the pool included; the drives' own time is
+            # booked there as shard_read and verify (erasure/bitrot.py)
+            with stagestats.timed("read_wait",
+                                  len(futs) * nblocks * shard_len):
+                for i, fut in futs.items():
                     try:
-                        active.append(next(idx_iter))
-                    except StopIteration:
-                        raise errors.ErasureReadQuorum(
-                            f"shard {i} failed and no spare drives remain"
-                        )
+                        got[i] = fut.result()
+                    except Exception:
+                        broken.add(i)
+                        try:
+                            active.append(next(idx_iter))
+                        except StopIteration:
+                            raise errors.ErasureReadQuorum(
+                                f"shard {i} failed and no spare drives "
+                                f"remain")
         return got
 
     def _assemble_data(self, got: dict[int, np.ndarray], nblocks: int,
                        shard_len: int) -> np.ndarray:
         """(nblocks, k, shard_len) data shards from k read shards,
         reconstructing missing data shards in one batched dispatch."""
-        data = np.empty((nblocks, self.k, shard_len), dtype=np.uint8)
         missing = tuple(i for i in range(self.k) if i not in got)
-        for i in range(self.k):
-            if i in got:
-                data[:, i, :] = got[i]
+        shard_bytes = nblocks * shard_len
+        # `assemble` is the host's copies alone, on both sides of the
+        # dispatch and not around it: the codec books its own leaves
+        with stagestats.timed("assemble", (
+                self.k - len(missing)
+                + (self.k if missing else 0)) * shard_bytes):
+            data = np.empty((nblocks, self.k, shard_len), dtype=np.uint8)
+            for i in range(self.k):
+                if i in got:
+                    data[:, i, :] = got[i]
+            if missing:
+                avail = tuple(sorted(got))[: self.k]
+                src = np.stack([got[i] for i in avail], axis=1)
         if missing:
-            avail = tuple(sorted(got))[: self.k]
-            src = np.stack([got[i] for i in avail], axis=1)
             rebuilt = self._reconstruct_shards(src, avail, missing)
-            for j, w in enumerate(missing):
-                data[:, w, :] = rebuilt[:, j, :]
+            with stagestats.timed("assemble", len(missing) * shard_bytes):
+                for j, w in enumerate(missing):
+                    data[:, w, :] = rebuilt[:, j, :]
         return data
 
     def decode_stream(self, writer, readers: Sequence, offset: int,
@@ -1297,7 +1331,8 @@ class Erasure:
             except errors.ErasureReadQuorum:
                 raise errors.ErasureReadQuorum("healing read quorum lost")
             avail = tuple(sorted(got))[: self.k]
-            src = np.stack([got[i] for i in avail], axis=1)
+            with stagestats.timed("assemble", self.k * g * shard_len):
+                src = np.stack([got[i] for i in avail], axis=1)
             rebuilt = self._reconstruct_shards(src, avail, wanted)
             for j, w in enumerate(wanted):
                 wf = getattr(writers[w], "write_frames", None)

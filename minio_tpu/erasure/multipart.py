@@ -370,7 +370,7 @@ class MultipartMixin:
 
         # commit-rename fan-out with quorum accounting (the serial loop
         # was one rename + sidecar write round trip PER drive)
-        errs = self._fan_out(commit, range(n))
+        errs = self._commit_meta(commit)
         if sum(1 for x in errs if x is None) < wq:
             raise errors.ErasureWriteQuorum("part commit quorum")
         return PartInfo(part_number, etag, total, now)
@@ -649,7 +649,7 @@ class MultipartMixin:
             # shared I/O pool with quorum accounting, the same shape as
             # put_object's commit (serial, assembly latency grew with
             # drive count even though every disk was idle 15/16ths of it)
-            errs = self._fan_out(commit, range(n))
+            errs = self._commit_meta(commit)
         self._mp_cache().pop((bucket, obj, upload_id), None)
         if sum(1 for x in errs if x is None) < wq:
             raise errors.ErasureWriteQuorum("complete multipart quorum")

@@ -482,83 +482,86 @@ class ErasureObjects:
     def _read_all_fileinfo(self, bucket: str, obj: str, version_id: str = "",
                            read_data: bool = False, hedge: bool = False
                            ) -> tuple[list[FileInfo | None], list[Exception | None]]:
-        disks = self.disks
-        n = len(disks)
-        fis: list[FileInfo | None] = [None] * n
-        errs: list[Exception | None] = [None] * n
+        """`read_version` of every drive, until an answer can be elected
+        (the quorum read of `xl.meta`: stage `meta_read`)."""
+        with stagestats.timed("meta_read"):
+            disks = self.disks
+            n = len(disks)
+            fis: list[FileInfo | None] = [None] * n
+            errs: list[Exception | None] = [None] * n
 
-        def read(i: int):
-            d = disks[i]
-            if d is None or not d.is_online():
-                raise errors.DiskNotFound(str(i))
-            return d.read_version(bucket, obj, version_id, read_data)
+            def read(i: int):
+                d = disks[i]
+                if d is None or not d.is_online():
+                    raise errors.DiskNotFound(str(i))
+                return d.read_version(bucket, obj, version_id, read_data)
 
-        futs = {deadline_mod.ctx_submit(_io_pool(), read, i): i
-                for i in range(n)}
-        budget = deadline_mod.current()
-        bounded = budget is not None and budget.t_end is not None
-        if not bounded and not hedge:
-            # no deadline in play (background scans/heals): preserve the
-            # complete fan-out — health accounting wants every answer
-            for f, i in futs.items():
+            futs = {deadline_mod.ctx_submit(_io_pool(), read, i): i
+                    for i in range(n)}
+            budget = deadline_mod.current()
+            bounded = budget is not None and budget.t_end is not None
+            if not bounded and not hedge:
+                # no deadline in play (background scans/heals): preserve the
+                # complete fan-out — health accounting wants every answer
+                for f, i in futs.items():
+                    try:
+                        fis[i] = f.result()
+                    except Exception as e:
+                        errs[i] = e
+                return fis, errs
+            # deadline-aware: return at quorum, abandon stragglers.  A
+            # FileInfo must actually be ELECTABLE from the answers in hand
+            # (modal signature at the object's own read quorum — RRS parity
+            # and mixed votes during a concurrent overwrite both demand more
+            # than a bare success count) before stragglers are put on the
+            # STRAGGLER_GRACE clock; a +500 ms drive then costs 50 ms, not
+            # the whole RPC timeout (cmd/erasure-metadata-utils.go
+            # readAllFileInfo; hedged-request literature in PAPERS.md).
+            # With hedge=True the same quorum+grace policy applies even
+            # WITHOUT a bounded budget: the foreground read path (GET /
+            # head) must not let one slow drive's read_version stall
+            # first-byte latency — the metadata analogue of the shard-stream
+            # hedging below (ROADMAP deadline-plane follow-up).
+            def electable() -> bool:
                 try:
-                    fis[i] = f.result()
-                except Exception as e:
-                    errs[i] = e
-            return fis, errs
-        # deadline-aware: return at quorum, abandon stragglers.  A
-        # FileInfo must actually be ELECTABLE from the answers in hand
-        # (modal signature at the object's own read quorum — RRS parity
-        # and mixed votes during a concurrent overwrite both demand more
-        # than a bare success count) before stragglers are put on the
-        # STRAGGLER_GRACE clock; a +500 ms drive then costs 50 ms, not
-        # the whole RPC timeout (cmd/erasure-metadata-utils.go
-        # readAllFileInfo; hedged-request literature in PAPERS.md).
-        # With hedge=True the same quorum+grace policy applies even
-        # WITHOUT a bounded budget: the foreground read path (GET /
-        # head) must not let one slow drive's read_version stall
-        # first-byte latency — the metadata analogue of the shard-stream
-        # hedging below (ROADMAP deadline-plane follow-up).
-        def electable() -> bool:
-            try:
-                rq, _ = self._quorum_from(fis)
-                find_file_info_in_quorum(fis, rq)
-                return True
-            except Exception:
-                return False
+                    rq, _ = self._quorum_from(fis)
+                    find_file_info_in_quorum(fis, rq)
+                    return True
+                except Exception:
+                    return False
 
-        pending = set(futs)
-        elected = False
-        while pending:
-            timeout = budget.remaining() if bounded else None
-            if elected:
-                timeout = STRAGGLER_GRACE if timeout is None \
-                    else min(timeout, STRAGGLER_GRACE)
-            if timeout is not None and timeout <= 0:
-                break
-            done, pending = cf.wait(pending, timeout=timeout,
-                                    return_when=cf.FIRST_COMPLETED)
-            if not done:
-                break  # grace or budget spent: abandon the rest
-            got_new = False
-            for f in done:
+            pending = set(futs)
+            elected = False
+            while pending:
+                timeout = budget.remaining() if bounded else None
+                if elected:
+                    timeout = STRAGGLER_GRACE if timeout is None \
+                        else min(timeout, STRAGGLER_GRACE)
+                if timeout is not None and timeout <= 0:
+                    break
+                done, pending = cf.wait(pending, timeout=timeout,
+                                        return_when=cf.FIRST_COMPLETED)
+                if not done:
+                    break  # grace or budget spent: abandon the rest
+                got_new = False
+                for f in done:
+                    i = futs[f]
+                    try:
+                        fis[i] = f.result()
+                        got_new = True
+                    except Exception as e:
+                        errs[i] = e
+                if got_new and not elected:
+                    elected = electable()
+            for f in pending:
                 i = futs[f]
-                try:
-                    fis[i] = f.result()
-                    got_new = True
-                except Exception as e:
-                    errs[i] = e
-            if got_new and not elected:
-                elected = electable()
-        for f in pending:
-            i = futs[f]
-            f.cancel()  # un-started pool items never run
-            errs[i] = errors.DeadlineExceeded(
-                f"drive {i}: straggler abandoned at quorum")
-            hedge_stats["abandoned"] += 1
-        if pending:
-            tracing.event("read.stragglers_abandoned", count=len(pending))
-        return fis, errs
+                f.cancel()  # un-started pool items never run
+                errs[i] = errors.DeadlineExceeded(
+                    f"drive {i}: straggler abandoned at quorum")
+                hedge_stats["abandoned"] += 1
+            if pending:
+                tracing.event("read.stragglers_abandoned", count=len(pending))
+            return fis, errs
 
     def _quorum_info(self, bucket, obj, version_id="", read_data=False,
                      hedge=False):
@@ -814,10 +817,11 @@ class ErasureObjects:
             if mp_groups is not None:
                 # node-batched commit over the worker plane: one
                 # message per worker commits every drive it wrote
-                res = mp_plane.commit(
-                    mp_groups, "rename_data", SYSTEM_VOL, tmp_prefix,
-                    fi=make_fi(0), bucket=bucket, obj=obj,
-                    skip=failed_shards)
+                with stagestats.timed("commit"):
+                    res = mp_plane.commit(
+                        mp_groups, "rename_data", SYSTEM_VOL, tmp_prefix,
+                        fi=make_fi(0), bucket=bucket, obj=obj,
+                        skip=failed_shards)
                 commit_errs = [None] * n
                 for i in range(n):
                     if i in failed_shards:
@@ -828,9 +832,10 @@ class ErasureObjects:
                     else:
                         commit_errs[i] = errors.DiskNotFound(str(i))
             else:
-                commit_errs = self._commit_all(commit, make_fi, disks,
-                                               inline, failed_shards,
-                                               tmp_prefix, bucket, obj)
+                with stagestats.timed("commit"):
+                    commit_errs = self._commit_all(
+                        commit, make_fi, disks, inline, failed_shards,
+                        tmp_prefix, bucket, obj)
         if not inline:
             # a successful commit MOVED the staged dir (rename_data);
             # only drives whose commit did not land still hold staging —
@@ -903,6 +908,13 @@ class ErasureObjects:
             for i, err in zip(g, f.result()):
                 out[i] = err
         return out
+
+    def _commit_meta(self, fn: Callable[[int], None]
+                     ) -> list[Exception | None]:
+        """A version's metadata written to every drive of the set, syncs
+        included (stage `commit`, as a PUT's rename_data fan-out)."""
+        with stagestats.timed("commit"):
+            return self._fan_out(fn, range(len(self.disks)))
 
     def _commit_all(self, commit, make_fi, disks, inline, failed_shards,
                     tmp_prefix, bucket, obj) -> list[Exception | None]:
@@ -1275,7 +1287,7 @@ class ErasureObjects:
                     raise errors.DiskNotFound(str(i))
                 d.write_metadata(bucket, obj, marker)
 
-            errs = self._fan_out(put_marker, range(len(self.disks)))
+            errs = self._commit_meta(put_marker)
             _, wq = self._quorum_from([None] * len(self.disks))
             if sum(1 for e2 in errs if e2 is None) < wq:
                 raise errors.ErasureWriteQuorum("delete marker quorum")
@@ -1304,7 +1316,7 @@ class ErasureObjects:
                     d.delete_version(bucket, obj, marker,
                                      force_del_marker=True)
 
-                errs = self._fan_out(put_null_marker, range(len(self.disks)))
+                errs = self._commit_meta(put_null_marker)
                 _, wq = self._quorum_from([None] * len(self.disks))
                 if sum(1 for e2 in errs if e2 is None) < wq:
                     raise errors.ErasureWriteQuorum("delete marker quorum")
@@ -1327,7 +1339,7 @@ class ErasureObjects:
                         raise errors.DiskNotFound(str(i))
                     d.write_metadata(bucket, obj, marker)
 
-                errs = self._fan_out(put_marker, range(len(self.disks)))
+                errs = self._commit_meta(put_marker)
                 _, wq = self._quorum_from([None] * len(self.disks))
                 if sum(1 for e2 in errs if e2 is None) < wq:
                     raise errors.ErasureWriteQuorum("delete marker quorum")
@@ -1361,7 +1373,7 @@ class ErasureObjects:
                     raise errors.DiskNotFound(str(i))
                 d.delete_version(bucket, obj, fi)
 
-            errs = self._fan_out(del_version, range(len(self.disks)))
+            errs = self._commit_meta(del_version)
             ok = sum(1 for e2 in errs
                      if e2 is None or isinstance(e2, errors.FileNotFound))
             # deletes use MAJORITY quorum regardless of the version's

@@ -1,20 +1,46 @@
-"""Per-stage wall-time accounting for the object data plane.
+"""The leaf-span primitive of the served path: one timed interval, three
+sinks.
 
-Every stage of the PUT/GET pipeline (stream read, etag folding, erasure
-encode, bitrot hash, shard write, shard decode, response hand-off) folds
-its elapsed seconds in here, so the remaining gap between codec speed and
-client-visible throughput is attributable instead of argued about.
-Exposed as `minio_dataplane_stage_seconds_total{stage=...}` by
-server/metrics.py and consumed by bench.py's object-layer breakdown.
+`with timed(stage, nbytes): ...` around a piece of the PUT/GET pipeline
+
+1. folds the interval into the process-wide per-stage counters:
+   *thread-seconds* of work (summed over every thread that was inside
+   the stage, so overlapping threads add up past wall time), bytes, and
+   a *wall-time* union (the clock runs while at least one thread is
+   inside the stage).  server/metrics.py exports them as
+   `minio_dataplane_stage_{seconds,bytes,wall_seconds}_total{stage}`;
+   the benchmark's `program_counter` metrics (benchmark/readers/stage_*)
+   read the first two;
+2. lies in the profiler's trace as `dp.<stage>` for the same interval
+   (`jax.profiler.TraceAnnotation`), on the clock of the device's own
+   lines, so a traced run can name what the host did while the chip was
+   idle (benchmark/trace.py `idle_gaps`).  The profiler being started is
+   the only switch; an inactive annotation costs well under a
+   microsecond;
+3. where a request trace is ambient (utils/tracing.py rides the copied
+   context into the pool threads), becomes a `dp.<stage>` child span of
+   it and folds into its per-request stage seconds.
 
 Stages overlap by design (the hasher folds batch N while the main thread
-encodes N+1 and the pool writes N-1), so the per-stage sum may exceed the
-pipeline's wall time — that is the point: a sum well above wall proves
-overlap, a stage near wall names the bottleneck.
+encodes N+1 and the pool writes N-1), so thread-seconds summed over
+stages may exceed the pipeline's wall time: a sum well above wall proves
+overlap, a stage whose wall time nears the request's names the
+bottleneck.
+
+Leaves only in the profiler.  The trace's reduction names an idle gap by
+the span that overlaps it most, so a span that wraps other spans would
+hide them.  `PARENTS` enclose other stages (`decode` = read_wait +
+assemble + the codec's leaves on the stream's own thread; `encode` =
+host_codec, or h2d + launch + fetch): they keep their counters and their
+per-request seconds and write no span.  `add()` books a reading taken
+elsewhere (the admission wait, a compile's duration, bytes that arrived)
+into the counters alone.
 """
 
 from __future__ import annotations
 
+import contextlib
+import sys
 import threading
 import time
 
@@ -26,22 +52,57 @@ from minio_tpu.utils import tracing
 # launch — one pass is the point); on the host fallback it carries the
 # tiled hash leg's real seconds so fused vs legacy "hash" stays
 # attributable.
-STAGES = ("read", "etag", "encode", "hash", "fused_hash", "write",
-          "decode", "respond")
+STAGES = (
+    # PUT: body read, etag fold, erasure encode (parent), frame hash,
+    # shard write; GET: decode (parent), hand-over to the HTTP front
+    "read", "etag", "encode", "hash", "fused_hash", "write", "decode",
+    "respond",
+    # GET / heal: the stream's thread waiting for its k shards; in the
+    # I/O pool, a drive's bytes arriving and their frame check
+    "read_wait", "shard_read", "verify",
+    # host copies around a codec dispatch; the dispatch itself: hand-over
+    # to the device, the jit call until it returns, waiting for the
+    # device plus read-back; or the host codec's own compute
+    "assemble", "h2d", "launch", "fetch", "host_codec",
+    # quorum write / read of xl.meta; signature + policy; admission wait
+    "commit", "meta_read", "auth", "admit",
+    # seconds in XLA compilation (counter only: benchmark/trace.py counts
+    # every host span with "compile" in its name as a compilation)
+    "compile",
+)
+PARENTS = frozenset(("encode", "decode"))
 
 _lock = threading.Lock()
 _seconds = {s: 0.0 for s in STAGES}
 _bytes = {s: 0 for s in STAGES}
+_wall = {s: 0.0 for s in STAGES}
+_inside = {s: 0 for s in STAGES}    # threads inside the stage now
+_since = {s: 0.0 for s in STAGES}   # when _inside last left 0
+
+# what a leaf is called in the profiler's trace and in a request's tree
+_SPAN_NAMES = {s: "dp." + s for s in STAGES if s not in PARENTS}
+_NO_SPAN = contextlib.nullcontext()
+
+
+def annotation(name: str):
+    """A context manager that lays `name` into the profiler's trace for
+    its block (`jax.profiler.TraceAnnotation`; no keyword arguments, so
+    the name comes out of the trace as written).  JAX is never imported
+    for this: `import jax` loads `jax.profiler`, only code that has
+    imported JAX can have started a profiler, and data-plane workers on
+    the host codec never do."""
+    profiler = sys.modules.get("jax.profiler")
+    # no attribute yet: JAX is being imported on another thread right now
+    span = getattr(profiler, "TraceAnnotation", None)
+    return _NO_SPAN if span is None else span(name)
 
 
 def add(stage: str, seconds: float, nbytes: int = 0) -> None:
-    """Fold one timed span into a stage (thread-safe; stages are bumped
-    from pool workers, hasher tasks and the main encode thread alike).
-
-    When a request trace is ambient (utils/tracing.py rides the copied
-    context into the same pool threads), the fold ALSO attributes to
-    that trace — per-request read/etag/encode/hash/write/decode
-    seconds, not just the global totals (ISSUE 12)."""
+    """Fold one reading into a stage's counters (thread-safe; stages are
+    bumped from pool workers, hasher tasks and the main encode thread
+    alike), and into the ambient request trace's per-request stage
+    seconds (ISSUE 12).  No span, no wall time: for readings taken
+    elsewhere."""
     with _lock:
         _seconds[stage] += seconds
         _bytes[stage] += nbytes
@@ -51,31 +112,57 @@ def add(stage: str, seconds: float, nbytes: int = 0) -> None:
 
 
 class timed:
-    """`with timed("write", n): ...` — time a span into a stage."""
+    """`with timed("write", n): ...` — one interval into all three sinks
+    (module docstring)."""
 
-    __slots__ = ("stage", "nbytes", "_t0")
+    __slots__ = ("stage", "nbytes", "_t0", "_span")
 
     def __init__(self, stage: str, nbytes: int = 0):
         self.stage = stage
         self.nbytes = nbytes
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        stage = self.stage
+        name = _SPAN_NAMES.get(stage)  # None: a parent, counters only
+        self._span = None if name is None else annotation(name)
+        if name is not None:
+            self._span.__enter__()
+        self._t0 = t0 = time.perf_counter()
+        with _lock:
+            if not _inside[stage]:
+                _since[stage] = t0
+            _inside[stage] += 1
         return self
 
     def __exit__(self, *exc) -> bool:
-        add(self.stage, time.perf_counter() - self._t0, self.nbytes)
+        stage = self.stage
+        t1 = time.perf_counter()
+        dt = t1 - self._t0
+        with _lock:
+            _seconds[stage] += dt
+            _bytes[stage] += self.nbytes
+            _inside[stage] -= 1
+            if not _inside[stage]:
+                _wall[stage] += t1 - _since[stage]
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        ref = tracing.current_ref()
+        if ref is not None:
+            ref[0].add_stage(stage, dt)
+            if self._span is not None:
+                tracing.record_span(ref, _SPAN_NAMES[stage], dt)
         return False
 
 
 def snapshot() -> dict[str, dict[str, float]]:
-    """{stage: {"seconds": s, "bytes": n}} — copied under the lock so a
-    metrics render never sees a half-updated row."""
+    """{stage: {"seconds": thread-seconds, "bytes": n, "wall": s}} —
+    copied under the lock so a metrics render never sees a half-updated
+    row.  An interval counts once it has ended."""
     with _lock:
-        return {s: {"seconds": _seconds[s], "bytes": _bytes[s]}
-                for s in STAGES}
+        return {s: {"seconds": _seconds[s], "bytes": _bytes[s],
+                    "wall": _wall[s]} for s in STAGES}
 
 
 def delta(before: dict, after: dict) -> dict[str, float]:
-    """Per-stage seconds between two snapshots (bench attribution)."""
+    """Per-stage thread-seconds between two snapshots."""
     return {s: after[s]["seconds"] - before[s]["seconds"] for s in STAGES}

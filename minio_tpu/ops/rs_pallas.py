@@ -27,6 +27,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from minio_tpu.erasure import stagestats
+
 from . import gf256, residency, rs_tpu
 
 # Column-tile width in int32 words (bytes = 4 * _TILE_WORDS per shard row).
@@ -225,14 +227,21 @@ class PallasRSCodec:
             lambda: jnp.asarray(_permute_mat(rs_tpu.encode_bits_matrix(k, m))))
 
     def _run(self, mat, shards) -> jax.Array:
-        shards = jnp.asarray(shards, dtype=jnp.uint8)
+        # the host side of a dispatch, timed as the calls are made: no
+        # wait is added to tell transfer from enqueue, so `h2d` is the
+        # hand-over as far as the call blocks and `launch` the jit call
+        # until it returns (the caller's `fetch` waits for the device)
+        with stagestats.timed("h2d") as span:
+            shards = jnp.asarray(shards, dtype=jnp.uint8)
+            span.nbytes = shards.nbytes
         s = shards.shape[-1]
         if s % (4 * _TILE_WORDS) != 0:
             raise ValueError(
                 f"shard length {s} not a multiple of {4 * _TILE_WORDS}; "
                 "use TpuRSCodec or pad"
             )
-        return _coding_call_bytes(mat, shards, interpret=self._interpret)
+        with stagestats.timed("launch", shards.nbytes):
+            return _coding_call_bytes(mat, shards, interpret=self._interpret)
 
     def encode(self, data_shards) -> jax.Array:
         """(B, K, S) uint8 -> (B, M, S) parity."""

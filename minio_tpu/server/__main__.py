@@ -101,6 +101,24 @@ def _prefork_http_front(n: int, argv) -> int:
     return 0
 
 
+def _count_compile_seconds() -> None:
+    """Book every XLA compilation of this process under the data plane's
+    stage `compile` (`minio_dataplane_stage_seconds_total{stage=
+    "compile"}`): a batch shape that compiles inside a request is
+    seconds of a PUT an operator could not otherwise see.  JAX reports
+    the backend's compile, or the persistent cache's answer in its
+    place, per program."""
+    import jax.monitoring
+
+    from minio_tpu.erasure import stagestats
+
+    def fold(event: str, seconds: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            stagestats.add("compile", seconds)
+
+    jax.monitoring.register_event_duration_secs_listener(fold)
+
+
 def _init_device(backend: str):
     """Initialise JAX for a backend that may use a device and say what
     it found (ops/device.DeviceInfo); None for backend "host", which
@@ -112,6 +130,7 @@ def _init_device(backend: str):
     if backend == "host":
         return None
     device.enable_compile_cache()
+    _count_compile_seconds()
     if backend == "tpu":
         return device.require_tpu("MINIO_TPU_ERASURE_BACKEND=tpu")
     return device.info()

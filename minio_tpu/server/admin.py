@@ -896,7 +896,9 @@ class AdminMixin:
     async def admin_trace_summary(self, request: web.Request,
                                   body: bytes) -> web.Response:
         """Per-stage latency aggregates over the retained trace store:
-        span-name p50/p99/count/total plus the stagestats fold totals.
+        span-name p50/p99/count/total plus the stagestats fold totals,
+        and under ``dataplane`` the process-wide stage counters
+        (thread-seconds, bytes, wall seconds) since boot.
         ``?n=`` bounds how many retained traces feed the aggregate
         (default: all); ``?since=<epoch-seconds>`` restricts to traces
         that STARTED at/after the instant (the simulator scopes a
@@ -904,6 +906,7 @@ class AdminMixin:
         store spans the server's whole life).  This is the forensics
         surface the simulator (and a human chasing a p99) reads
         instead of re-deriving stage timings from counters."""
+        from minio_tpu.erasure import stagestats
         from minio_tpu.utils import tracing
 
         q = request.rel_url.query
@@ -923,6 +926,15 @@ class AdminMixin:
         if since:
             docs = [d for d in docs if d.get("start", 0.0) >= since]
         out = tracing.summarize_stages(docs)
+        # the process-wide stage counters beside the retained traces':
+        # thread-seconds, bytes and wall time since boot, every request
+        # in them, captured or not
+        out["dataplane"] = {
+            stage: {"seconds": round(d["seconds"], 6),
+                    "bytes": int(d["bytes"]),
+                    "wallSeconds": round(d["wall"], 6)}
+            for stage, d in stagestats.snapshot().items()
+            if d["seconds"] or d["bytes"]}
         out["enabled"] = tracing.enabled()
         out["store"] = tracing.store.stats()
         return web.json_response(out)

@@ -30,6 +30,7 @@ from xml.sax.saxutils import escape
 from aiohttp import web
 
 from minio_tpu.storage import errors as st
+from minio_tpu.erasure import stagestats
 from minio_tpu.erasure.objects import PutObjectOptions
 from . import sigv4
 from .bucket_meta import BucketMetaHandlers
@@ -751,58 +752,61 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         `action` on the resource (reference checkRequestAuthType,
         cmd/auth-handler.go).  Decision combines the IAM layer with the
         bucket policy; an explicit Deny in either layer wins."""
-        query = [(k, v) for k, v in urllib.parse.parse_qsl(
-            request.rel_url.query_string, keep_blank_values=True
-        )]
-        headers = dict(request.headers)
-        headers["host"] = request.headers.get("Host", request.host)
-        path = urllib.parse.unquote(request.rel_url.raw_path)
-        conditions = self._request_conditions(request)
+        # stage `auth`: signature check and policy, for every caller.  The
+        # policy look-up may await the executor; its wait is in the span
+        with stagestats.timed("auth"):
+            query = [(k, v) for k, v in urllib.parse.parse_qsl(
+                request.rel_url.query_string, keep_blank_values=True
+            )]
+            headers = dict(request.headers)
+            headers["host"] = request.headers.get("Host", request.host)
+            path = urllib.parse.unquote(request.rel_url.raw_path)
+            conditions = self._request_conditions(request)
 
-        if self._is_anonymous(request):
-            # anonymous request: the bucket policy alone decides
-            # (reference cmd/auth-handler.go authTypeAnonymous path)
-            if action and bucket and await self._authorized(
-                    "*", action, bucket, obj, conditions):
-                return sigv4.V4Context("", b"", "", "", "")
-            raise S3Error("AccessDenied", "anonymous access denied",
-                          resource=request.path)
-
-        try:
-            qd = dict(query)
-            auth_hdr = request.headers.get("Authorization", "")
-            if "X-Amz-Signature" in qd:
-                ctx = sigv4.verify_v4_presigned(
-                    request.method, path, query, headers,
-                    self.iam.get_secret, self.region,
-                )
-            elif "Signature" in qd and "AWSAccessKeyId" in qd:
-                # legacy V2 presigned (reference cmd/signature-v2.go)
-                ctx = sigv4.verify_v2_presigned(
-                    request.method, path, query, headers,
-                    self.iam.get_secret,
-                )
-            elif auth_hdr.startswith("AWS ") \
-                    and not auth_hdr.startswith("AWS4-"):
-                # legacy V2 header form
-                ctx = sigv4.verify_v2(
-                    request.method, path, query, headers,
-                    self.iam.get_secret,
-                )
-            else:
-                ctx = sigv4.verify_v4(
-                    request.method, path, query, headers, payload_hash,
-                    self.iam.get_secret, self.region,
-                )
-        except sigv4.SigV4Error as e:
-            raise S3Error(e.code, str(e))
-        request["accessKey"] = ctx.access_key  # for audit/trace entries
-        if action:
-            if not await self._authorized(ctx.access_key, action, bucket,
-                                          obj, conditions):
-                raise S3Error("AccessDenied", f"not allowed to {action}",
+            if self._is_anonymous(request):
+                # anonymous request: the bucket policy alone decides
+                # (reference cmd/auth-handler.go authTypeAnonymous path)
+                if action and bucket and await self._authorized(
+                        "*", action, bucket, obj, conditions):
+                    return sigv4.V4Context("", b"", "", "", "")
+                raise S3Error("AccessDenied", "anonymous access denied",
                               resource=request.path)
-        return ctx
+
+            try:
+                qd = dict(query)
+                auth_hdr = request.headers.get("Authorization", "")
+                if "X-Amz-Signature" in qd:
+                    ctx = sigv4.verify_v4_presigned(
+                        request.method, path, query, headers,
+                        self.iam.get_secret, self.region,
+                    )
+                elif "Signature" in qd and "AWSAccessKeyId" in qd:
+                    # legacy V2 presigned (reference cmd/signature-v2.go)
+                    ctx = sigv4.verify_v2_presigned(
+                        request.method, path, query, headers,
+                        self.iam.get_secret,
+                    )
+                elif auth_hdr.startswith("AWS ") \
+                        and not auth_hdr.startswith("AWS4-"):
+                    # legacy V2 header form
+                    ctx = sigv4.verify_v2(
+                        request.method, path, query, headers,
+                        self.iam.get_secret,
+                    )
+                else:
+                    ctx = sigv4.verify_v4(
+                        request.method, path, query, headers, payload_hash,
+                        self.iam.get_secret, self.region,
+                    )
+            except sigv4.SigV4Error as e:
+                raise S3Error(e.code, str(e))
+            request["accessKey"] = ctx.access_key  # for audit/trace entries
+            if action:
+                if not await self._authorized(ctx.access_key, action, bucket,
+                                              obj, conditions):
+                    raise S3Error("AccessDenied", f"not allowed to {action}",
+                                  resource=request.path)
+            return ctx
 
     @staticmethod
     def _is_anonymous(request: web.Request) -> bool:
@@ -1204,6 +1208,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                 self._sem_held += 1
             wait_dt = time.monotonic() - t0
             self._m_queue_wait.observe(wait_dt)
+            stagestats.add("admit", wait_dt)
             if root is not None:
                 # admission-wait child: ~0 on the fast path, the queue
                 # wait otherwise — the first place a slow request's

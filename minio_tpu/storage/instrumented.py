@@ -24,6 +24,7 @@ import os
 import threading
 import time
 
+from minio_tpu.erasure import stagestats
 from minio_tpu.storage import errors
 from minio_tpu.utils import deadline as deadline_mod
 from minio_tpu.utils import tracing
@@ -224,6 +225,7 @@ class InstrumentedStorage:
     def _wrap(self, op: str, fn):
         stats = self._ops[op]
         gated = op in DEADLINE_GATED_OPS
+        span_name = f"drive.{op}"
 
         def timed(*a, **kw):
             if self._breaker_open:
@@ -245,13 +247,16 @@ class InstrumentedStorage:
             ref = tracing.current_ref()
             t0 = time.monotonic()
             try:
-                out = fn(*a, **kw)
+                # the same interval in the profiler's trace, when one
+                # is being taken (erasure/stagestats.py)
+                with stagestats.annotation(span_name):
+                    out = fn(*a, **kw)
             except Exception as e:
                 dt = time.monotonic() - t0
                 stats.record(dt, failed=True)
                 if ref is not None:
                     tracing.record_span(
-                        ref, f"drive.{op}", dt,
+                        ref, span_name, dt,
                         drive=self._endpoint_label(),
                         error=type(e).__name__)
                 self._note(fault=is_drive_fault(e))
@@ -259,7 +264,7 @@ class InstrumentedStorage:
             dt = time.monotonic() - t0
             stats.record(dt, failed=False)
             if ref is not None:
-                tracing.record_span(ref, f"drive.{op}", dt,
+                tracing.record_span(ref, span_name, dt,
                                     drive=self._endpoint_label())
             self._note(fault=False)
             return out
@@ -286,7 +291,8 @@ class InstrumentedStorage:
         fut = deadline_mod.ctx_submit(_deadline_pool(), fn, *a, **kw)
         t0 = time.monotonic()
         try:
-            out = fut.result(timeout=rem)
+            with stagestats.annotation(f"drive.{op}"):
+                out = fut.result(timeout=rem)
         except cf.TimeoutError:
             if fut.cancel():
                 # never started: pool backlog ate the budget — not this

@@ -38,7 +38,9 @@ Span records are plain dicts (msgpack/pickle-safe for the carriers)::
 
     {"id", "parent", "name", "t0", "dur", <tag>: <value>, ...}
 
-``t0``/``dur`` are seconds relative to the owning trace's start.  The
+``t0``/``dur`` are seconds relative to the owning trace's start; a
+captured document also holds that start on the monotonic clock
+(``startMonotonic``), so a span can be laid beside a profiler trace.  The
 admin surface (``GET /minio/admin/v3/trace/slow``) returns captured
 traces with the tree assembled by ``span_tree``.
 """
@@ -64,7 +66,7 @@ MAX_SPANS_PER_TRACE = 512
 
 # observability for the tracing plane itself (read by server/metrics.py;
 # bare int bumps — the GIL makes them safe enough for counters)
-stats = {"traces": 0, "spans": 0, "spans_dropped": 0, "fragments": 0}
+stats = {"traces": 0, "spans": 0, "spans_dropped": 0}
 
 
 def _fast_env_reader():
@@ -160,8 +162,7 @@ _stage_mu = threading.Lock()
 
 class Trace:
     """One request's span collection: lock-free appends (see _stage_mu
-    note), with per-stage wall-time attribution folded in by
-    stagestats.  ``sampled`` is drawn LAZILY (None = undecided): the
+    note), with per-stage seconds folded in by stagestats.  ``sampled`` is drawn LAZILY (None = undecided): the
     common drop path pays the head-sampling env read + draw once, at
     finish/to_wire, not at start."""
 
@@ -455,6 +456,10 @@ def finish(root: Span, *, status: int = 200, error: bool = False,
         "traceId": tr.trace_id,
         "name": tr.name,
         "start": round(tr.wall0, 3),
+        # the same instant on time.perf_counter() (CLOCK_MONOTONIC, the
+        # clock of a profiler trace's host lines): start + a span's t0
+        # places it beside the device's own lines
+        "startMonotonic": round(tr.t0, 6),
         "durationMs": round(dur * 1e3, 3),
         "status": status,
         "reason": reason,
@@ -535,7 +540,6 @@ class continuation:
         if tr is None:
             tr = Trace(tid, self.name, sampled=sampled, fragment=True)
             self._fragment = tr
-            stats["fragments"] += 1
         self.sp = Span(tr, self.name, parent_id, self.tags)
         self._token = _current.set(self.sp)
         return self.sp

@@ -390,6 +390,17 @@ class TestTraceSummary:
         # at least one non-root stage exists to attribute against
         assert any(not d["isRoot"] for d in spans.values())
         assert "totalS" in next(iter(spans.values()))
+        # the served path's leaf stages reach the request trees as
+        # dp.<stage> spans, and the process-wide counters stand beside
+        # them with wall time (ISSUE 26); the admission wait is booked
+        # from the front's own reading, so it has seconds and no span
+        assert {"dp.auth", "dp.meta_read", "dp.commit"} <= set(spans)
+        assert "dp.admit" not in spans
+        plane = doc["dataplane"]
+        for stage in ("auth", "meta_read", "commit"):
+            assert 0 < plane[stage]["wallSeconds"] <= plane[stage]["seconds"]
+        assert plane["admit"]["seconds"] > 0
+        assert plane["admit"]["wallSeconds"] == 0
 
     def test_since_scopes_the_aggregate(self, slo_srv, monkeypatch):
         """?since= restricts to traces started at/after the instant —

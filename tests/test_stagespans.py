@@ -1,0 +1,259 @@
+"""ISSUE 26: the stage timer as the one leaf-span primitive
+(erasure/stagestats.py): counters, the profiler's trace, the request
+tree.
+
+One PUT and one degraded GET of a 16 MiB object on a 2+2 set run once
+under `jax.profiler` (host codec); a second, small stream runs through
+the Pallas kernel in interpret mode, the dispatch path of the chip.  The
+tests then read the `.xplane.pb` the way benchmark/trace.py does, the
+counters' deltas and the captured request traces.  CPU only: no number
+here is a device number.
+"""
+
+import io
+import os
+import shutil
+import sys
+import threading
+import time
+import types
+
+import numpy as np
+import pytest
+
+from benchmark import serve
+from benchmark import trace as bench_trace
+from minio_tpu.erasure import bitrot, coding, stagestats
+from minio_tpu.erasure.objects import ErasureObjects
+from minio_tpu.storage.instrumented import instrument
+from minio_tpu.storage.local import LocalStorage
+from minio_tpu.utils import tracing
+
+# leaves a PUT + degraded GET reach on the host codec, and the three more
+# of a device dispatch
+HOST_PUT = ("read", "etag", "host_codec", "hash", "write", "commit")
+HOST_GET = ("meta_read", "read_wait", "shard_read", "verify", "assemble",
+            "host_codec", "respond")
+DEVICE = ("h2d", "launch", "fetch")
+OBJECT_BYTES = 16 << 20
+
+
+def _delta(before: dict, after: dict) -> dict:
+    return {s: {k: after[s][k] - before[s][k] for k in after[s]}
+            for s in stagestats.STAGES}
+
+
+def _device_stream(tmp_path) -> dict:
+    """Two full 2+2 blocks encoded and, with a data shard away, decoded
+    through PallasRSCodec in interpret mode -> the GET's stage deltas."""
+    from minio_tpu.ops import rs_pallas
+
+    k, m, bs = 2, 2, 1 << 20
+    coding._DeviceCodec._cache[(k, m)] = (
+        rs_pallas.PallasRSCodec(k, m, interpret=True), True)
+    try:
+        e = coding.Erasure(k, m, bs, backend="tpu")
+        size = 2 * bs
+        data = np.random.default_rng(3).integers(
+            0, 256, size, dtype=np.uint8).tobytes()
+        paths = [tmp_path / f"shard{i}" for i in range(k + m)]
+        writers = [bitrot.BitrotWriter(open(p, "wb"), e.shard_size)
+                   for p in paths]
+        e.encode_stream(io.BytesIO(data), writers, size, k + 1)
+        for w in writers:
+            w.close()
+        readers = [None if i == 0 else bitrot.BitrotReader(
+            open(paths[i], "rb"), e.shard_file_size(size), e.shard_size)
+            for i in range(k + m)]
+        out = io.BytesIO()
+        before = stagestats.snapshot()
+        e.decode_stream(out, readers, 0, size, size)
+        after = stagestats.snapshot()
+        for r in readers:
+            if r is not None:
+                r.close()
+        assert out.getvalue() == data
+        return _delta(before, after)
+    finally:
+        coding._DeviceCodec._cache.pop((k, m), None)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """The run itself, once -> what the tests read."""
+    import jax
+
+    tmp = tmp_path_factory.mktemp("stagespans")
+    mp = pytest.MonkeyPatch()
+    mp.setenv("MINIO_TPU_ERASURE_BACKEND", "host")
+    mp.setenv("MINIO_TPU_TRACE", "1")
+    mp.setenv("MINIO_TPU_TRACE_SLOW_MS", "0")
+    disks = instrument([LocalStorage(str(tmp / f"d{i}")) for i in range(4)])
+    for d in disks:
+        d.make_volume("bkt")
+    api = ErasureObjects(disks)
+    data = np.random.default_rng(26).integers(
+        0, 256, OBJECT_BYTES, dtype=np.uint8).tobytes()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp / "trace"), profiler_options=options)
+    try:
+        root = tracing.begin_request("put_object")
+        api.put_object("bkt", "obj", io.BytesIO(data), len(data))
+        put_doc = tracing.end_request(root)
+        # drives 2 and 4 hold one data and one parity shard of a 2+2
+        # object whatever its rotation (benchmark/selfcheck.py)
+        for i in (1, 3):
+            shutil.rmtree(os.path.join(str(tmp), f"d{i}", "bkt", "obj"))
+        before = stagestats.snapshot()
+        root = tracing.begin_request("get_object")
+        _, stream = api.get_object("bkt", "obj")
+        body = b"".join(stream)
+        get_doc = tracing.end_request(root)
+        get_stages = _delta(before, stagestats.snapshot())
+        device_stages = _device_stream(tmp)
+    finally:
+        jax.profiler.stop_trace()
+        mp.undo()
+    assert body == data
+    events = bench_trace.load_events(
+        bench_trace.find_xplane(str(tmp / "trace")))
+    return types.SimpleNamespace(
+        host_spans={name for name, _, _ in events["host"]},
+        put_doc=put_doc, get_doc=get_doc, get_stages=get_stages,
+        device_stages=device_stages, total=stagestats.snapshot())
+
+
+@pytest.mark.parametrize("stage", sorted(set(HOST_PUT + HOST_GET + DEVICE)))
+def test_leaf_lies_in_the_profile_and_counts(traced, stage):
+    assert f"dp.{stage}" in traced.host_spans
+    row = traced.total[stage]
+    assert row["seconds"] > 0
+    # the clock of the union runs only while a thread is inside
+    assert 0 < row["wall"] <= row["seconds"] + 1e-9
+
+
+@pytest.mark.parametrize("stage", sorted(stagestats.PARENTS) + ["compile"])
+def test_parent_and_compile_write_no_span(traced, stage):
+    """A span around other spans would take every idle gap's name; a
+    span named compile would count as a compilation."""
+    assert f"dp.{stage}" not in traced.host_spans
+    assert stage in stagestats.STAGES  # a counter all the same
+
+
+def test_decode_is_booked_though_it_has_no_span(traced):
+    assert traced.get_stages["decode"]["seconds"] > 0
+    assert traced.total["encode"]["seconds"] > 0
+
+
+def test_span_names_honour_the_benchmarks_readers(traced):
+    ours = {n for n in traced.host_spans if n.startswith(("dp.", "drive."))}
+    assert {"drive.read_version", "drive.rename_data",
+            "drive.read_file_stream"} <= ours
+    for name in ours:
+        assert not bench_trace.COMPILE_SPAN.search(name), name
+        assert name != serve.MARK
+        assert len(name) <= 80 and " = " not in name
+    # the scrape of benchmark/server.py reads stage labels as \w+
+    assert all(s.isidentifier() for s in stagestats.STAGES)
+
+
+@pytest.mark.parametrize("path,leaves", [
+    ("get_stages", ("read_wait", "assemble", "host_codec")),
+    ("device_stages", ("read_wait", "assemble", "h2d", "launch", "fetch")),
+])
+def test_owning_threads_leaves_close_on_decode(traced, path, leaves):
+    """On the stream's own thread the leaves do not nest and leave
+    little of `decode` unnamed (a loose limit: no test of the box)."""
+    stages = getattr(traced, path)
+    decode = stages["decode"]["seconds"]
+    named = sum(stages[s]["seconds"] for s in leaves)
+    assert all(stages[s]["seconds"] > 0 for s in leaves)
+    assert 0.8 * decode <= named <= decode
+    if path == "device_stages":
+        assert stages["host_codec"]["seconds"] == 0
+
+
+@pytest.mark.parametrize("doc,leaves", [("put_doc", HOST_PUT),
+                                        ("get_doc", HOST_GET)])
+def test_captured_request_holds_leaf_spans(traced, doc, leaves):
+    doc = getattr(traced, doc)
+    names = {s["name"] for s in doc["spans"]}
+    assert {f"dp.{s}" for s in leaves} <= names
+    assert not {"dp.decode", "dp.encode"} & names
+    # per-request seconds stay, parents among them
+    assert set(leaves) <= set(doc["stages"])
+    assert {"encode", "decode"} & set(doc["stages"])
+    # the trace's start on the profiler's clock places its spans there
+    assert 0 < doc["startMonotonic"] <= time.perf_counter()
+    (root,) = [s for s in doc["spans"] if s["parent"] is None]
+    for s in doc["spans"]:
+        if s["name"].startswith("dp."):
+            assert s["parent"] == root["id"]
+            assert 0 <= s["t0"] <= root["dur"] + 1e-3
+
+
+def test_wall_time_union_under_threads():
+    """More threads than cores inside one stage: the union never passes
+    the thread-seconds, counts an overlap once and leaves nobody inside."""
+    before = stagestats.snapshot()["verify"]
+    start = threading.Barrier(8)
+
+    def work():
+        start.wait(timeout=10)
+        for _ in range(200):
+            with stagestats.timed("verify", 3):
+                pass
+        with stagestats.timed("verify", 3):
+            time.sleep(0.02)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        elapsed = time.perf_counter() - t0
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    after = stagestats.snapshot()["verify"]
+    assert stagestats._inside["verify"] == 0
+    assert after["bytes"] - before["bytes"] == 8 * 201 * 3
+    seconds = after["seconds"] - before["seconds"]
+    wall = after["wall"] - before["wall"]
+    assert seconds >= 8 * 0.02
+    assert 0.02 <= wall <= min(seconds, elapsed) + 1e-9
+
+
+def test_compile_seconds_are_a_counter():
+    import jax
+    import jax.numpy as jnp
+
+    from minio_tpu.server.__main__ import _count_compile_seconds
+
+    before = stagestats.snapshot()["compile"]
+    _count_compile_seconds()
+    jax.jit(lambda x: x * 26 + 1)(jnp.arange(26)).block_until_ready()
+    after = stagestats.snapshot()["compile"]
+    assert after["seconds"] > before["seconds"]
+    assert after["wall"] == before["wall"]
+
+
+def test_scrape_shows_the_wall_family(traced):
+    from minio_tpu.server.metrics import MetricsMixin
+
+    class _Reg:
+        def render(self):
+            return ""
+
+    text = MetricsMixin._render_metrics(
+        types.SimpleNamespace(metrics=_Reg(), api=None))
+    for family in ("seconds", "bytes", "wall_seconds"):
+        for stage in ("read_wait", "fetch", "commit", "admit", "compile"):
+            assert (f'minio_dataplane_stage_{family}_total'
+                    f'{{stage="{stage}"}} ') in text
