@@ -34,7 +34,10 @@ assemble + the codec's leaves on the stream's own thread; `encode` =
 host_codec, or h2d + launch + fetch): they keep their counters and their
 per-request seconds and write no span.  `add()` books a reading taken
 elsewhere (the admission wait, a compile's duration, bytes that arrived)
-into the counters alone.
+into the counters alone; so does the body pipe for what it waited and
+worked inside `read`, once a call and not once a chunk, and `dp.read`
+stays the one span of a batch's body (thousands of chunk-long spans
+would name no gap and fill a request's tree).
 """
 
 from __future__ import annotations
@@ -66,6 +69,11 @@ STAGES = (
     "assemble", "h2d", "launch", "fetch", "host_codec",
     # quorum write / read of xl.meta; signature + policy; admission wait
     "commit", "meta_read", "auth", "admit",
+    # inside `read`, the HTTP front's body pipe (server/app.py
+    # _QueuePipeReader; counters only, booked once a call): waiting for
+    # the socket's next chunk; the pipe's own work, with the bytes it
+    # moved (over `read`'s bytes: the copies per body byte)
+    "body_wait", "body_copy",
     # seconds in XLA compilation (counter only: benchmark/trace.py counts
     # every host span with "compile" in its name as a compilation)
     "compile",

@@ -247,31 +247,82 @@ class _TeeHashReader(io.RawIOBase):
 
 
 class _QueuePipeReader(io.RawIOBase):
-    """Bridges async body chunks into the sync object layer."""
+    """Bridges async body chunks into the sync object layer: the event
+    loop puts what the socket gave into a bounded queue (16 chunks, so a
+    slow consumer holds the client back), the handler's thread reads.
+
+    What a caller may count on:
+    - `read(n)` returns exactly `n` bytes unless the body ends first,
+      `read(-1)` all that is left, `b""` only at the end of the body (the
+      chunked-signature decoder, the tee hashers and the SSE and
+      compression readers ask with small and large `n`);
+    - `readinto(b)` fills `b` unless the body ends first, straight from
+      the queued chunks; 0 only at the end of the body;
+    - a body byte is moved once on either path: `readinto` copies it
+      into the caller's buffer, `read` joins the collected pieces once.
+      Both copy with the interpreter lock held: a socket's chunk is
+      64-128 KiB, some 10 us of memcpy, and letting go of the lock for
+      that (numpy's assignment) cost more in getting it back than the
+      other streams gained, on the CPU box and on the chip's host;
+    - besides the queue the pipe holds the unread rest of one chunk (a
+      view, no copy) and nothing else.
+
+    Every call books its wait for the socket and its own work into the
+    stage counters `body_wait` and `body_copy` (leaves of `read`)."""
 
     def __init__(self):
         self.q: queue_mod.Queue = queue_mod.Queue(maxsize=16)
-        self.buf = b""
+        self._rest: memoryview | None = None  # of the chunk partly read
+        self._waited = 0.0  # seconds in q.get() since the last _book()
         self.eof = False
 
-    def read(self, n: int = -1) -> bytes:
-        if n < 0:
-            chunks = [self.buf]
-            self.buf = b""
-            while not self.eof:
-                item = self.q.get()
+    def _pieces(self, n: int):
+        """Views of the queued chunks in order, `n` bytes in all (`n` < 0:
+        to the end of the body); fewer only where the body ends."""
+        while n:
+            rest = self._rest
+            if rest is None:
+                if self.eof:
+                    return
+                try:
+                    item = self.q.get_nowait()
+                except queue_mod.Empty:
+                    t0 = time.perf_counter()
+                    item = self.q.get()
+                    self._waited += time.perf_counter() - t0
                 if item is None:
                     self.eof = True
-                    break
-                chunks.append(item)
-            return b"".join(chunks)
-        while len(self.buf) < n and not self.eof:
-            item = self.q.get()
-            if item is None:
-                self.eof = True
-                break
-            self.buf += item
-        out, self.buf = self.buf[:n], self.buf[n:]
+                    return
+                if not item:
+                    continue
+                rest = memoryview(item)
+            if n < 0 or len(rest) <= n:
+                piece, self._rest = rest, None
+            else:
+                piece, self._rest = rest[:n], rest[n:]
+            n -= len(piece)
+            yield piece
+
+    def _book(self, t0: float, moved: int) -> None:
+        spent = time.perf_counter() - t0
+        stagestats.add("body_wait", self._waited)
+        stagestats.add("body_copy", spent - self._waited, moved)
+        self._waited = 0.0
+
+    def readinto(self, b) -> int:
+        t0 = time.perf_counter()
+        dst = memoryview(b).cast("B")
+        got = 0
+        for piece in self._pieces(len(dst)):
+            dst[got:got + len(piece)] = piece
+            got += len(piece)
+        self._book(t0, got)
+        return got
+
+    def read(self, n: int = -1) -> bytes:
+        t0 = time.perf_counter()
+        out = b"".join(self._pieces(-1 if n is None else n))
+        self._book(t0, len(out))
         return out
 
 
