@@ -300,18 +300,23 @@ def steady_state_backend(k: int, m: int,
     """"device" | "mesh" | "host": where a full batch of this geometry's
     blocks is coded under the configured backend — the dispatch
     encode_stream, degraded reads and heal make in steady state.  Under
-    "auto" on a TPU this runs the calibration probe.  A geometry whose
-    shard length the device kernel cannot tile (12+4: ceil(1 MiB / 12)
-    is not a multiple of 8192) answers "host" on any backend but mesh."""
+    "auto" on a TPU this runs the calibration probe.  The rule is by
+    shard length and not by geometry: full-width shards of any length go
+    to the device (12+4's 87,382 bytes too: the dispatch program widens
+    them to the kernel's tile on the device, ops/rs_pallas.py), tail
+    blocks and inline objects stay on the host."""
     e = Erasure(k, m, block_size)
     return _backend_name(
         e._device(block_size * DEVICE_BATCH_BLOCKS, e.shard_size))
 
 
 class _PaddedCodec:
-    """Pads the shard axis of a batch to the codec's steady-state width
-    so one compiled mesh program serves tail blocks too; outputs are
-    sliced back lazily (the JAX array stays async until resolved)."""
+    """Codes a batch at the codec's steady-state shard width, so one
+    compiled mesh program serves tail blocks too: widened on the host
+    before the batch is laid over the mesh (gf256.code_at_width, the
+    same widening the single-chip program does on the device for a
+    shard that is no multiple of its tile); outputs are sliced back
+    lazily (the JAX array stays async until resolved)."""
 
     def __init__(self, inner, s_full: int):
         self.inner = inner
@@ -321,20 +326,19 @@ class _PaddedCodec:
     def backend(self) -> str:
         return getattr(self.inner, "backend", "device")
 
-    def _pad(self, batch: np.ndarray) -> np.ndarray:
-        b, k, s = batch.shape
-        out = np.zeros((b, k, self.s_full), dtype=np.uint8)
-        out[:, :, :s] = batch
-        return out
+    def _pad(self, batch: np.ndarray, widths) -> np.ndarray:
+        b, k, _ = batch.shape
+        with stagestats.timed("pad", b * k * self.s_full):
+            return np.pad(batch, widths)
 
     def encode(self, batch: np.ndarray):
-        s = batch.shape[2]
-        return self.inner.encode(self._pad(batch))[:, :, :s]
+        return gf256.code_at_width(
+            self.inner.encode, batch, self.s_full, self._pad)
 
     def reconstruct(self, batch: np.ndarray, available, wanted):
-        s = batch.shape[2]
-        return self.inner.reconstruct(
-            self._pad(batch), available, wanted)[:, :, :s]
+        return gf256.code_at_width(
+            lambda wide: self.inner.reconstruct(wide, available, wanted),
+            batch, self.s_full, self._pad)
 
 
 class Erasure:
@@ -424,13 +428,14 @@ class Erasure:
                     return _PaddedCodec(codec, self.shard_size)
                 return None
             return codec
-        # The single-chip kernel tiles shards in 8 KiB columns, and only
-        # a geometry's full-width shards go to it: a tail block or an
-        # inline object is one sub-MiB dispatch that cannot amortise a
-        # round trip, and every distinct shard length is another
-        # compile.  (Under "auto" DEVICE_MIN_BYTES already keeps those on
-        # the host; this makes "tpu" agree.)
-        if shard_len != self.shard_size or shard_len % 8192 != 0:
+        # Only a geometry's full-width shards go to the single chip,
+        # whatever their length (the dispatch program widens a shard
+        # that is no multiple of the kernel's tile on the device): a
+        # tail block or an inline object is one sub-MiB dispatch that
+        # cannot amortise a round trip, and every distinct shard length
+        # is another compile.  (Under "auto" DEVICE_MIN_BYTES already
+        # keeps those on the host; this makes "tpu" agree.)
+        if shard_len != self.shard_size:
             return None
         if self.backend == "tpu":
             return _DeviceCodec.get(self.k, self.m, probe=False)
@@ -1078,7 +1083,7 @@ class Erasure:
                     # to per-block gf256.split + stack, which cost two
                     # copies and nfull python round trips)
                     per = -(-bs // self.k)
-                    with stagestats.timed("assemble", nfull * bs):
+                    with stagestats.timed("pad", nfull * bs):
                         batch = np.zeros((nfull, self.k * per),
                                          dtype=np.uint8)
                         batch[:, :bs] = data_arr[: nfull * bs].reshape(
@@ -1183,20 +1188,30 @@ class Erasure:
         return got
 
     def _assemble_data(self, got: dict[int, np.ndarray], nblocks: int,
-                       shard_len: int) -> np.ndarray:
-        """(nblocks, k, shard_len) data shards from k read shards,
-        reconstructing missing data shards in one batched dispatch."""
+                       shard_len: int, block_len: int) -> np.ndarray:
+        """(nblocks, block_len) object bytes from k read shards of
+        blocks that hold block_len bytes each, reconstructing missing
+        data shards in one batched dispatch.  A data shard is copied
+        once, straight to its place in the block; where k does not
+        divide the block, the zeros that fill up the last shards stay
+        behind in that same copy."""
         missing = tuple(i for i in range(self.k) if i not in got)
         shard_bytes = nblocks * shard_len
+        data = np.empty((nblocks, block_len), dtype=np.uint8)
+
+        def place(i: int, rows: np.ndarray) -> None:
+            lo = min(i * shard_len, block_len)
+            hi = min(lo + shard_len, block_len)
+            data[:, lo:hi] = rows[:, :hi - lo]
+
         # `assemble` is the host's copies alone, on both sides of the
         # dispatch and not around it: the codec books its own leaves
         with stagestats.timed("assemble", (
                 self.k - len(missing)
                 + (self.k if missing else 0)) * shard_bytes):
-            data = np.empty((nblocks, self.k, shard_len), dtype=np.uint8)
             for i in range(self.k):
                 if i in got:
-                    data[:, i, :] = got[i]
+                    place(i, got[i])
             if missing:
                 avail = tuple(sorted(got))[: self.k]
                 src = np.stack([got[i] for i in avail], axis=1)
@@ -1204,7 +1219,11 @@ class Erasure:
             rebuilt = self._reconstruct_shards(src, avail, missing)
             with stagestats.timed("assemble", len(missing) * shard_bytes):
                 for j, w in enumerate(missing):
-                    data[:, w, :] = rebuilt[:, j, :]
+                    place(w, rebuilt[:, j, :])
+        if self.k * shard_len != block_len:
+            # the shards' fill never became a copy of its own: the
+            # blocks' bytes, and no host time
+            stagestats.add("pad", 0.0, nblocks * block_len)
         return data
 
     def decode_stream(self, writer, readers: Sequence, offset: int,
@@ -1261,11 +1280,8 @@ class Erasure:
                         readers, broken, block_idx * shard_len,
                         g * shard_len, g, shard_len, pool, prefer,
                     )
-                    data = self._assemble_data(got, g, shard_len)
-                flat = data.reshape(g, self.k * shard_len)
-                if self.k * shard_len != self.block_size:
-                    # k does not divide block_size: drop per-block shard padding
-                    flat = np.ascontiguousarray(flat[:, : self.block_size])
+                    flat = self._assemble_data(
+                        got, g, shard_len, self.block_size)
                 span = g * self.block_size
                 lo = max(offset, block_off) - block_off
                 hi = min(offset + length, block_off + span) - block_off
@@ -1284,8 +1300,8 @@ class Erasure:
                         readers, broken, block_idx * self.shard_size,
                         shard_len, 1, shard_len, pool, prefer,
                     )
-                    data = self._assemble_data(got, 1, shard_len)
-                block = data.reshape(-1)[:cur_size]
+                    block = self._assemble_data(
+                        got, 1, shard_len, cur_size).reshape(-1)
                 lo = max(offset, block_off) - block_off
                 hi = min(offset + length, block_off + cur_size) - block_off
                 if hi > lo:

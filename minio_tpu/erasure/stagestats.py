@@ -67,6 +67,14 @@ STAGES = (
     # to the device, the jit call until it returns, waiting for the
     # device plus read-back; or the host codec's own compute
     "assemble", "h2d", "launch", "fetch", "host_codec",
+    # what exists only because a shard is no multiple of the kernel's
+    # tile or k does not divide the block: zero columns added, made rows
+    # cut back, a shard's fill dropped, with the bytes that passed.
+    # Seconds only where the host copies (a PUT's split of blocks that k
+    # does not divide, the mesh's tail blocks); where the dispatch
+    # program widens on the device or another copy takes the fill along,
+    # the bytes alone
+    "pad",
     # quorum write / read of xl.meta; signature + policy; admission wait
     "commit", "meta_read", "auth", "admit",
     # inside `read`, the HTTP front's body pipe (server/app.py

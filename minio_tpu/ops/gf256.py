@@ -231,6 +231,21 @@ def split(data: bytes | np.ndarray, k: int) -> np.ndarray:
     return padded.reshape(k, per)
 
 
+def code_at_width(code, shards, width: int, pad=np.pad):
+    """Code a (B, K, S) batch with a codec that wants another shard
+    width than S: a kernel that tiles the axis, a program compiled for
+    one shape.  Zero columns are appended up to `width`, the batch is
+    coded, the made rows are cut back to S.  GF(2^8) coding is byte-wise,
+    so zero columns give zero rows and no real column sees them.  The
+    one place this is done: `pad` is numpy's on the host and jax.numpy's
+    inside a jitted program, where the widening is part of the dispatch
+    and costs the host no copy."""
+    s = shards.shape[-1]
+    if s == width:
+        return code(shards)
+    return code(pad(shards, ((0, 0), (0, 0), (0, width - s))))[:, :, :s]
+
+
 def encode_np(shards: np.ndarray, parity: int) -> np.ndarray:
     """Compute parity shards on host: (k, n) uint8 -> (m, n) uint8."""
     k = shards.shape[0]

@@ -174,6 +174,15 @@ def _flat_coding_call(
     )(mat_bits, seed, words)
 
 
+# The shard axis as the byte entry tiles it.
+SHARD_TILE = 4 * _TILE_WORDS
+
+
+def kernel_width(shard_len: int) -> int:
+    """The shard length the kernel sees: the next multiple of its tile."""
+    return -(-shard_len // SHARD_TILE) * SHARD_TILE
+
+
 def _to_words(shards: jax.Array) -> jax.Array:
     """(B, K, S) uint8 -> (B, K, S/4) int32 (little-endian byte packing)."""
     b, k, s = shards.shape
@@ -192,20 +201,32 @@ def _from_words(words: jax.Array) -> jax.Array:
 def _coding_call_bytes(mat_bits: jax.Array, shards: jax.Array, *,
                        interpret: bool = False):
     """The production uint8 entry as ONE program: mat_bits (R8, K8) int8;
-    shards (B, K, S) uint8 -> (B, R, S) uint8.  tests/test_tpu_aot.py
-    compiles exactly this for the v5e, so what the CPU box checks is
-    what the chip runs."""
-    return _from_words(
-        _coding_call(mat_bits, _to_words(shards), interpret=interpret))
+    shards (B, K, S) uint8 -> (B, R, S) uint8, for any S.  The kernel
+    tiles the shard axis in SHARD_TILE bytes; a shard that is no
+    multiple of that (EC 12+4: 87,382 bytes) is widened with zero
+    columns and the made rows cut back inside this program, on the
+    device, so the host hands over and takes back real bytes only.  A
+    tile-aligned S traces neither.  tests/test_tpu_aot.py compiles
+    exactly this for the v5e, so what the CPU box checks is what the
+    chip runs."""
+    def code(wide):
+        return _from_words(
+            _coding_call(mat_bits, _to_words(wide), interpret=interpret))
+
+    return gf256.code_at_width(
+        code, shards, kernel_width(shards.shape[-1]), jnp.pad)
 
 
 class PallasRSCodec:
     """Drop-in faster variant of rs_tpu.TpuRSCodec (same API).
 
-    Requires shard length S to be a multiple of 4*_TILE_WORDS (8192 bytes);
-    the streaming block pipeline always feeds 1 MiB blocks (S = 128 KiB for
-    EC 8+4), so this holds on the hot path.  Callers with odd sizes should
-    use TpuRSCodec, or pad.
+    `encode` and `reconstruct` take shards of any length S.  The kernel
+    tiles the shard axis in 8,192 bytes; where S is no multiple of that
+    (EC 12+4, 14+2, 10+2 at 1 MiB blocks) the dispatch program widens
+    the batch with zero columns and cuts the made rows back on the
+    device (`_coding_call_bytes`), at no copy on the host: the `pad`
+    stage books the bytes with no seconds.  The word and flat entries
+    still want whole tiles.
     """
 
     backend = "device"  # explicit dispatch-stats bucket (ADVICE r5)
@@ -234,12 +255,12 @@ class PallasRSCodec:
         with stagestats.timed("h2d") as span:
             shards = jnp.asarray(shards, dtype=jnp.uint8)
             span.nbytes = shards.nbytes
-        s = shards.shape[-1]
-        if s % (4 * _TILE_WORDS) != 0:
-            raise ValueError(
-                f"shard length {s} not a multiple of {4 * _TILE_WORDS}; "
-                "use TpuRSCodec or pad"
-            )
+        b, k, s = shards.shape
+        if s % SHARD_TILE:
+            # widened and cut back inside the program: the bytes of the
+            # batch the kernel reads and of the rows cut, no host time
+            stagestats.add("pad", 0.0, b * (
+                k * kernel_width(s) + mat.shape[0] // 8 * s))
         with stagestats.timed("launch", shards.nbytes):
             return _coding_call_bytes(mat, shards, interpret=self._interpret)
 

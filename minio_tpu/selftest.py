@@ -116,7 +116,9 @@ def device_self_test(k: int, m: int, block_size: int) -> float:
 
     t0 = time.perf_counter()
     codec = coding._DeviceCodec.get(k, m, probe=False)
-    b, s = coding.DEVICE_BATCH_BLOCKS, block_size // k
+    # a full block's shard (cmd/erasure-coding.go:122): for 12+4 no
+    # multiple of the kernel's tile, which is what has to be seen here
+    b, s = coding.DEVICE_BATCH_BLOCKS, -(-block_size // k)
     batch = np.random.default_rng(k * 256 + m).integers(
         0, 256, size=(b, k, s), dtype=np.uint8)
     parity = np.asarray(codec.encode(batch))

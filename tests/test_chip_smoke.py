@@ -77,22 +77,29 @@ def test_backend_tpu_without_a_tpu_raises():
 def test_only_full_width_shards_leave_the_host(monkeypatch):
     """The single-chip path takes a geometry's full-width shards only: a
     64 KiB inline object (shard 8192, tileable) and a tail block stay on
-    the host codec whatever the backend, and 12+4 never tiles."""
+    the host codec whatever the backend.  The rule is by shard length,
+    not by geometry: 12+4, 14+2 and 10+2, whose full shards are no
+    multiple of the kernel's tile, go to the device like 8+4."""
     from minio_tpu.erasure import coding
 
     class Fake:
         backend = "device"
 
     monkeypatch.setitem(coding._DeviceCodec._cache, (8, 4), (Fake(), True))
-    monkeypatch.setitem(coding._DeviceCodec._cache, (12, 4), (Fake(), True))
     e = Erasure(8, 4, backend="tpu")
     assert e._device(32 << 20, e.shard_size) is not None
     assert e._device(64 << 10, 8192) is None
     assert e._device(512 << 10, 65536) is None
     assert coding.steady_state_backend(4, 2) == "host"  # auto, no TPU
-    e12 = Erasure(12, 4, backend="tpu")
-    assert e12.shard_size % 8192 != 0
-    assert e12._device(32 << 20, e12.shard_size) is None
+    for k, m in ((12, 4), (14, 2), (10, 2)):
+        monkeypatch.setitem(coding._DeviceCodec._cache, (k, m), (Fake(), True))
+        odd = Erasure(k, m, backend="tpu")
+        assert odd.shard_size % 8192 != 0
+        assert odd._device(32 << 20, odd.shard_size) is not None
+        assert odd._device(odd.shard_size // 2 * k, odd.shard_size // 2) is None
+        monkeypatch.setenv("MINIO_TPU_ERASURE_BACKEND", "tpu")
+        assert coding.steady_state_backend(k, m) == "device"
+        monkeypatch.delenv("MINIO_TPU_ERASURE_BACKEND")
 
 
 def test_compile_cache_dir_from_outside_is_the_one_used(tmp_path):

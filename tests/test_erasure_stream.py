@@ -184,6 +184,103 @@ def test_device_codec_stream_roundtrip(tmp_path):
         coding._DeviceCodec._cache.pop((k, m), None)
 
 
+def test_device_codec_stream_at_12_4(tmp_path):
+    """PUT, healthy GET, degraded GET and heal at EC 12+4 with the
+    device codec in place (interpret mode): a full block's shard is
+    87,382 bytes, no multiple of the kernel's 8,192-byte tile, and k does
+    not divide the block.  The device codec must have been dispatched,
+    and no byte past a shard's end may reach a drive."""
+    from minio_tpu.erasure import coding, stagestats
+    from minio_tpu.ops import gf256, rs_pallas
+
+    k, m, bs = 12, 4, 1 << 20
+    codec = _CountingCodec(rs_pallas.PallasRSCodec(k, m, interpret=True))
+    coding._DeviceCodec._cache[(k, m)] = (codec, True)
+    try:
+        e = Erasure(k, m, bs, backend="tpu")
+        assert e.shard_size == 87382
+        # the rule is by shard length, and every full-width one goes
+        assert coding.steady_state_backend(k, m) == "device"
+        assert e._device(32 * bs, e.shard_size) is codec
+        size = 2 * bs + 12345  # 2 full blocks through the kernel + host tail
+        payload = np.random.default_rng(12).integers(
+            0, 256, size=size, dtype=np.uint8).tobytes()
+        paths = [tmp_path / f"shard{i}" for i in range(k + m)]
+        writers = [bitrot.BitrotWriter(open(p, "wb"), e.shard_size)
+                   for p in paths]
+        n, failed = e.encode_stream(io.BytesIO(payload), writers, size, k + 1)
+        assert n == size and not failed
+        for w in writers:
+            w.close()
+        assert codec.encodes == 1
+
+        # the drives hold the reference's shards and not a byte more
+        tail = -(-12345 // k)
+        blocks = np.zeros((2, k * e.shard_size), np.uint8)
+        blocks[:, :bs] = np.frombuffer(payload[:2 * bs], np.uint8).reshape(2, bs)
+        blocks = blocks.reshape(2, k, e.shard_size)
+        last = gf256.split(payload[2 * bs:], k)
+        originals = [p.read_bytes() for p in paths]
+        for i, raw in enumerate(originals):
+            assert len(raw) == 2 * (32 + e.shard_size) + 32 + tail
+            rows = [gf256.encode_np(blocks[b], m)[i - k] if i >= k
+                    else blocks[b, i] for b in range(2)]
+            rows.append(gf256.encode_np(last, m)[i - k] if i >= k else last[i])
+            at = 0
+            for row in rows:
+                assert raw[at + 32:at + 32 + row.size] == row.tobytes(), i
+                at += 32 + row.size
+
+        till = e.shard_file_size(size)
+
+        def readers(gone=()):
+            return [None if i in gone else bitrot.BitrotReader(
+                open(paths[i], "rb"), till, e.shard_size)
+                for i in range(k + m)]
+
+        pad0 = stagestats.snapshot()["pad"]
+        out = io.BytesIO()
+        assert e.decode_stream(out, readers(), 0, size, size) == size
+        assert out.getvalue() == payload
+        assert codec.reconstructs == 0  # a healthy read codes nothing
+        pad1 = stagestats.snapshot()["pad"]
+        # the shards' fill dropped in the assemble's own copy: the two
+        # full blocks and the tail, whose 12345 bytes 12 does not divide
+        assert pad1["bytes"] - pad0["bytes"] == size
+        assert pad1["seconds"] == pad0["seconds"]
+
+        # degraded: one data and one parity shard gone, then two data
+        for gone, rebuilt in (((1, 13), 1), ((0, 11), 2)):
+            before = codec.reconstructs
+            out = io.BytesIO()
+            assert e.decode_stream(out, readers(gone), 0, size, size) == size
+            assert out.getvalue() == payload, gone
+            assert codec.reconstructs == before + 1  # the tail: host
+        # a range that starts and ends inside blocks
+        out = io.BytesIO()
+        assert e.decode_stream(out, readers((0, 11)), bs - 7, bs + 99,
+                               size) == bs + 99
+        assert out.getvalue() == payload[bs - 7:2 * bs + 92]
+
+        # heal three shards zeroed (cmd/erasure-heal_test.go at 12+4)
+        stale = (2, 7, 14)
+        for i in stale:
+            os.remove(paths[i])
+        before = codec.reconstructs
+        heal_writers = [
+            bitrot.BitrotWriter(open(paths[i], "wb"), e.shard_size)
+            if i in stale else None for i in range(k + m)]
+        e.heal(heal_writers, readers(stale), size)
+        for w in heal_writers:
+            if w:
+                w.close()
+        assert codec.reconstructs == before + 1
+        for i in stale:
+            assert paths[i].read_bytes() == originals[i], f"shard {i}"
+    finally:
+        coding._DeviceCodec._cache.pop((k, m), None)
+
+
 def test_bitrot_file_size_math():
     e = Erasure(8, 4)
     assert bitrot.bitrot_shard_file_size(0, e.shard_size) == 0

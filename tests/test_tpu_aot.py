@@ -35,6 +35,11 @@ PROGRAMS = [
     "gf_bitmatmul_8+4_B32",
     "fused_encode_hash_8+4_B32",
     "md5_scan_32MiB",
+    # EC 12+4: K no power of two in the kernel's unpack, shards of
+    # 87,382 bytes widened to whole tiles inside the program
+    "pallas_encode_12+4_B32",
+    "pallas_reconstruct_12+4_r1_B32",
+    "pallas_reconstruct_12+4_r2_B32",
 ]
 
 
@@ -58,7 +63,7 @@ def _compile_all() -> dict:
         return spec((r * 8, k * 8), jnp.int8)
 
     def shards(k):
-        return spec((B, k, BLOCK // k), jnp.uint8)
+        return spec((B, k, -(-BLOCK // k)), jnp.uint8)
 
     words = spec((B, 8, BLOCK // 8 // 4), jnp.int32)
     programs = {
@@ -83,6 +88,12 @@ def _compile_all() -> dict:
             (hh_device._md5_scan_fn(),
              (spec((4,), jnp.uint32),
               spec((B * BLOCK // 64, 16), jnp.uint32))),
+        "pallas_encode_12+4_B32":
+            (rs_pallas._coding_call_bytes, (mat(4, 12), shards(12))),
+        "pallas_reconstruct_12+4_r1_B32":
+            (rs_pallas._coding_call_bytes, (mat(1, 12), shards(12))),
+        "pallas_reconstruct_12+4_r2_B32":
+            (rs_pallas._coding_call_bytes, (mat(2, 12), shards(12))),
     }
     assert sorted(programs) == sorted(PROGRAMS)
     out = {"device_kind": topo.devices[0].device_kind}
