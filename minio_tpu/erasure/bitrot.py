@@ -266,20 +266,45 @@ class BitrotReader:
             raise errors.FileCorrupt("bitrot: truncated frame group")
         return raw
 
-    def read_blocks(self, offset: int, nblocks: int, block_len: int) -> np.ndarray:
-        """Read + verify `nblocks` frames of `block_len` logical bytes each
-        starting at logical `offset` in ONE file read and ONE batched hash
-        call, returning a (nblocks, block_len) uint8 view into the frame
-        buffer (rows strided past the interleaved hashes — zero extra
-        copies).  block_len == shard_size except for a stream's final
-        short block (then nblocks must be 1)."""
-        frame = self._hsize + block_len
-        want = nblocks * frame
-        with stagestats.timed("shard_read", want):
-            raw = self._read_frames(offset, want)
-        arr = np.frombuffer(raw, dtype=np.uint8).reshape(nblocks, frame)
-        hashes = arr[:, : self._hsize]
-        blocks = arr[:, self._hsize:]
+    def _read_rows(self, offset: int, out: np.ndarray
+                   ) -> np.ndarray | None:
+        """The frames from logical `offset` read apart, two readinto
+        calls a frame: each hash into a row of a small array, each block
+        straight into its row of `out`.  Returns the hashes, or None
+        where the stream has no readinto (remote RPC shard streams)."""
+        ri = None if getattr(self, "_no_readinto", False) \
+            else getattr(self.r, "readinto", None)
+        if ri is None:
+            return None
+        nblocks, block_len = out.shape
+        hashes = np.empty((nblocks, self._hsize), dtype=np.uint8)
+        self._seek_to(offset)
+        # the stream moves from here on: until the rows are verified it
+        # stands nowhere the next read may rely on
+        self._pos = -1
+        with stagestats.timed("shard_read",
+                              nblocks * (self._hsize + block_len)):
+            try:
+                for i in range(nblocks):
+                    for seg in (hashes[i].data, out[i].data):
+                        got = 0
+                        while got < len(seg):
+                            n = ri(seg[got:])
+                            if not n:
+                                raise errors.FileCorrupt(
+                                    "bitrot: truncated frame group")
+                            got += n
+            except (NotImplementedError, io.UnsupportedOperation):
+                # RawIOBase subclasses that only implement read(): see
+                # _read_frames
+                self._no_readinto = True
+                return None
+        return hashes
+
+    def _verify(self, blocks: np.ndarray, hashes: np.ndarray) -> None:
+        """One batched hash call over the (possibly strided) rows
+        against their frames' hashes; FileCorrupt where any differs."""
+        nblocks, block_len = blocks.shape
         with stagestats.timed("verify", nblocks * block_len):
             try:
                 batched = (
@@ -298,8 +323,49 @@ class BitrotReader:
                 )
         if not ok:
             raise errors.FileCorrupt("bitrot: hash mismatch")
+
+    def read_blocks(self, offset: int, nblocks: int, block_len: int,
+                    out: np.ndarray | None = None) -> np.ndarray:
+        """Read + verify `nblocks` frames of `block_len` logical bytes each
+        starting at logical `offset` in ONE file read and ONE batched hash
+        call, returning a (nblocks, block_len) uint8 view into the frame
+        buffer (rows strided past the interleaved hashes — zero extra
+        copies).  block_len == shard_size except for a stream's final
+        short block (then nblocks must be 1).
+
+        With `out`, a (nblocks, block_len) uint8 array whose rows are
+        contiguous (any row stride: one shard's column of a dispatch's
+        (B, K, S) batch), the rows are read where the caller wants them
+        and `out` is returned: no frame buffer, no copy (_read_rows).
+        Verified before the call returns like any other read; a stream
+        that can place no rows is read as without `out` and copied."""
+        if out is not None:
+            if (out.dtype != np.uint8 or out.shape != (nblocks, block_len)
+                    or not out.flags.writeable
+                    or (block_len and out.strides[1] != 1)
+                    or (nblocks > 1 and out.strides[0] < block_len)):
+                raise ValueError(
+                    f"read_blocks: out must be a writable ({nblocks}, "
+                    f"{block_len}) uint8 array of contiguous rows")
+            hashes = self._read_rows(offset, out)
+            if hashes is not None:
+                self._verify(out, hashes)
+                self._pos = offset + nblocks * block_len
+                stagestats.add("staged", 0.0, out.size)
+                return out
+        frame = self._hsize + block_len
+        want = nblocks * frame
+        with stagestats.timed("shard_read", want):
+            raw = self._read_frames(offset, want)
+        arr = np.frombuffer(raw, dtype=np.uint8).reshape(nblocks, frame)
+        blocks = arr[:, self._hsize:]
+        self._verify(blocks, arr[:, : self._hsize])
         self._pos = offset + nblocks * block_len
-        return blocks
+        if out is None:
+            return blocks
+        with stagestats.timed("assemble", blocks.size):
+            out[:] = blocks
+        return out
 
     def read_at_ranges(self, runs, block_len: int | None = None
                        ) -> dict[int, np.ndarray]:

@@ -119,6 +119,10 @@ def _arena_acquire(nbytes: int) -> np.ndarray:
 def _arena_release(arr: np.ndarray) -> None:
     # lint: allow(shared-state): per-process arena pool by design — see _arena_acquire
     global _arena_pool_bytes
+    if arr.ndim != 1:
+        # the pool hands out flat arrays, whatever shape a user gave
+        # its arena (contiguous: a view)
+        arr = arr.reshape(-1)
     with _arena_lock:
         if arr.nbytes > _ARENA_POOL_MAX_BYTES:
             return
@@ -1130,8 +1134,9 @@ class Erasure:
     def _read_group(self, readers: Sequence, broken: set[int],
                     shard_off: int, read_len: int, nblocks: int,
                     shard_len: int, pool,
-                    prefer: Sequence[int] | None = None
-                    ) -> dict[int, np.ndarray]:
+                    prefer: Sequence[int] | None = None,
+                    rebuild: bool = False
+                    ) -> tuple[dict[int, np.ndarray], np.ndarray | None]:
         """Read one group of `nblocks` consecutive shard blocks from the
         first k healthy readers, work-stealing to spare drives on failure
         (parallelReader.Read trigger channels, cmd/erasure-decode.go:101).
@@ -1140,7 +1145,15 @@ class Erasure:
         drives last so the first k reads route around them); default is
         shard-index order.
 
-        Returns {shard_index: (nblocks, shard_len) uint8}; exactly k entries.
+        Returns ({shard_index: (nblocks, shard_len) uint8}, arena);
+        exactly k entries.  Where the first k candidates hold every data
+        shard and the caller asks for no `rebuild`, arena is None and
+        the rows are views of the readers' frame buffers.  Where shards
+        will have to be made (a staged read), arena is a pooled
+        (nblocks, k, shard_len) batch for the codec, entry j of the
+        dict is its column arena[:, j, :], read straight into it, and
+        the dict's order is the `available` tuple of the dispatch.  The
+        caller gives the arena back (_arena_release).
         """
         n = self.k + self.m
         got: dict[int, np.ndarray] = {}
@@ -1154,47 +1167,85 @@ class Erasure:
         except StopIteration:
             raise errors.ErasureReadQuorum("not enough shard streams")
 
-        def read_one(r):
+        arena = None
+        if rebuild or any(i >= self.k for i in active):
+            # a tail block's arena (under block_size + k bytes) is a
+            # one-off size class like a small PUT's slot: the pool's LRU
+            # evicts those before the full groups' class, and the copy
+            # that a tail would take instead asks the pool for the same
+            arena = _arena_acquire(nblocks * self.k * shard_len).reshape(
+                nblocks, self.k, shard_len)
+            # columns ascending, as every codec's matrices have been
+            # keyed; a spare takes the failed read's column
+            active.sort()
+        column = {i: j for j, i in enumerate(active)}
+
+        def read_one(r, out):
             rb = getattr(r, "read_blocks", None)
             if rb is not None:
-                # one file read + one batched hash verify, rows returned as
-                # a zero-copy strided view of the frame buffer
-                return rb(shard_off, nblocks, shard_len)
-            return np.frombuffer(r.read_at(shard_off, read_len),
+                # one file read + one batched hash verify; the rows a
+                # zero-copy strided view of the frame buffer, or read
+                # straight into the column they were given
+                return rb(shard_off, nblocks, shard_len, out)
+            rows = np.frombuffer(r.read_at(shard_off, read_len),
                                  dtype=np.uint8).reshape(nblocks, shard_len)
+            if out is None:
+                return rows
+            with stagestats.timed("assemble", rows.size):
+                out[:] = rows
+            return out
 
-        while len(got) < self.k:
-            futs = {
-                i: ctx_submit(pool, read_one, readers[i])
-                for i in active
-            }
-            active = []
-            # this thread's wait for the pool to bring the shards, the
-            # queueing in the pool included; the drives' own time is
-            # booked there as shard_read and verify (erasure/bitrot.py)
-            with stagestats.timed("read_wait",
-                                  len(futs) * nblocks * shard_len):
-                for i, fut in futs.items():
-                    try:
-                        got[i] = fut.result()
-                    except Exception:
-                        broken.add(i)
+        futs: dict[int, cf.Future] = {}
+        try:
+            while len(got) < self.k:
+                futs = {
+                    i: ctx_submit(
+                        pool, read_one, readers[i],
+                        None if arena is None else arena[:, column[i], :])
+                    for i in active
+                }
+                active = []
+                # this thread's wait for the pool to bring the shards, the
+                # queueing in the pool included; the drives' own time is
+                # booked there as shard_read and verify (erasure/bitrot.py)
+                with stagestats.timed("read_wait",
+                                      len(futs) * nblocks * shard_len):
+                    for i, fut in futs.items():
                         try:
-                            active.append(next(idx_iter))
-                        except StopIteration:
-                            raise errors.ErasureReadQuorum(
-                                f"shard {i} failed and no spare drives "
-                                f"remain")
-        return got
+                            got[i] = fut.result()
+                        except Exception:
+                            broken.add(i)
+                            try:
+                                spare = next(idx_iter)
+                            except StopIteration:
+                                raise errors.ErasureReadQuorum(
+                                    f"shard {i} failed and no spare drives "
+                                    f"remain")
+                            active.append(spare)
+                            column[spare] = column.pop(i)
+        except BaseException:
+            if arena is not None:
+                # no read may still be filling a column when the arena
+                # goes back to the pool
+                cf.wait(list(futs.values()))
+                _arena_release(arena)
+            raise
+        if arena is not None:
+            got = {i: got[i] for i in sorted(got, key=column.__getitem__)}
+        return got, arena
 
-    def _assemble_data(self, got: dict[int, np.ndarray], nblocks: int,
+    def _assemble_data(self, got: dict[int, np.ndarray],
+                       arena: np.ndarray | None, nblocks: int,
                        shard_len: int, block_len: int) -> np.ndarray:
-        """(nblocks, block_len) object bytes from k read shards of
-        blocks that hold block_len bytes each, reconstructing missing
-        data shards in one batched dispatch.  A data shard is copied
-        once, straight to its place in the block; where k does not
-        divide the block, the zeros that fill up the last shards stay
-        behind in that same copy."""
+        """(nblocks, block_len) object bytes from what _read_group
+        brought: k read shards of blocks that hold block_len bytes each
+        and, for a staged read, the arena they lie in.  Missing data
+        shards are reconstructed in one batched dispatch of that arena
+        as it is.  A data shard is copied once, straight to its place
+        in the block; where k does not divide the block, the zeros that
+        fill up the last shards stay behind in that same copy.  The
+        block is a fresh array that the writer owns; the arena goes
+        back to the pool on every exit."""
         missing = tuple(i for i in range(self.k) if i not in got)
         shard_bytes = nblocks * shard_len
         data = np.empty((nblocks, block_len), dtype=np.uint8)
@@ -1204,22 +1255,32 @@ class Erasure:
             hi = min(lo + shard_len, block_len)
             data[:, lo:hi] = rows[:, :hi - lo]
 
-        # `assemble` is the host's copies alone, on both sides of the
-        # dispatch and not around it: the codec books its own leaves
-        with stagestats.timed("assemble", (
-                self.k - len(missing)
-                + (self.k if missing else 0)) * shard_bytes):
-            for i in range(self.k):
-                if i in got:
-                    place(i, got[i])
+        try:
+            # `assemble` is the host's copies alone, on both sides of the
+            # dispatch and not around it: the codec books its own leaves
+            with stagestats.timed(
+                    "assemble", (self.k - len(missing)) * shard_bytes) as span:
+                for i in range(self.k):
+                    if i in got:
+                        place(i, got[i])
+                if missing and arena is None:
+                    # the group turned degraded after its reads began (a
+                    # frame failed its hash, a drive timed out): what
+                    # was read into frame buffers is copied to an arena
+                    arena = _arena_acquire(self.k * shard_bytes).reshape(
+                        nblocks, self.k, shard_len)
+                    got = {i: got[i] for i in sorted(got)}
+                    for j, rows in enumerate(got.values()):
+                        arena[:, j, :] = rows
+                    span.nbytes += self.k * shard_bytes
             if missing:
-                avail = tuple(sorted(got))[: self.k]
-                src = np.stack([got[i] for i in avail], axis=1)
-        if missing:
-            rebuilt = self._reconstruct_shards(src, avail, missing)
-            with stagestats.timed("assemble", len(missing) * shard_bytes):
-                for j, w in enumerate(missing):
-                    place(w, rebuilt[:, j, :])
+                rebuilt = self._reconstruct_shards(arena, tuple(got), missing)
+                with stagestats.timed("assemble", len(missing) * shard_bytes):
+                    for j, w in enumerate(missing):
+                        place(w, rebuilt[:, j, :])
+        finally:
+            if arena is not None:
+                _arena_release(arena)
         if self.k * shard_len != block_len:
             # the shards' fill never became a copy of its own: the
             # blocks' bytes, and no host time
@@ -1276,12 +1337,12 @@ class Erasure:
                 )
                 shard_len = self.shard_size
                 with stagestats.timed("decode", g * self.block_size):
-                    got = self._read_group(
+                    got, arena = self._read_group(
                         readers, broken, block_idx * shard_len,
                         g * shard_len, g, shard_len, pool, prefer,
                     )
                     flat = self._assemble_data(
-                        got, g, shard_len, self.block_size)
+                        got, arena, g, shard_len, self.block_size)
                 span = g * self.block_size
                 lo = max(offset, block_off) - block_off
                 hi = min(offset + length, block_off + span) - block_off
@@ -1296,12 +1357,12 @@ class Erasure:
                 # tail block (shorter shard length)
                 shard_len = -(-cur_size // self.k)
                 with stagestats.timed("decode", cur_size):
-                    got = self._read_group(
+                    got, arena = self._read_group(
                         readers, broken, block_idx * self.shard_size,
                         shard_len, 1, shard_len, pool, prefer,
                     )
                     block = self._assemble_data(
-                        got, 1, shard_len, cur_size).reshape(-1)
+                        got, arena, 1, shard_len, cur_size).reshape(-1)
                 lo = max(offset, block_off) - block_off
                 hi = min(offset + length, block_off + cur_size) - block_off
                 if hi > lo:
@@ -1339,17 +1400,19 @@ class Erasure:
                 cur_size = total_length - block_idx * self.block_size
                 shard_len = -(-cur_size // self.k)
             try:
-                got = self._read_group(
+                # a heal always reconstructs: the staged read of a
+                # degraded GET, whatever shards the first k hold
+                got, arena = self._read_group(
                     readers, broken, block_idx * self.shard_size,
                     g * shard_len if shard_len == self.shard_size else shard_len,
-                    g, shard_len, pool,
+                    g, shard_len, pool, rebuild=True,
                 )
             except errors.ErasureReadQuorum:
                 raise errors.ErasureReadQuorum("healing read quorum lost")
-            avail = tuple(sorted(got))[: self.k]
-            with stagestats.timed("assemble", self.k * g * shard_len):
-                src = np.stack([got[i] for i in avail], axis=1)
-            rebuilt = self._reconstruct_shards(src, avail, wanted)
+            try:
+                rebuilt = self._reconstruct_shards(arena, tuple(got), wanted)
+            finally:
+                _arena_release(arena)
             for j, w in enumerate(wanted):
                 wf = getattr(writers[w], "write_frames", None)
                 if wf is not None:

@@ -1949,8 +1949,9 @@ class _LazyShardReader:
                 self._inner = self._open_fn(self._idx)  # may raise: steal
             return self._inner                          # marks it broken
 
-    def read_blocks(self, offset: int, nblocks: int, block_len: int):
-        return self._resolve().read_blocks(offset, nblocks, block_len)
+    def read_blocks(self, offset: int, nblocks: int, block_len: int,
+                    out=None):
+        return self._resolve().read_blocks(offset, nblocks, block_len, out)
 
     def read_at(self, offset: int, length: int) -> bytes:
         return self._resolve().read_at(offset, length)

@@ -437,9 +437,10 @@ class CountingReader:
     def shard_size(self) -> int:
         return self._inner.shard_size
 
-    def read_blocks(self, offset: int, nblocks: int, block_len: int):
+    def read_blocks(self, offset: int, nblocks: int, block_len: int,
+                    out=None):
         self._acct(nblocks * (self._hsize + block_len))
-        return self._inner.read_blocks(offset, nblocks, block_len)
+        return self._inner.read_blocks(offset, nblocks, block_len, out)
 
     def read_at(self, offset: int, length: int) -> bytes:
         if length > 0:

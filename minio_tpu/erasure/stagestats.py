@@ -67,6 +67,11 @@ STAGES = (
     # to the device, the jit call until it returns, waiting for the
     # device plus read-back; or the host codec's own compute
     "assemble", "h2d", "launch", "fetch", "host_codec",
+    # bytes a drive's read put straight into a dispatch's staging arena
+    # (erasure/bitrot.py read_blocks(out=)), so that no host copy stands
+    # between the read and the device (counter only, no seconds of its
+    # own: shard_read and verify hold them)
+    "staged",
     # what exists only because a shard is no multiple of the kernel's
     # tile or k does not divide the block: zero columns added, made rows
     # cut back, a shard's fill dropped, with the bytes that passed.

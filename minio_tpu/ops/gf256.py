@@ -154,17 +154,19 @@ def parity_matrix(data: int, parity: int) -> np.ndarray:
 def decode_matrix(data: int, parity: int, available: tuple[int, ...]) -> np.ndarray:
     """Matrix reconstructing ALL data shards from `data` available shards.
 
-    `available` lists >= data shard indices (0..data+parity-1) that survive,
-    in increasing order.  Returns (data x data) matrix D such that
+    `available` lists >= data distinct shard indices (0..data+parity-1)
+    that survive, in the order the caller stacks them (ascending as a
+    rule; a degraded read whose spare took a failed read's column hands
+    them in unsorted).  Returns (data x data) matrix D such that
     data_shards = D @ available_shards[:data].
 
     Mirrors reedsolomon.Reconstruct's subMatrix-invert step.
     """
     if len(available) < data:
         raise ValueError("not enough shards to reconstruct")
-    if list(available) != sorted(available):
-        raise ValueError("available shard indices must be sorted ascending")
     rows = list(available)[:data]
+    if len(set(rows)) != data:
+        raise ValueError("available shard indices must be distinct")
     full = coding_matrix(data, data + parity)
     sub = full[list(rows), :]
     return gf_mat_inv(sub)

@@ -50,7 +50,7 @@ def reconstruct_bits_matrix(
     k: int, m: int, available: tuple[int, ...], wanted: tuple[int, ...]
 ) -> np.ndarray:
     """(len(wanted)*8, k*8) bit matrix rebuilding `wanted` shards from the
-    first k shards of `available` (sorted ascending).
+    first k shards of `available`, columns in the order given.
 
     Bounded: the (available, wanted) signature space is combinatorial, so
     churny degraded reads with varying survivor sets would otherwise grow
@@ -148,10 +148,10 @@ class TpuRSCodec:
         """Rebuild `wanted` shards from surviving shards.
 
         src_shards: (B, K, S) uint8 — the first K *available* shards,
-            stacked in ascending index order (the caller reads only K of
-            the N shard streams, mirroring parallelReader's first-K-of-N
-            at cmd/erasure-decode.go:101).
-        available:  sorted tuple of surviving shard indices (>= K of them).
+            stacked in the order `available` names them (the caller
+            reads only K of the N shard streams, mirroring
+            parallelReader's first-K-of-N at cmd/erasure-decode.go:101).
+        available:  tuple of surviving shard indices (>= K of them).
         wanted:     tuple of shard indices to rebuild (data and/or parity).
         returns:    (B, len(wanted), S) uint8.
         """

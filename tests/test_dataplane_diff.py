@@ -339,6 +339,166 @@ class TestReadAtRegression:
             r.read_at(0, len(payload) + shard)
 
 
+class _ReadOnlyStream(io.RawIOBase):
+    """A remote shard stream's shape: read() and seek(), no readinto."""
+
+    def __init__(self, data):
+        self._b = io.BytesIO(data)
+
+    def read(self, n=-1):
+        return self._b.read(n)
+
+    def seek(self, off, whence=0):
+        return self._b.seek(off, whence)
+
+
+class _DirectLike:
+    """The O_DIRECT staging reader's shape: readinto, no descriptor."""
+
+    def __init__(self, data):
+        self._b = io.BytesIO(data)
+        self.readinto = self._b.readinto
+        self.read = self._b.read
+        self.seek = self._b.seek
+        self.close = self._b.close
+
+
+class TestReadBlocksOut:
+    """read_blocks(out=): the rows of a frame group read where the
+    caller wants them (ISSUE 29: a column of a dispatch's batch), by
+    every kind of stream, verified like any other read."""
+
+    NBLOCKS, SHARD = 8, 1000
+
+    def _blob(self):
+        rng = np.random.default_rng(29)
+        payload = rng.integers(0, 256, self.NBLOCKS * self.SHARD,
+                               dtype=np.uint8).tobytes()
+        buf = _KeepOpen()
+        w = bitrot.BitrotWriter(buf, self.SHARD)
+        for i in range(self.NBLOCKS):
+            w.write(payload[i * self.SHARD:(i + 1) * self.SHARD])
+        return payload, buf.getvalue()
+
+    def _stream(self, kind, blob, tmp_path):
+        if kind in ("file", "odirect"):
+            (tmp_path / "part.1").write_bytes(blob)
+            if kind == "file":  # a drive whose file system refuses O_DIRECT
+                return open(tmp_path / "part.1", "rb")
+            from minio_tpu.storage import local
+            try:  # what a drive hands out where the file system allows it
+                return local._DirectReader(str(tmp_path / "part.1"))
+            except OSError as e:
+                pytest.skip(f"no O_DIRECT under {tmp_path}: {e}")
+        return {"bytesio": io.BytesIO, "readinto": _DirectLike,
+                "read_only": _ReadOnlyStream}[kind](blob)
+
+    @pytest.mark.parametrize("kind", ["file", "odirect", "bytesio",
+                                      "readinto", "read_only"])
+    def test_rows_land_in_out(self, kind, tmp_path):
+        from minio_tpu.erasure import stagestats
+
+        payload, blob = self._blob()
+        r = bitrot.BitrotReader(self._stream(kind, blob, tmp_path),
+                                len(payload), self.SHARD)
+        # one shard's column of a (B, K, S) batch: rows strided
+        batch = np.zeros((4, 3, self.SHARD), np.uint8)
+        before = stagestats.snapshot()["staged"]["bytes"]
+        for first, row in ((6, 2), (0, 0)):  # a jump back between them
+            got = r.read_blocks(first * self.SHARD, 2, self.SHARD,
+                                out=batch[row:row + 2, 1, :])
+            assert np.shares_memory(got, batch)
+        # in turn with the last one: no seek between them
+        r.read_blocks(2 * self.SHARD, 2, self.SHARD,
+                      out=np.empty((2, self.SHARD), np.uint8))
+        want = np.frombuffer(payload, np.uint8).reshape(-1, self.SHARD)
+        assert np.array_equal(batch[:2, 1, :], want[0:2])
+        assert np.array_equal(batch[2:, 1, :], want[6:8])
+        assert not batch[:, 0, :].any() and not batch[:, 2, :].any()
+        # a read without `out` goes on where the placed ones left off
+        assert r.read_blocks(4 * self.SHARD, 2, self.SHARD).tobytes() == \
+            payload[4 * self.SHARD:6 * self.SHARD]
+        staged = stagestats.snapshot()["staged"]["bytes"] - before
+        assert staged == (0 if kind == "read_only" else 6 * self.SHARD)
+        r.close()
+
+    @pytest.mark.parametrize("kind", ["file", "bytesio", "read_only"])
+    def test_flipped_byte_raises_and_leaves_the_position(self, kind,
+                                                         tmp_path):
+        from minio_tpu.storage import errors as st_errors
+
+        payload, blob = self._blob()
+        bad = bytearray(blob)
+        bad[5 * (32 + self.SHARD) + 32 + 17] ^= 0x01  # in block 5
+        r = bitrot.BitrotReader(self._stream(kind, bytes(bad), tmp_path),
+                                len(payload), self.SHARD)
+        out = np.empty((4, self.SHARD), np.uint8)
+        assert r.read_blocks(0, 4, self.SHARD, out=out) is out
+        pos = r._pos
+        with pytest.raises(st_errors.FileCorrupt):
+            r.read_blocks(4 * self.SHARD, 4, self.SHARD, out=out)
+        # not moved on to the group's end: the next read seeks
+        assert r._pos in (pos, -1)
+        assert r.read_blocks(6 * self.SHARD, 2, self.SHARD,
+                             out=out[:2]).tobytes() == \
+            payload[6 * self.SHARD:]
+        assert r.read_blocks(0, 1, self.SHARD).tobytes() == \
+            payload[:self.SHARD]
+
+    def test_fresh_reader_keeps_its_position_on_a_bad_frame(self, tmp_path):
+        from minio_tpu.storage import errors as st_errors
+
+        payload, blob = self._blob()
+        bad = bytearray(blob)
+        bad[40] ^= 0x80
+        for kind in ("file", "bytesio"):
+            r = bitrot.BitrotReader(
+                self._stream(kind, bytes(bad), tmp_path),
+                len(payload), self.SHARD)
+            with pytest.raises(st_errors.FileCorrupt):
+                r.read_blocks(0, 2, self.SHARD,
+                              out=np.empty((2, self.SHARD), np.uint8))
+            assert r._pos == -1
+
+    @pytest.mark.parametrize("out", [
+        np.empty((2, 2000), np.uint8)[:, ::2],      # rows not contiguous
+        np.empty((1000, 2), np.uint8).T,            # column-major
+        np.empty((3, 1000), np.uint8),              # another group
+        np.empty((2, 1000), np.uint16),             # another type
+        np.broadcast_to(np.zeros(1000, np.uint8), (2, 1000)),  # read-only
+    ], ids=["strided_row", "transposed", "shape", "dtype", "read_only"])
+    def test_rows_that_cannot_take_a_read_are_refused(self, out):
+        payload, blob = self._blob()
+        r = bitrot.BitrotReader(io.BytesIO(blob), len(payload), self.SHARD)
+        with pytest.raises(ValueError):
+            r.read_blocks(0, 2, self.SHARD, out=out)
+        assert r._pos == -1  # nothing was read
+
+    def test_short_final_block_and_truncation(self, tmp_path):
+        from minio_tpu.storage import errors as st_errors
+
+        rng = np.random.default_rng(30)
+        payload = rng.integers(0, 256, 2 * self.SHARD + 123,
+                               dtype=np.uint8).tobytes()
+        buf = _KeepOpen()
+        w = bitrot.BitrotWriter(buf, self.SHARD)
+        for i in range(0, len(payload), self.SHARD):
+            w.write(payload[i:i + self.SHARD])
+        for kind in ("file", "bytesio"):
+            r = bitrot.BitrotReader(
+                self._stream(kind, buf.getvalue(), tmp_path),
+                len(payload), self.SHARD)
+            tail = np.empty((1, 123), np.uint8)
+            r.read_blocks(2 * self.SHARD, 1, 123, out=tail)
+            assert tail.tobytes() == payload[2 * self.SHARD:]
+            with pytest.raises(st_errors.FileCorrupt):  # past the end
+                r.read_blocks(self.SHARD, 2, self.SHARD,
+                              out=np.empty((2, self.SHARD), np.uint8))
+            with pytest.raises(st_errors.InvalidArgument):  # unaligned
+                r.read_blocks(17, 1, self.SHARD,
+                              out=np.empty((1, self.SHARD), np.uint8))
+
+
 class TestHedgedMetadataFanout:
     """Satellite: read_version fan-out abandons slow-drive stragglers
     once a quorum FileInfo is electable, even without a deadline budget

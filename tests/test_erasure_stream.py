@@ -281,6 +281,337 @@ def test_device_codec_stream_at_12_4(tmp_path):
         coding._DeviceCodec._cache.pop((k, m), None)
 
 
+# -- the staged read of a degraded group (ISSUE 29) --------------------------
+# Blocks of 64 KiB: 40 full blocks are two groups (32 + 8) and the tail a
+# third; at 12+4 a shard is 5,462 bytes (k does not divide the block, no
+# multiple of the kernel's tile), at 8+4 8 KiB, at 2+2 32 KiB.
+_BS = 1 << 16
+_SIZE = 40 * _BS + 4321
+
+
+def _stored(tmp_path, k, m, size=_SIZE, backend="host"):
+    """An object's shard files, written through encode_stream."""
+    e = Erasure(k, m, _BS, backend=backend)
+    payload = np.random.default_rng(29 + k).integers(
+        0, 256, size=size, dtype=np.uint8).tobytes()
+    paths = [tmp_path / f"shard{i}" for i in range(k + m)]
+    writers = [bitrot.BitrotWriter(open(p, "wb"), e.shard_size)
+               for p in paths]
+    n, failed = e.encode_stream(io.BytesIO(payload), writers, size, k + 1)
+    assert n == size and not failed
+    for w in writers:
+        w.close()
+    return e, paths, payload
+
+
+def _open(e, paths, size, gone=(), wrap=None):
+    till = e.shard_file_size(size)
+    readers = [None if i in gone else bitrot.BitrotReader(
+        open(p, "rb"), till, e.shard_size) for i, p in enumerate(paths)]
+    if wrap:
+        readers = [r if r is None else wrap(i, r)
+                   for i, r in enumerate(readers)]
+    return readers
+
+
+def _flip(path, frame, frame_len):
+    raw = bytearray(path.read_bytes())
+    raw[frame * frame_len + 32 + 5] ^= 0x40
+    path.write_bytes(bytes(raw))
+
+
+def _stage_bytes():
+    from minio_tpu.erasure import stagestats
+
+    snap = stagestats.snapshot()
+    return {s: snap[s]["bytes"] for s in ("staged", "assemble")}
+
+
+def _shard_bytes(e, size):
+    """Bytes of one shard of the object: what one column of all its
+    dispatches holds."""
+    full, tail = divmod(size, e.block_size)
+    return full * e.shard_size + (-(-tail // e.k) if tail else 0)
+
+
+class _ArenaWatch:
+    """Counts the pool's arenas out and in, and keeps every one seen."""
+
+    def __init__(self, monkeypatch):
+        from minio_tpu.erasure import coding
+
+        self.out = self.most = 0
+        self.seen = []
+        acquire, release = coding._arena_acquire, coding._arena_release
+
+        def acq(nbytes):
+            arr = acquire(nbytes)
+            self.out += 1
+            self.most = max(self.most, self.out)
+            self.seen.append(arr)
+            return arr
+
+        def rel(arr):
+            self.out -= 1
+            release(arr)
+            # the pool's other users (a PUT's slots) take flat arrays
+            assert all(a.ndim == 1 for bucket in coding._arena_pool.values()
+                       for a in bucket)
+
+        monkeypatch.setattr(coding, "_arena_acquire", acq)
+        monkeypatch.setattr(coding, "_arena_release", rel)
+
+
+@pytest.mark.parametrize("lost", [1, 2])
+@pytest.mark.parametrize("k,m", [(2, 2), (8, 4), (12, 4)])
+def test_staged_degraded_read_and_heal_give_the_healthy_bytes(
+        tmp_path, monkeypatch, k, m, lost):
+    e, paths, payload = _stored(tmp_path, k, m)
+    size = len(payload)
+    originals = [p.read_bytes() for p in paths]
+    gone = (0, k - 1)[:lost]  # the first and the last data shard
+    col = _shard_bytes(e, size)
+
+    healthy = io.BytesIO()
+    before = _stage_bytes()
+    e.decode_stream(healthy, _open(e, paths, size), 0, size, size)
+    after = _stage_bytes()
+    assert healthy.getvalue() == payload
+    assert after["staged"] == before["staged"]  # no new line on its path
+    assert after["assemble"] - before["assemble"] == k * col
+
+    watch = _ArenaWatch(monkeypatch)
+    out = io.BytesIO()
+    before = _stage_bytes()
+    assert e.decode_stream(out, _open(e, paths, size, gone), 0, size,
+                           size) == size
+    after = _stage_bytes()
+    assert out.getvalue() == healthy.getvalue()
+    # every survivor's rows went from its drive into the dispatch's
+    # arena, and the host copied each served byte once: the survivors'
+    # data shards and the rebuilt ones, to their place in the block
+    assert after["staged"] - before["staged"] == k * col
+    assert after["assemble"] - before["assemble"] == k * col
+    assert watch.out == 0 and watch.most == 1 and len(watch.seen) == 3
+
+    # a range that starts and ends inside a group, and one across two
+    for off, ln in ((3 * _BS + 77, 5 * _BS + 1), (31 * _BS + 5, 2 * _BS)):
+        out = io.BytesIO()
+        assert e.decode_stream(out, _open(e, paths, size, gone), off, ln,
+                               size) == ln
+        assert out.getvalue() == payload[off:off + ln], (off, ln)
+
+    # heal the lost shards, and a parity shard where m allows a third,
+    # through the same read
+    stale = (gone + (k + m - 1,))[:m]
+    for i in stale:
+        os.remove(paths[i])
+    writers = [bitrot.BitrotWriter(open(paths[i], "wb"), e.shard_size)
+               if i in stale else None for i in range(k + m)]
+    before = _stage_bytes()
+    e.heal(writers, _open(e, paths, size, stale), size)
+    after = _stage_bytes()
+    for w in writers:
+        if w:
+            w.close()
+    for i in stale:
+        assert paths[i].read_bytes() == originals[i], f"shard {i}"
+    assert after["staged"] - before["staged"] == k * col
+    assert after["assemble"] == before["assemble"]  # no host copy at all
+    assert watch.out == 0 and watch.most == 1
+
+
+def test_heal_of_parity_alone_takes_the_staged_read(tmp_path, monkeypatch):
+    """All data shards present: a GET would read views, a heal still
+    reconstructs, so its reads are staged all the same."""
+    k, m = 4, 2
+    e, paths, payload = _stored(tmp_path, k, m)
+    want = paths[5].read_bytes()
+    os.remove(paths[5])
+    watch = _ArenaWatch(monkeypatch)
+    writers = [None] * 5 + [bitrot.BitrotWriter(open(paths[5], "wb"),
+                                                e.shard_size)]
+    before = _stage_bytes()
+    e.heal(writers, _open(e, paths, len(payload), (5,)), len(payload))
+    writers[5].close()
+    assert paths[5].read_bytes() == want
+    assert _stage_bytes()["staged"] - before["staged"] == \
+        k * _shard_bytes(e, len(payload))
+    assert watch.out == 0 and len(watch.seen) == 3
+
+
+@pytest.mark.parametrize("codec", ["host", "device"])
+def test_bad_frame_in_a_staged_group_hands_its_column_to_a_spare(
+        tmp_path, codec):
+    """Data shard 0 is away, so the group is staged; shard 1 fails its
+    hash in the middle of the second group.  The spare (shard 3) reads
+    into the failed read's column, the codec takes the columns in the
+    order given (3, 2: unsorted), the bytes are the healthy read's and
+    the bad shard reaches `broken_out`."""
+    from minio_tpu.erasure import coding
+    from minio_tpu.ops import rs_pallas
+
+    k, m = 2, 2
+    if codec == "device":
+        coding._DeviceCodec._cache[(k, m)] = (
+            rs_pallas.PallasRSCodec(k, m, interpret=True), True)
+    try:
+        e, paths, payload = _stored(
+            tmp_path, k, m, backend="tpu" if codec == "device" else "host")
+        size = len(payload)
+        _flip(paths[1], 35, 32 + e.shard_size)
+        seen = []
+        inner = e._reconstruct_shards_raw
+
+        def raw(batch, available, wanted):
+            assert batch.flags.c_contiguous and batch.shape[1] == k
+            seen.append((available, wanted))
+            return inner(batch, available, wanted)
+
+        e._reconstruct_shards_raw = raw
+        broken: set = set()
+        out = io.BytesIO()
+        assert e.decode_stream(out, _open(e, paths, size, (0,)), 0, size,
+                               size, broken_out=broken) == size
+        assert out.getvalue() == payload
+        assert broken == {1}
+        assert seen == [((1, 2), (0,)), ((3, 2), (0, 1)), ((2, 3), (0, 1))]
+    finally:
+        coding._DeviceCodec._cache.pop((k, m), None)
+
+
+def test_group_that_turns_degraded_after_its_reads_began(tmp_path,
+                                                         monkeypatch):
+    """Every data shard is there when the group's reads go out, so no
+    arena is taken; data shard 2 then fails its hash.  What was read
+    into frame buffers is copied into an arena (booked as `assemble`),
+    and the groups after it, which see the shard as broken before they
+    read, are staged."""
+    k, m = 4, 2
+    e, paths, payload = _stored(tmp_path, k, m)
+    size = len(payload)
+    _flip(paths[2], 3, 32 + e.shard_size)
+    watch = _ArenaWatch(monkeypatch)
+    broken: set = set()
+    out = io.BytesIO()
+    before = _stage_bytes()
+    assert e.decode_stream(out, _open(e, paths, size), 0, size, size,
+                           broken_out=broken) == size
+    after = _stage_bytes()
+    assert out.getvalue() == payload and broken == {2}
+    first = 32 * e.shard_size  # one column of the first group
+    col = _shard_bytes(e, size)
+    assert after["staged"] - before["staged"] == k * (col - first)
+    # the first group: three data shards placed, four survivors copied
+    # to the arena, one shard rebuilt and placed; the others k columns
+    assert after["assemble"] - before["assemble"] == \
+        (2 * k) * first + k * (col - first)
+    assert watch.out == 0 and watch.most == 1 and len(watch.seen) == 3
+
+
+class _ReadAtOnly:
+    """A shard reader of the older protocol: `read_at` and no more."""
+
+    def __init__(self, inner):
+        self.read_at = inner.read_at
+        self.close = inner.close
+
+
+def test_reader_with_read_at_only_is_copied_into_its_column(tmp_path):
+    k, m = 4, 2
+    e, paths, payload = _stored(tmp_path, k, m)
+    size = len(payload)
+    readers = _open(e, paths, size, (1,),
+                    wrap=lambda i, r: _ReadAtOnly(r) if i in (3, 4) else r)
+    out = io.BytesIO()
+    before = _stage_bytes()
+    assert e.decode_stream(out, readers, 0, size, size) == size
+    after = _stage_bytes()
+    assert out.getvalue() == payload
+    col = _shard_bytes(e, size)
+    assert after["staged"] - before["staged"] == (k - 2) * col
+    assert after["assemble"] - before["assemble"] == (k + 2) * col
+
+
+class _AliasSink:
+    """A writer that keeps what it was handed, as the HTTP front's
+    queue does while the stream's thread reads the next group."""
+
+    def __init__(self):
+        self.chunks = []
+
+    def write(self, data):
+        self.chunks.append(data)
+        return len(data)
+
+
+def test_arenas_go_back_to_the_pool_and_no_response_aliases_one(
+        tmp_path, monkeypatch):
+    from minio_tpu.erasure import coding
+
+    k, m = 2, 2
+    e, paths, payload = _stored(tmp_path, k, m, size=100 * _BS)
+    size = len(payload)
+    watch = _ArenaWatch(monkeypatch)
+    for _ in range(3):  # twelve degraded groups
+        sink = _AliasSink()
+        assert e.decode_stream(sink, _open(e, paths, size, (0, 3)), 0,
+                               size, size) == size
+        assert watch.out == 0
+        assert b"".join(bytes(c) for c in sink.chunks) == payload
+        for chunk in sink.chunks:
+            flat = np.frombuffer(chunk, np.uint8)
+            assert not any(np.shares_memory(flat, a) for a in watch.seen)
+    assert watch.most == 1 and len(watch.seen) == 12
+    # the pool gave the same few arenas out again
+    assert len({a.ctypes.data for a in watch.seen}) <= 2
+    assert 0 < coding._arena_pool_bytes <= coding._ARENA_POOL_MAX_BYTES
+
+    # a group that loses its quorum while its reads are out: shard 1
+    # fails its hash in the second group and no spare is left
+    _flip(paths[1], 40, 32 + e.shard_size)
+    with pytest.raises(errors.ErasureReadQuorum):
+        e.decode_stream(_AliasSink(), _open(e, paths, size, (0, 3)), 0,
+                        size, size)
+    assert watch.out == 0 and watch.most == 1
+    # and one that never had it takes no arena
+    taken = len(watch.seen)
+    with pytest.raises(errors.ErasureReadQuorum):
+        e.decode_stream(_AliasSink(), _open(e, paths, size, (0, 2, 3)), 0,
+                        size, size)
+    assert len(watch.seen) == taken
+    with pytest.raises(errors.ErasureReadQuorum):
+        e.heal([bitrot.BitrotWriter(io.BytesIO(), e.shard_size), None,
+                None, None], _open(e, paths, size, (0, 3)), size)
+    assert watch.out == 0
+    assert coding._arena_pool_bytes <= coding._ARENA_POOL_MAX_BYTES
+
+
+@pytest.mark.parametrize("available,wanted", [
+    ((1, 2, 3, 4), (0,)), ((1, 5, 3, 4), (0, 2)), ((5, 4, 3, 0), (1, 2)),
+    ((4, 5, 1, 0), (2, 3)),
+])
+def test_reconstruct_matrix_takes_columns_in_the_order_given(available,
+                                                             wanted):
+    """A spare that took a failed read's column leaves `available`
+    unsorted: the matrix has to follow the columns, not their sort."""
+    from minio_tpu.ops import gf256, host, rs_tpu
+
+    k, m = 4, 2
+    shards = np.random.default_rng(5).integers(
+        0, 256, (k, 64), dtype=np.uint8)
+    full = np.concatenate([shards, gf256.encode_np(shards, m)])
+    src = full[list(available)]
+    rm = gf256.reconstruct_matrix(k, m, available, wanted)
+    rebuilt = host.HostRSCodec(k, m).matmul(rm, src)
+    assert np.array_equal(rebuilt, full[list(wanted)])
+    assert np.array_equal(
+        rs_tpu.reconstruct_bits_matrix(k, m, available, wanted),
+        gf256.gf_matrix_to_bits(rm).astype(np.int8))
+    with pytest.raises(ValueError):
+        gf256.decode_matrix(k, m, (1, 1, 2, 3))
+
+
 def test_bitrot_file_size_math():
     e = Erasure(8, 4)
     assert bitrot.bitrot_shard_file_size(0, e.shard_size) == 0
