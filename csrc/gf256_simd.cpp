@@ -4,8 +4,8 @@
 // (klauspost/reedsolomon, used from /root/reference/cmd/erasure-coding.go:63):
 // multiplication by a constant c is two 16-entry table lookups (low/high
 // nibble) XORed together; PSHUFB does 32 byte-lookups per instruction.
-// Serves as (a) the CPU fallback codec when no TPU is attached and (b) the
-// same-host AVX2 baseline that bench.py compares the TPU kernels against.
+// Serves as the host codec: the CPU fallback when no TPU is attached, and
+// under a device backend the tail blocks and inline objects.
 //
 // Field: polynomial 0x11D, generator 2 — identical to minio_tpu.ops.gf256.
 

@@ -50,7 +50,6 @@ WORKER_SURFACE = (
     "erasure/bitrot.py",
     "erasure/stagestats.py",
     "ops/host.py",
-    "ops/hh_device.py",
     "ops/gf256.py",
     "ops/residency.py",
     "utils/deadline.py",

@@ -313,16 +313,9 @@ class Batcher:
             return
         t_disp = time.perf_counter()
         try:
-            # a dispatch may return one array (parity) or a TUPLE of
-            # batch-major arrays (the fused encode+hash plane returns
-            # (parity, frame_hashes)); every component is sliced per
-            # item along axis 0
             if len(live) == 1:
-                out = live[0].dispatch(live[0].batch)
-                if isinstance(out, tuple):
-                    outs = [tuple(np.asarray(p) for p in out)]
-                else:
-                    outs = [np.asarray(out)]
+                out = np.asarray(live[0].dispatch(live[0].batch))
+                outs = [out]
             else:
                 # set-major layout: the mesh codec shards the batch axis
                 # over the mesh, so grouping rows by erasure set shards
@@ -330,9 +323,7 @@ class Batcher:
                 order = set_major_order([it.set_id for it in live])
                 live = [live[int(i)] for i in order]
                 cat = np.concatenate([it.batch for it in live], axis=0)
-                out = live[0].dispatch(cat)
-                parts = (tuple(np.asarray(p) for p in out)
-                         if isinstance(out, tuple) else (np.asarray(out),))
+                out = np.asarray(live[0].dispatch(cat))
                 outs = []
                 lo = 0
                 for it in live:
@@ -340,8 +331,7 @@ class Batcher:
                     # copy, don't view: a view would keep the WHOLE
                     # fused output alive for as long as the slowest
                     # co-batched request holds its slice
-                    sl = tuple(p[lo:lo + b].copy() for p in parts)
-                    outs.append(sl if isinstance(out, tuple) else sl[0])
+                    outs.append(out[lo:lo + b].copy())
                     lo += b
             with self._cv:
                 self.stats["dispatches"] += 1

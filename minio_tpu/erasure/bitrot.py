@@ -91,8 +91,7 @@ class BitrotWriter:
             self.w.write(block)
         self.written += self._hsize + len(block)
 
-    def write_frames(self, blocks: np.ndarray,
-                     hashes: np.ndarray | None = None) -> None:
+    def write_frames(self, blocks: np.ndarray) -> None:
         """Write many shard blocks as [hash|block] frames in one shot.
 
         blocks: (nb, L) uint8, L <= shard_size, every row one erasure
@@ -104,14 +103,6 @@ class BitrotWriter:
         interleaved layout costs no extra memory pass.  Equivalent to the
         per-block write() loop (cmd/bitrot-streaming.go:43) and
         byte-identical on disk.
-
-        hashes: optional (nb, 32) uint8 precomputed frame hashes — the
-        fused encode+hash tick program (MINIO_TPU_FUSED_HASH,
-        erasure/coding.py) hands them in so the writer skips its host
-        hashing pass entirely; they MUST be the HighwayHash-256 of the
-        corresponding rows (the fused kernel is pinned bit-exact against
-        ops/host.py::hh256, so on-disk frames stay byte-identical).
-        Only honored for the highwayhash algorithms.
         """
         blocks = np.asarray(blocks, dtype=np.uint8)
         if blocks.ndim != 2:
@@ -134,21 +125,13 @@ class BitrotWriter:
             for row in blocks:
                 self.write(row)
             return
-        if hashes is not None:
-            hashes = np.ascontiguousarray(hashes, dtype=np.uint8)
-            if hashes.shape != (nb, self._hsize):
-                raise errors.InvalidArgument(
-                    f"write_frames: hashes shape {hashes.shape} does not "
-                    f"match {(nb, self._hsize)}"
-                )
-        else:
-            try:
-                with stagestats.timed("hash", blocks.nbytes):
-                    hashes = host.hh256_batch(blocks)
-            except RuntimeError:
-                for row in blocks:
-                    self.write(row)
-                return
+        try:
+            with stagestats.timed("hash", blocks.nbytes):
+                hashes = host.hh256_batch(blocks)
+        except RuntimeError:
+            for row in blocks:
+                self.write(row)
+            return
         fd = None
         try:
             fd = self.w.fileno()

@@ -34,7 +34,7 @@ from typing import BinaryIO, Sequence
 
 import numpy as np
 
-from minio_tpu.ops import device, gf256, hh_device, host
+from minio_tpu.ops import device, gf256, host
 from minio_tpu.storage import errors
 from minio_tpu.utils.deadline import ctx_submit
 from minio_tpu.utils.logger import log
@@ -69,22 +69,6 @@ def pipeline_enabled() -> bool:
         "MINIO_TPU_DATAPLANE_PIPELINE", "1").lower() not in (
             "0", "off", "false")
 
-
-# Cache tile for the host fused encode->hash schedule: blocks are
-# encoded and hashed in groups whose data+parity rows fit this budget,
-# so a shard row is hashed while still L2-resident instead of after the
-# whole batch has been evicted (the schedule-reordering + tiling recipe
-# of arxiv 2108.02692 applied to the PUT hot loop).
-FUSED_TILE_BYTES = max(64 << 10, int(os.environ.get(
-    "MINIO_TPU_FUSED_TILE_BYTES", str(1 << 20))))
-
-
-def fused_hash_enabled() -> bool:
-    """MINIO_TPU_FUSED_HASH=1: frame hashes ride the encode dispatch
-    (one pass over payload bytes) instead of a second host hashing pass
-    in BitrotWriter.  Default off; the differential suite pins 0<->1
-    byte-identical on disk."""
-    return os.environ.get("MINIO_TPU_FUSED_HASH", "0") == "1"
 
 _pool_lock = threading.Lock()
 _shared_pool: cf.ThreadPoolExecutor | None = None
@@ -587,140 +571,6 @@ class Erasure:
         out = self._host_encode(batch)
         return lambda: out
 
-    # -- fused encode + frame-hash plane (MINIO_TPU_FUSED_HASH) -------------
-    @staticmethod
-    def _hash_rows(rows: np.ndarray) -> np.ndarray:
-        """(N, S) -> (N, 32) HighwayHash-256 frames: batched C call, or
-        the vectorized numpy kernel when the native library is absent."""
-        try:
-            return host.hh256_batch(rows)
-        except RuntimeError:
-            return hh_device.hh256_batch_np(rows)
-
-    def _fused_device(self, nbytes: int, shard_len: int):
-        """Device policy for the fused encode+hash program.  Same pricing
-        as _device, but only the single-device XLA path fuses — the mesh
-        codec (and its padded tail dispatches) stays on the legacy
-        unfused plane (ROADMAP leftover: mesh-sharding the fused
-        program)."""
-        if self.backend == "mesh":
-            return None
-        return self._device(nbytes, shard_len)
-
-    def _fused_launch(self, batch: np.ndarray):
-        """The fused program's jit call: it takes the host batch as it
-        is, so the hand-over to the device is inside the call."""
-        with stagestats.timed("launch", batch.nbytes):
-            return hh_device.fused_encode_hash(self.k, self.m)(batch)
-
-    def _encode_hash_host_tiled(self, batch: np.ndarray, parity: np.ndarray,
-                                hashes: np.ndarray, lo: int, hi: int) -> None:
-        """Host fallback fused schedule over blocks [lo, hi): encode a
-        cache-sized group, then hash that group's data+parity rows while
-        they are still L2-resident (arxiv 2108.02692 schedule reordering
-        + tiling; the hash leg books into the "fused_hash" stage so the
-        fused-vs-legacy split stays attributable)."""
-        b, k, s = batch.shape
-        rowset = k + self.m
-        group = max(1, FUSED_TILE_BYTES // max(1, rowset * s))
-        for glo in range(lo, hi, group):
-            ghi = min(glo + group, hi)
-            if self.m:
-                self._host_encode(batch[glo:ghi], parity[glo:ghi])
-            with stagestats.timed("fused_hash", (ghi - glo) * rowset * s):
-                hashes[glo:ghi, :k] = self._hash_rows(
-                    batch[glo:ghi].reshape(-1, s)).reshape(ghi - glo, k, 32)
-                if self.m:
-                    hashes[glo:ghi, k:] = self._hash_rows(
-                        parity[glo:ghi].reshape(-1, s)).reshape(
-                            ghi - glo, self.m, 32)
-
-    def _encode_hash_shards_raw(self, batch: np.ndarray
-                                ) -> tuple[np.ndarray, np.ndarray]:
-        """(B, K, S) -> (parity (B, M, S), frame hashes (B, K+M, 32)).
-
-        The fused analogue of _encode_shards_raw: on the device, ONE
-        jitted program (ops/hh_device.py::fused_encode_hash) computes
-        parity and every shard row's HighwayHash-256 in the same launch,
-        so payload bytes cross the memory system once; on the host, the
-        tiled encode->hash schedule.  The batcher feeds merged
-        cross-request batches through here under the "ench" signature."""
-        b, k, s = batch.shape
-        dev = self._fused_device(batch.nbytes, s)
-        _count(_backend_name(dev), batch.nbytes)
-        if dev is not None:
-            t0 = time.perf_counter()
-            par, hsh = self._fused_launch(batch)
-            with stagestats.timed("fetch", par.nbytes + hsh.nbytes):
-                parity, frames = np.asarray(par), np.asarray(hsh)
-            stagestats.add("encode", time.perf_counter() - t0, batch.nbytes)
-            # the hash plane rode the encode launch: book its bytes with
-            # zero seconds — one pass is the point
-            stagestats.add("fused_hash", 0.0, b * (k + self.m) * s)
-            return parity, frames
-        parity = np.empty((b, self.m, s), dtype=np.uint8)
-        hashes = np.empty((b, k + self.m, 32), dtype=np.uint8)
-        self._encode_hash_host_tiled(batch, parity, hashes, 0, b)
-        return parity, hashes
-
-    def _encode_hash_shards_async(self, batch: np.ndarray, pool=None):
-        """Non-blocking fused dispatch: resolve() -> (parity, hashes).
-
-        Mirrors _encode_shards_async — batcher routing first (kind
-        "ench" coalesces fused ticks separately from plain "enc" ones),
-        then JAX async dispatch on the device, then the pool-sharded
-        tiled host schedule — so encode_stream's pipeline depth
-        bookkeeping is unchanged when the fused gate is on."""
-        routed = self._via_batcher("ench", batch,
-                                   self._encode_hash_shards_raw)
-        if routed is not None:
-            return routed
-        b, k, s = batch.shape
-        dev = self._fused_device(batch.nbytes, s)
-        _count(_backend_name(dev), batch.nbytes)
-        if dev is not None:
-            t0 = time.perf_counter()
-            par, hsh = self._fused_launch(batch)
-
-            def resolve_dev():
-                with stagestats.timed("fetch", par.nbytes + hsh.nbytes):
-                    parity = np.asarray(par)
-                    frames = np.asarray(hsh)
-                stagestats.add("encode", time.perf_counter() - t0,
-                               batch.nbytes)
-                stagestats.add("fused_hash", 0.0, b * (k + self.m) * s)
-                return parity, frames
-
-            return resolve_dev
-        parity = np.empty((b, self.m, s), dtype=np.uint8)
-        hashes = np.empty((b, k + self.m, 32), dtype=np.uint8)
-        if pool is not None and b > 1:
-            # shard the batch across pool workers; each worker runs the
-            # L2-tiled encode->hash schedule within its span (the C
-            # matmul and hash calls release the GIL)
-            nshards = max(1, min(4, (os.cpu_count() or 4) - 1, b))
-            step = -(-b // nshards)
-            futs = [
-                ctx_submit(pool, self._encode_hash_host_tiled,
-                           batch, parity, hashes, lo, min(lo + step, b))
-                for lo in range(0, b, step)
-            ]
-
-            def resolve_host():
-                for f in futs:
-                    f.result()
-                return parity, hashes
-
-            return resolve_host
-        if pool is not None:
-            def run_host():
-                self._encode_hash_host_tiled(batch, parity, hashes, 0, b)
-                return parity, hashes
-
-            return ctx_submit(pool, run_host).result
-        self._encode_hash_host_tiled(batch, parity, hashes, 0, b)
-        return lambda: (parity, hashes)
-
     def _reconstruct_shards_raw(self, batch: np.ndarray, available: tuple,
                                 wanted: tuple) -> np.ndarray:
         b, k, s = batch.shape
@@ -829,20 +679,6 @@ class Erasure:
         if pipelined is None:
             pipelined = pipeline_enabled()
         pool = _io_pool()
-        # Fused hash plane (MINIO_TPU_FUSED_HASH=1): frame hashes ride
-        # the encode dispatch and write_frames skips its host hashing
-        # pass.  Only when some writer can consume them (BitrotWriter on
-        # a highwayhash algo) and the backend is not mesh (the mesh
-        # program stays unfused for now).
-        fused = (
-            fused_hash_enabled()
-            and self.backend != "mesh"
-            and any(
-                w is not None and hasattr(w, "write_frames")
-                and getattr(w, "algo", None) in (
-                    "highwayhash256S", "highwayhash256")
-                for w in writers)
-        )
         total = 0
         # Per-drive write CHAINS instead of a per-batch barrier: drive
         # i's write for batch N+1 is submitted chained on its batch-N
@@ -955,21 +791,9 @@ class Erasure:
 
         def emit_one() -> None:
             slot, batch, block_len, resolve, hfut = pending.pop(0)
-            out = resolve()
-            # the fused plane resolves to (parity, frame hashes); the
-            # legacy plane to parity alone
-            if isinstance(out, tuple):
-                parity, frame_hashes = out
-            else:
-                parity, frame_hashes = out, None
+            parity = resolve()
             prune_dead()
             shard_len = -(-block_len // self.k)
-            # fused hashes cover full-width rows; every flush path sets
-            # S == shard_len so the trim below is a no-op, but if a
-            # future path ever violates that the writer re-hashes rather
-            # than frame a stale digest
-            hashes_ok = (frame_hashes is not None
-                         and shard_len == batch.shape[2])
 
             def write_drive(i: int, prev: cf.Future | None) -> None:
                 if prev is not None:
@@ -980,11 +804,7 @@ class Erasure:
                 rows = batch[:, i, :] if i < self.k else parity[:, i - self.k, :]
                 wf = getattr(writers[i], "write_frames", None)
                 if wf is not None:
-                    if hashes_ok and getattr(writers[i], "algo", None) in (
-                            "highwayhash256S", "highwayhash256"):
-                        wf(rows[:, :shard_len], hashes=frame_hashes[:, i, :])
-                    else:
-                        wf(rows[:, :shard_len])
+                    wf(rows[:, :shard_len])
                 else:
                     for bi in range(rows.shape[0]):
                         writers[i].write(rows[bi, :shard_len])
@@ -1025,10 +845,9 @@ class Erasure:
             # rows are a strided column of the batch, no per-shard copies.
             if slot is not None:
                 slot_refs[slot] += 1
-            enc = (self._encode_hash_shards_async if fused
-                   else self._encode_shards_async)
             pending.append((slot, batch, block_len,
-                            enc(batch, pool if pipelined else None), hfut))
+                            self._encode_shards_async(
+                                batch, pool if pipelined else None), hfut))
             self.max_inflight = max(self.max_inflight, len(pending))
             while len(pending) > depth:
                 emit_one()

@@ -63,7 +63,7 @@ from . import bitrot
 from . import coding as coding_mod
 
 # ---------------------------------------------------------------- stats
-# read by server/metrics.py and the BENCH_r10 heal drill
+# read by server/metrics.py
 
 _stats_mu = threading.Lock()
 repair_stats = {

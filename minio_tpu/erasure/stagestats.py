@@ -49,17 +49,10 @@ import time
 
 from minio_tpu.utils import tracing
 
-# "fused_hash" books the frame-hash plane when MINIO_TPU_FUSED_HASH
-# folds it into the encode program (erasure/coding.py): on the device
-# path the bytes land here with ~zero seconds (the hash rides the encode
-# launch — one pass is the point); on the host fallback it carries the
-# tiled hash leg's real seconds so fused vs legacy "hash" stays
-# attributable.
 STAGES = (
     # PUT: body read, etag fold, erasure encode (parent), frame hash,
     # shard write; GET: decode (parent), hand-over to the HTTP front
-    "read", "etag", "encode", "hash", "fused_hash", "write", "decode",
-    "respond",
+    "read", "etag", "encode", "hash", "write", "decode", "respond",
     # GET / heal: the stream's thread waiting for its k shards; in the
     # I/O pool, a drive's bytes arriving and their frame check
     "read_wait", "shard_read", "verify",

@@ -49,9 +49,9 @@ def require_tpu(what: str) -> DeviceInfo:
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Entry points (server, bench.py) call this before their first
-    compile.  JAX_COMPILATION_CACHE_DIR wins when set (JAX reads it
-    itself; no directory is set here); otherwise the cache lives at
+    The server's entry point calls this before its first compile.
+    JAX_COMPILATION_CACHE_DIR wins when set (JAX reads it itself; no
+    directory is set here); otherwise the cache lives at
     <checkout>/.jax_cache — a fixed path, because the path is part of
     the cache key.  Small programs are cached too: the codec kernels
     compile in well under JAX's default one-second floor."""

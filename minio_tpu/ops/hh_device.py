@@ -1,56 +1,24 @@
-"""Device-side batched HighwayHash-256 + fused encode/hash/etag kernels.
+"""Batched HighwayHash-256 in plain numpy: the oracle of the frame hash.
 
-The PUT hot path needs two hash planes next to the Reed-Solomon encode:
+The bitrot framing keys HighwayHash-256 with the pi-decimals magic key
+(reference cmd/bitrot.go:55); the served path hashes with the C hasher
+(``ops/host.py`` ``hh256_batch``, csrc/highwayhash.cpp).
 
-- per-shard *frame* hashes for the bitrot framing (reference
-  cmd/bitrot.go:55 — HighwayHash-256 keyed with the pi-decimals magic
-  key), today a second full pass over payload bytes on the host;
-- the whole-object MD5 *etag* (reference cmd/erasure-object.go), today
-  folded by a dedicated hash-lane worker process (parallel/workers.py).
-
-This module moves both next to the encode so one program launch makes
-one pass over the payload:
-
-- ``hh256_batch_np``: vectorized pure-numpy HighwayHash-256 over N
-  equal-length rows — the bit-exact oracle the device kernel and the
-  property tests check against (and a dependency-free fallback).
-- ``hh256_jax``: the same hash as a jittable XLA program.  JAX runs
-  without 64-bit types here, so every u64 lane is carried as a
-  (lo, hi) uint32 pair: 64-bit adds ripple a carry, the 32x32->64
-  multiplies split at 16 bits for the high half, and the zipper merge
-  is re-derived as byte shuffles on the pair (formulas checked
-  byte-for-byte against csrc/highwayhash.cpp).
-- ``fused_encode_hash``: ONE jitted program ``(B, K, S) -> (parity
-  (B, M, S), frame hashes (B, K+M, 32))`` — GF(2^8) bit-plane matmul
-  (ops/rs_tpu.py) feeding the batched hash while shard rows are still
-  live in vector memory.  This is what the batcher dispatches per tick
-  when MINIO_TPU_FUSED_HASH=1.
-- ``Md5Fold``: whole-object MD5 as a lax.scan over 64-byte blocks, so
-  the etag folds on-device and the PR 8 hash-lane process becomes
-  optional (``fused_etag_available``).
-
-Everything here is pure XLA (no Pallas): the hash state is 16 u64
-lanes per row, the update is shift/mask/multiply — XLA vectorizes it
-across rows, which is the axis that matters for a tick batch.
+``hh256_batch_np`` is the same hash over N equal-length rows as
+vectorized numpy u64 arithmetic.  It is the reference the tests compare
+the C hasher and the frames on disk against, never a target; nothing on
+the served path calls it.
 """
 
 from __future__ import annotations
 
-import functools
-import os
-
 import numpy as np
 
-from . import device
 from .host import MAGIC_HH256_KEY
 
 __all__ = [
     "MAGIC_HH256_KEY",
     "hh256_batch_np",
-    "hh256_jax",
-    "fused_encode_hash",
-    "Md5Fold",
-    "fused_etag_available",
 ]
 
 U64 = np.uint64
@@ -217,390 +185,3 @@ def hh256_batch_np(blocks: np.ndarray,
         lanes = packet.view("<u8").reshape(n, 4).astype(U64, copy=False)
         _np_update(lanes, mul0, mul1, v0, v1)
     return _finalize256(mul0, mul1, v0, v1)
-
-
-# ---------------------------------------------------------------------------
-# JAX kernel: u64 as (lo, hi) uint32 pairs (no jax_enable_x64 dependence)
-# ---------------------------------------------------------------------------
-
-def _jx():
-    import jax
-    import jax.numpy as jnp
-    return jax, jnp
-
-
-def _add64(jnp, al, ah, bl, bh):
-    rl = al + bl
-    carry = (rl < al).astype(jnp.uint32)
-    return rl, ah + bh + carry
-
-
-def _mul32x32(jnp, a, b):
-    """Full 32x32 -> 64 product as (lo, hi) uint32 (mulhi via 16-bit split)."""
-    lo = a * b
-    a0 = a & 0xFFFF
-    a1 = a >> 16
-    b0 = b & 0xFFFF
-    b1 = b >> 16
-    t = a0 * b1 + ((a0 * b0) >> 16)
-    t2 = a1 * b0 + (t & 0xFFFF)
-    hi = a1 * b1 + (t >> 16) + (t2 >> 16)
-    return lo, hi
-
-
-def _zipper_pair(alo, ahi, blo, bhi):
-    """_zipper in the (lo, hi) uint32 representation.
-
-    Returns ((add0_lo, add0_hi), (add1_lo, add1_hi)) with the same byte
-    shuffle as the u64 formulas (a = v1 argument, b = v0 argument).
-    """
-    r0lo = ((blo >> 24) | ((ahi & 0xFF) << 8) | (blo & 0xFF0000)
-            | (((bhi >> 8) & 0xFF) << 24))
-    r0hi = (((ahi >> 16) & 0xFF) | (((blo >> 8) & 0xFF) << 8)
-            | (((ahi >> 24) & 0xFF) << 16) | ((blo & 0xFF) << 24))
-    r1lo = ((alo >> 24) | ((bhi & 0xFF) << 8) | (alo & 0xFF0000)
-            | (((ahi >> 8) & 0xFF) << 24))
-    r1hi = (((alo >> 8) & 0xFF) | (((bhi >> 16) & 0xFF) << 8)
-            | ((alo & 0xFF) << 16) | (bhi & np.uint32(0xFF000000)))
-    return (r0lo, r0hi), (r1lo, r1hi)
-
-
-def _jax_update(jnp, state, lanes_lo, lanes_hi):
-    """One UpdatePacket.  state: dict of (N, 4) uint32 arrays."""
-    m0l, m0h = state["m0l"], state["m0h"]
-    m1l, m1h = state["m1l"], state["m1h"]
-    v0l, v0h = state["v0l"], state["v0h"]
-    v1l, v1h = state["v1l"], state["v1h"]
-    tl, th = _add64(jnp, m0l, m0h, lanes_lo, lanes_hi)
-    v1l, v1h = _add64(jnp, v1l, v1h, tl, th)
-    pl, ph = _mul32x32(jnp, v1l, v0h)
-    m0l, m0h = m0l ^ pl, m0h ^ ph
-    v0l, v0h = _add64(jnp, v0l, v0h, m1l, m1h)
-    pl, ph = _mul32x32(jnp, v0l, v1h)
-    m1l, m1h = m1l ^ pl, m1h ^ ph
-
-    def merge(dl, dh, sl, sh):
-        """Zipper-merge columns 0..3 of source s into dest d (in place on
-        fresh arrays via at[] updates is slow — rebuild by stacking)."""
-        (a0l, a0h), (a1l, a1h) = _zipper_pair(
-            sl[:, 1], sh[:, 1], sl[:, 0], sh[:, 0])
-        (b0l, b0h), (b1l, b1h) = _zipper_pair(
-            sl[:, 3], sh[:, 3], sl[:, 2], sh[:, 2])
-        addl = jnp.stack([a0l, a1l, b0l, b1l], axis=1)
-        addh = jnp.stack([a0h, a1h, b0h, b1h], axis=1)
-        return _add64(jnp, dl, dh, addl, addh)
-
-    v0l, v0h = merge(v0l, v0h, v1l, v1h)
-    v1l, v1h = merge(v1l, v1h, v0l, v0h)
-    return {"m0l": m0l, "m0h": m0h, "m1l": m1l, "m1h": m1h,
-            "v0l": v0l, "v0h": v0h, "v1l": v1l, "v1h": v1h}
-
-
-def _bytes_to_lanes(jnp, packets):
-    """(N, P, 32) uint8 -> (lo, hi) each (N, P, 4) uint32, LE lanes."""
-    b = packets.astype(jnp.uint32).reshape(
-        packets.shape[0], packets.shape[1], 4, 8)
-    lo = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
-    hi = b[..., 4] | (b[..., 5] << 8) | (b[..., 6] << 16) | (b[..., 7] << 24)
-    return lo, hi
-
-
-@functools.lru_cache(maxsize=8)
-def _hh256_rows_fn(key: bytes):
-    """Traceable (N, L) uint8 -> (N, 32) uint8 batched HighwayHash-256
-    (compose into a jit; see _hh256_rows_jit for the standalone entry)."""
-    jax, jnp = _jx()
-    lanes = _key_lanes(key)
-    i0, i1 = _INIT0, _INIT1
-    kv0, kv1 = i0 ^ lanes, i1 ^ _rot32(lanes)
-
-    def split(u):  # (4,) u64 -> two (4,) uint32 numpy arrays
-        return ((u & _M32).astype(np.uint32), (u >> U64(32)).astype(np.uint32))
-
-    consts = {k: split(v) for k, v in
-              (("m0", i0), ("m1", i1), ("v0", kv0), ("v1", kv1))}
-
-    def run(blocks):
-        n = blocks.shape[0]
-        length = blocks.shape[1]  # static under jit
-        state = {}
-        for name, (lo, hi) in consts.items():
-            state[name[0] + name[1] + "l"] = jnp.broadcast_to(
-                jnp.asarray(lo), (n, 4))
-            state[name[0] + name[1] + "h"] = jnp.broadcast_to(
-                jnp.asarray(hi), (n, 4))
-        nfull, rem = divmod(length, 32)
-        if nfull:
-            packets = blocks[:, :nfull * 32].reshape(n, nfull, 32)
-            plo, phi = _bytes_to_lanes(jnp, packets)  # (N, P, 4)
-
-            def body(st, lane):
-                return _jax_update(jnp, st, lane[0], lane[1]), None
-
-            state, _ = jax.lax.scan(
-                body, state,
-                (jnp.moveaxis(plo, 1, 0), jnp.moveaxis(phi, 1, 0)))
-        if rem:
-            # v0 += (rem << 32) + rem: u64 add — lo gains rem (with carry
-            # into hi), hi gains rem
-            state["v0l"], state["v0h"] = _add64(
-                jnp, state["v0l"], state["v0h"],
-                jnp.uint32(rem), jnp.uint32(rem))
-            if rem % 32:
-                c = rem % 32
-
-                def rotl(x):
-                    return (x << c) | (x >> (32 - c))
-
-                state["v1l"] = rotl(state["v1l"])
-                state["v1h"] = rotl(state["v1h"])
-            tail = rem & ~3
-            mod4 = rem & 3
-            base = nfull * 32
-            cols = [None] * 32
-            for i in range(tail):
-                cols[i] = base + i
-            if rem & 16:
-                for i in range(4):
-                    cols[28 + i] = base + tail + i + mod4 - 4
-            elif mod4:
-                cols[16] = base + tail
-                cols[17] = base + tail + (mod4 >> 1)
-                cols[18] = base + rem - 1
-            zero = jnp.zeros((n,), dtype=jnp.uint8)
-            packet = jnp.stack(
-                [blocks[:, c] if c is not None else zero for c in cols],
-                axis=1)[:, None, :]
-            plo, phi = _bytes_to_lanes(jnp, packet)
-            state = _jax_update(jnp, state, plo[:, 0], phi[:, 0])
-        for _ in range(10):
-            pl = jnp.stack(
-                [state["v0h"][:, 2], state["v0h"][:, 3],
-                 state["v0h"][:, 0], state["v0h"][:, 1]], axis=1)
-            ph = jnp.stack(
-                [state["v0l"][:, 2], state["v0l"][:, 3],
-                 state["v0l"][:, 0], state["v0l"][:, 1]], axis=1)
-            state = _jax_update(jnp, state, pl, ph)
-
-        def modular(a3, a2, a1, a0):
-            a3l, a3h = a3
-            a2l, a2h = a2
-            a1l, a1h = a1
-            a0l, a0h = a0
-            a3h = a3h & 0x3FFFFFFF
-            s1l = (a3l << 1) | (a2h >> 31)
-            s1h = (a3h << 1) | (a3l >> 31)
-            s2l = (a3l << 2) | (a2h >> 30)
-            s2h = (a3h << 2) | (a3l >> 30)
-            m1l = a1l ^ s1l ^ s2l
-            m1h = a1h ^ s1h ^ s2h
-            m0l = a0l ^ (a2l << 1) ^ (a2l << 2)
-            m0h = a0h ^ ((a2h << 1) | (a2l >> 31)) \
-                ^ ((a2h << 2) | (a2l >> 30))
-            return (m1l, m1h), (m0l, m0h)
-
-        def lane_sum(col):
-            va = _add64(jnp, state["v1l"][:, col], state["v1h"][:, col],
-                        state["m1l"][:, col], state["m1h"][:, col])
-            vb = _add64(jnp, state["v0l"][:, col], state["v0h"][:, col],
-                        state["m0l"][:, col], state["m0h"][:, col])
-            return va, vb
-
-        (s1a, s1b), (s0a, s0b) = lane_sum(1), lane_sum(0)
-        h1, h0 = modular(s1a, s0a, s1b, s0b)
-        (s3a, s3b), (s2a, s2b) = lane_sum(3), lane_sum(2)
-        h3, h2 = modular(s3a, s2a, s3b, s2b)
-        words = jnp.stack(
-            [h0[0], h0[1], h1[0], h1[1], h2[0], h2[1], h3[0], h3[1]],
-            axis=1)  # (N, 8) uint32, LE word order
-        bytes_ = jnp.stack(
-            [(words >> (8 * i)) & 0xFF for i in range(4)],
-            axis=2).astype(jnp.uint8)
-        return bytes_.reshape(n, 32)
-
-    return run
-
-
-@functools.lru_cache(maxsize=8)
-def _hh256_rows_jit(key: bytes):
-    jax, _ = _jx()
-    return jax.jit(_hh256_rows_fn(key))
-
-
-def hh256_jax(blocks, key: bytes = MAGIC_HH256_KEY):
-    """Batched HighwayHash-256 as a jitted XLA program.
-
-    (N, L) uint8 -> (N, 32) uint8, bit-exact with ops/host.py::hh256.
-    Compiles per distinct (N, L) shape; callers on the PUT path only see
-    the few shard widths of a tick signature.
-    """
-    _, jnp = _jx()
-    blocks = jnp.asarray(blocks, dtype=jnp.uint8)
-    if blocks.ndim != 2:
-        raise ValueError("hh256_jax wants (N, L)")
-    if blocks.shape[0] == 0:
-        return jnp.empty((0, 32), dtype=jnp.uint8)
-    return _hh256_rows_jit(key)(blocks)
-
-
-# ---------------------------------------------------------------------------
-# Fused encode + frame-hash program
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=32)
-def fused_encode_hash(k: int, m: int, key: bytes = MAGIC_HH256_KEY):
-    """ONE program for a tick bucket: encode + per-shard frame hashes.
-
-    Returns a jitted ``run(batch)``: (B, K, S) uint8 data shards ->
-    ``(parity (B, M, S) uint8, hashes (B, K+M, 32) uint8)``.  The GF(2^8)
-    parity rows come from the same bit-plane matmul the plain encode
-    dispatch uses (ops/rs_tpu.py), and every shard row — data and parity —
-    is hashed inside the same XLA program, so payload bytes cross the
-    memory system once per PUT instead of once for encode plus once for
-    host hashing.  hashes[:, i, :] lines up with drive i's write_frames
-    rows in erasure/coding.py::encode_stream.
-    """
-    from . import rs_tpu
-    jax, jnp = _jx()
-    mat_bits = rs_tpu.encode_bits_matrix(k, m)
-    rows_fn = _hh256_rows_fn(key)
-
-    def run(batch):
-        b = batch.shape[0]
-        s = batch.shape[2]
-        parity = rs_tpu.gf_bitmatmul(mat_bits, batch)
-        rows = jnp.concatenate([batch, parity], axis=1)
-        hashes = rows_fn(rows.reshape(b * (k + m), s))
-        return parity, hashes.reshape(b, k + m, 32)
-
-    return jax.jit(run)
-
-
-# ---------------------------------------------------------------------------
-# MD5 etag fold (lax.scan over 64-byte blocks)
-# ---------------------------------------------------------------------------
-
-_MD5_INIT = np.array(
-    [0x67452301, 0xEFCDAB89, 0x98BADCFE, 0x10325476], dtype=np.uint32)
-_MD5_K = np.floor(
-    np.abs(np.sin(np.arange(1, 65, dtype=np.float64))) * (2.0 ** 32)
-).astype(np.uint64).astype(np.uint32)
-_MD5_S = ([7, 12, 17, 22] * 4 + [5, 9, 14, 20] * 4
-          + [4, 11, 16, 23] * 4 + [6, 10, 15, 21] * 4)
-
-
-@functools.lru_cache(maxsize=1)
-def _md5_scan_fn():
-    jax, jnp = _jx()
-    kconst = [int(x) for x in _MD5_K]
-
-    def block_fold(state, words):
-        # words: (16,) uint32 LE message words of one 64-byte block
-        a, b, c, d = state[0], state[1], state[2], state[3]
-        for i in range(64):
-            if i < 16:
-                f = (b & c) | (~b & d)
-                g = i
-            elif i < 32:
-                f = (d & b) | (~d & c)
-                g = (5 * i + 1) % 16
-            elif i < 48:
-                f = b ^ c ^ d
-                g = (3 * i + 5) % 16
-            else:
-                f = c ^ (b | ~d)
-                g = (7 * i) % 16
-            f = f + a + jnp.uint32(kconst[i]) + words[g]
-            sh = _MD5_S[i]
-            a, d, c, b = d, c, b, b + ((f << sh) | (f >> (32 - sh)))
-        return jnp.stack([state[0] + a, state[1] + b,
-                          state[2] + c, state[3] + d]), None
-
-    def run(state, words):  # state (4,) uint32, words (nblocks, 16) uint32
-        out, _ = jax.lax.scan(block_fold, state, words)
-        return out
-
-    return jax.jit(run)
-
-
-class Md5Fold:
-    """Streaming MD5 with the block folds running as a jitted scan.
-
-    hashlib-compatible result (hexdigest pinned bit-exact in tests); the
-    point is the fold happens on the accelerator next to the fused
-    encode+hash program instead of in a separate hash-lane process.
-    Sub-block tails are buffered host-side; full 64-byte spans go to the
-    device in one scan per update call.
-    """
-
-    def __init__(self):
-        self._state = None  # device (4,) uint32; lazily placed
-        self._state_np = _MD5_INIT.copy()
-        self._tail = b""
-        self._total = 0
-
-    def _fold(self, chunk: np.ndarray) -> None:
-        """chunk: (nblocks*64,) uint8 contiguous."""
-        _, jnp = _jx()
-        words = np.ascontiguousarray(chunk).view("<u4").reshape(-1, 16)
-        if self._state is None:
-            self._state = jnp.asarray(self._state_np)
-        self._state = _md5_scan_fn()(self._state, jnp.asarray(words))
-
-    def update(self, data) -> None:
-        if isinstance(data, np.ndarray):
-            data = np.ascontiguousarray(data, dtype=np.uint8)
-            buf = data.view(np.uint8).reshape(-1)
-        else:
-            buf = np.frombuffer(bytes(data), dtype=np.uint8)
-        self._total += buf.size
-        if self._tail:
-            need = 64 - len(self._tail)
-            take = min(need, buf.size)
-            self._tail += buf[:take].tobytes()
-            buf = buf[take:]
-            if len(self._tail) == 64:
-                self._fold(np.frombuffer(self._tail, dtype=np.uint8))
-                self._tail = b""
-        nblk = buf.size // 64
-        if nblk:
-            self._fold(buf[:nblk * 64])
-            buf = buf[nblk * 64:]
-        if buf.size:
-            self._tail = self._tail + buf.tobytes()
-
-    def _final_state(self) -> np.ndarray:
-        pad = self._tail + b"\x80"
-        pad += b"\x00" * ((56 - len(pad)) % 64)
-        pad += (self._total * 8 % (1 << 64)).to_bytes(8, "little")
-        chunk = np.frombuffer(pad, dtype=np.uint8)
-        if self._state is None:
-            self._state = _jx()[1].asarray(self._state_np)
-        final = _md5_scan_fn()(
-            self._state, _jx()[1].asarray(
-                np.ascontiguousarray(chunk).view("<u4").reshape(-1, 16)))
-        return np.asarray(final)
-
-    def hexdigest(self) -> str:
-        return self._final_state().astype("<u4").tobytes().hex()
-
-    def digest(self) -> bytes:
-        return self._final_state().astype("<u4").tobytes()
-
-
-def fused_etag_available() -> bool:
-    """Should put_data skip the hash-lane process and fold MD5 inline?
-
-    True when the fused-hash gate is on AND either a TPU is
-    present (the fold rides the accelerator next to the fused tick
-    program) or MINIO_TPU_FUSED_ETAG=1 forces it (tests / CPU
-    validation).  MINIO_TPU_FUSED_ETAG=0 force-disables regardless.
-    """
-    forced = os.environ.get("MINIO_TPU_FUSED_ETAG")
-    if forced == "0":
-        return False
-    if os.environ.get("MINIO_TPU_FUSED_HASH", "0") != "1":
-        return False
-    if forced == "1":
-        return True
-    return device.info().platform == "tpu"

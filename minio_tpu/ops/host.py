@@ -1,9 +1,9 @@
 """ctypes bindings for the C++ host library (csrc/).
 
 Provides:
-- HostRSCodec: AVX2 PSHUFB GF(2^8) codec — CPU fallback and the same-host
-  baseline bench.py compares TPU kernels against (the reference's
-  equivalent is klauspost/reedsolomon's AVX2 assembly).
+- HostRSCodec: AVX2 PSHUFB GF(2^8) codec: what serves tail blocks,
+  inline objects and the `host` backend (the reference's equivalent is
+  klauspost/reedsolomon's AVX2 assembly).
 - hh256 / HH256: bit-exact HighwayHash-256 for bitrot checksums
   (reference: minio/highwayhash used at cmd/bitrot.go:55).
 

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from minio_tpu.erasure import stagestats
 
@@ -36,12 +35,6 @@ from . import gf256, residency, rs_tpu
 # (`arith.shrsi/shrui` on i8 vectors and bitwidth-changing bitcasts fail
 # to legalize), so the int32-word layout below stands.
 _TILE_WORDS = 2048
-
-# The flat (K, N) kernel processes this many words per grid program (an
-# inner loop over _TILE_WORDS sub-tiles keeps VMEM intermediates small
-# while amortising per-program overhead).
-_FLAT_TILE_WORDS = 131072
-
 
 def _permute_mat(mat_bits: np.ndarray) -> np.ndarray:
     """Reorder a (R*8, K*8) bit matrix from byte-major (shard*8 + bit) to
@@ -116,64 +109,6 @@ def _coding_call(mat_bits: jax.Array, words: jax.Array, *, interpret: bool = Fal
     )(mat_bits, words)
 
 
-def _flat_kernel(mat_ref, seed_ref, in_ref, out_ref, *, ntiles, r):
-    """One grid program of the flat (K, N) layout.
-
-    Identical math to _coding_kernel but shard rows span the whole stream
-    (col = word index), matching how a shard's bytes are laid out on disk
-    (cmd/erasure-coding.go:122-150 shard arithmetic).  Each program owns
-    ntiles sub-tiles of _TILE_WORDS words and loops over them so VMEM
-    intermediates stay ~1.5 MiB while per-program overhead is amortised.
-
-    seed_ref is a (1,) SMEM scalar XORed into the input words — zero for
-    production use (identity).  bench.py threads the previous iteration's
-    parity word through it to build a sequentially-dependent chain that
-    defeats loop-invariant hoisting while adding one VPU op.
-    """
-    sub = _TILE_WORDS
-    s = seed_ref[0]
-    for t in range(ntiles):
-        x = in_ref[:, t * sub:(t + 1) * sub] ^ s  # (K, SUB) int32
-        out_ref[:, t * sub:(t + 1) * sub] = _code_tile(mat_ref[:], x, r)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _flat_coding_call(
-    mat_bits: jax.Array,
-    words: jax.Array,
-    seed: jax.Array | None = None,
-    *,
-    interpret: bool = False,
-):
-    """mat_bits (R8, K8) int8; words (K, N) int32 -> (R, N) int32.
-
-    The shard-contiguous layout: row k holds every word of shard k, the
-    natural shape for whole-extent encodes of large streams.  N must be a
-    multiple of _TILE_WORDS (8 KiB of shard bytes)."""
-    k, n = words.shape
-    r = mat_bits.shape[0] // 8
-    if n % _TILE_WORDS != 0:
-        raise ValueError(f"flat word count {n} not a multiple of {_TILE_WORDS}")
-    if seed is None:
-        seed = jnp.zeros((1,), jnp.int32)
-    tile = _FLAT_TILE_WORDS
-    while n % tile:
-        tile //= 2
-    kern = functools.partial(_flat_kernel, ntiles=tile // _TILE_WORDS, r=r)
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.int32),
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((mat_bits.shape[0], mat_bits.shape[1]), lambda ti: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((k, tile), lambda ti: (0, ti)),
-        ],
-        out_specs=pl.BlockSpec((r, tile), lambda ti: (0, ti)),
-        interpret=interpret,
-    )(mat_bits, seed, words)
-
-
 # The shard axis as the byte entry tiles it.
 SHARD_TILE = 4 * _TILE_WORDS
 
@@ -225,8 +160,8 @@ class PallasRSCodec:
     (EC 12+4, 14+2, 10+2 at 1 MiB blocks) the dispatch program widens
     the batch with zero columns and cuts the made rows back on the
     device (`_coding_call_bytes`), at no copy on the host: the `pad`
-    stage books the bytes with no seconds.  The word and flat entries
-    still want whole tiles.
+    stage books the bytes with no seconds.  The word entry still wants
+    whole tiles.
     """
 
     backend = "device"  # explicit dispatch-stats bucket (ADVICE r5)
@@ -278,21 +213,6 @@ class PallasRSCodec:
         if words.shape[-1] % _TILE_WORDS != 0:
             raise ValueError(f"word count must be a multiple of {_TILE_WORDS}")
         return _coding_call(self._enc, words, interpret=self._interpret)
-
-    def encode_flat(self, words) -> jax.Array:
-        """(K, N) int32 shard-contiguous words -> (M, N) int32 parity.
-
-        Whole-extent entry point: row k is shard k's packed bytes for the
-        entire stream, so one dispatch covers an arbitrarily large extent
-        (N a multiple of _TILE_WORDS)."""
-        words = jnp.asarray(words, dtype=jnp.int32)
-        return _flat_coding_call(self._enc, words, interpret=self._interpret)
-
-    def reconstruct_flat(self, words, available, wanted) -> jax.Array:
-        """(K, N) int32 surviving-shard words -> (len(wanted), N) int32."""
-        mat = self._rec_mat(available, wanted)
-        words = jnp.asarray(words, dtype=jnp.int32)
-        return _flat_coding_call(mat, words, interpret=self._interpret)
 
     def _rec_mat(self, available, wanted) -> jax.Array:
         sig = (tuple(available), tuple(wanted))

@@ -98,7 +98,7 @@ def sharded_encode_fn(mesh: Mesh, k: int, m: int):
 # programs concurrently can interleave their per-device enqueues in
 # different orders — device A runs thread 1's psum while device B runs
 # thread 2's, and both wait forever on their missing partners (observed
-# as a hard wedge on a 4-virtual-chip (2,2) mesh; BENCH_r13).  One
+# as a hard wedge on a 4-virtual-chip (2,2) mesh).  One
 # launch at a time keeps every device's queue in program order.
 # MODULE-level on purpose: codec instances are cached per (k, m)
 # geometry, so a per-instance lock would still let an 8+4 and a 4+2

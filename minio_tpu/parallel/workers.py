@@ -1,10 +1,10 @@
 """Multi-process data plane: shared-memory arena rings + I/O worker
 processes (ISSUE 8 / ROADMAP item 1 — escape the GIL).
 
-BENCH_r09 proved every PUT pipeline stage overlaps (stage-sum 7.1x wall)
-yet the wall stayed GIL-bound: read, md5 etag, erasure encode, bitrot
-hashing and shard writes all share ONE interpreter, so "overlapped"
-stages still convoy on bytecode glue.  This module shards the PUT data
+Every PUT pipeline stage overlaps, yet on the CPU box the wall stayed
+GIL-bound: read, md5 etag, erasure encode, bitrot hashing and shard
+writes all share ONE interpreter, so "overlapped" stages still convoy
+on bytecode glue.  This module shards the PUT data
 plane across OS processes:
 
 * ``WorkerPlane`` (front side) owns N spawned **I/O worker processes**
@@ -66,11 +66,14 @@ message as ``deadline_ms`` — the cross-process twin of the
 
 Teardown: the plane closes via ``shutdown_plane()`` (ServiceManager /
 S3Server close, conftest, atexit); segment names carry the
-``mtpu-ring-`` prefix so the conftest leak check can prove /dev/shm is
-clean, and the front's resource_tracker unlinks segments even after a
-SIGKILL.  Workers UNREGISTER attached segments from their own resource
-tracker — an attaching process must not unlink a segment the creator
-still owns (the documented CPython multi-process shm wart).
+``mtpu-ring-`` prefix and the creating process's pid
+(``segment_prefix()``) so the conftest leak check can prove that this
+process left nothing in /dev/shm without reading another process's
+live rings as litter, and the front's resource_tracker unlinks
+segments even after a SIGKILL.  Workers UNREGISTER attached segments
+from their own resource tracker — an attaching process must not unlink
+a segment the creator still owns (the documented CPython multi-process
+shm wart).
 """
 
 from __future__ import annotations
@@ -89,6 +92,12 @@ from minio_tpu.utils import deadline as deadline_mod
 from minio_tpu.utils import tracing
 
 SHM_PREFIX = "mtpu-ring-"
+
+
+def segment_prefix() -> str:
+    """What every ring segment this process creates is named by."""
+    return f"{SHM_PREFIX}{os.getpid()}-"
+
 
 # generation sentinel lengths published in a slot's len cell
 _EOF = -1    # producer finished cleanly
@@ -389,7 +398,7 @@ class _RingPool:
                 return shm
         total, _ = _ring_layout(nslots, slot_bytes, nconsumers)
         shm = shared_memory.SharedMemory(
-            name=f"{SHM_PREFIX}{uuid.uuid4().hex[:16]}", create=True,
+            name=f"{segment_prefix()}{uuid.uuid4().hex[:16]}", create=True,
             size=total)
         _register_segment(shm)
         return shm
@@ -984,22 +993,9 @@ class WorkerPlane:
             h = _WorkerHandle(self, "io", i)
             h.spawn()
             self.io.append(h)
-        # the dedicated hash-lane process is skipped when the fused
-        # etag fold is available (MINIO_TPU_FUSED_HASH + a device, or
-        # MINIO_TPU_FUSED_ETAG=1): put_data folds MD5 inline via the
-        # device scan (ops/hh_device.py::Md5Fold) instead of shipping
-        # every payload byte to a second process
-        fused_etag = False
-        try:
-            from minio_tpu.ops import hh_device
-
-            fused_etag = hh_device.fused_etag_available()
-        except Exception:
-            fused_etag = False
-        if not fused_etag:
-            h = _WorkerHandle(self, "hash", 0)
-            h.spawn()
-            self.hash = h
+        h = _WorkerHandle(self, "hash", 0)
+        h.spawn()
+        self.hash = h
 
     def child_env(self, kind: str) -> dict:
         """Env overrides for a child: the O_DIRECT device-write gate is
@@ -1039,8 +1035,7 @@ class WorkerPlane:
     def ping(self, timeout: float = 30.0) -> bool:
         """Round-trip every worker (spawn warmup / tests)."""
         try:
-            ps = [(h, h.send({"op": "ping"}))
-                  for h in self.io + ([self.hash] if self.hash else [])]
+            ps = [(h, h.send({"op": "ping"})) for h in self.io + [self.hash]]
             for h, p in ps:
                 h.wait(p, timeout)
             return True
@@ -1106,9 +1101,7 @@ class WorkerPlane:
             nslots = 2
         parts = self._partition(n, self.nworkers)
         handles = self.io[:len(parts)]
-        # + hash lane, unless the fused etag fold replaced it (then the
-        # producer folds MD5 inline and no hash consumer rides the ring)
-        nconsumers = len(handles) + (1 if self.hash is not None else 0)
+        nconsumers = len(handles) + 1  # + hash lane
         shm = self.rings.acquire(nslots, slot_bytes, nconsumers)
         prod = RingProducer(shm, nslots, slot_bytes, nconsumers)
         if os.environ.get("MINIO_TPU_MP_TRACE"):
@@ -1172,28 +1165,22 @@ class WorkerPlane:
                     dead.add(c)
                     for s, _r in drives:
                         failed[s] = ex
-            md5_fold = None
-            if self.hash is not None:
-                hmsg = dict(base)
-                hmsg.update({"op": "hash", "consumer": len(handles),
-                             "drives": []})
+            hmsg = dict(base)
+            hmsg.update({"op": "hash", "consumer": len(handles),
+                         "drives": []})
+            try:
+                gens[len(handles)] = self.hash.restarts
+                hash_span = tracing.begin("mp.job", op="hash")
+                hash_pending = self.hash.send(hmsg)
+            except WorkerDied:
+                # no etag lane, no PUT: unblock the io workers (they
+                # would otherwise wait out the whole ring window on a
+                # generation that never comes) and surface retryable
                 try:
-                    gens[len(handles)] = self.hash.restarts
-                    hash_span = tracing.begin("mp.job", op="hash")
-                    hash_pending = self.hash.send(hmsg)
+                    prod.finish(dead_fn, abort=True, timeout=5.0)
                 except WorkerDied:
-                    # no etag lane, no PUT: unblock the io workers (they
-                    # would otherwise wait out the whole ring window on a
-                    # generation that never comes) and surface retryable
-                    try:
-                        prod.finish(dead_fn, abort=True, timeout=5.0)
-                    except WorkerDied:
-                        pass
-                    raise
-            else:
-                from minio_tpu.ops import hh_device
-
-                md5_fold = hh_device.Md5Fold()
+                    pass
+                raise
 
             total = 0
             t_read = 0.0
@@ -1211,14 +1198,6 @@ class WorkerPlane:
                     t_read += time.perf_counter() - t0
                     if not got:
                         break
-                    if md5_fold is not None:
-                        # fused etag: fold before publish — the slot's
-                        # bytes are stable here, and the device scan
-                        # dispatches async so the next fill overlaps it
-                        t0 = time.perf_counter()
-                        md5_fold.update(view[:got])
-                        stagestats.add(
-                            "etag", time.perf_counter() - t0, got)
                     prod.publish(got)
                     total += got
                     if got < want:
@@ -1254,26 +1233,18 @@ class WorkerPlane:
                     tracing.graft(out.get("trace"), sp)
                     sp.finish()
                 self.last_worker_wall = out.get("wall")
-            if md5_fold is not None:
-                # fused etag: the producer folded every published byte
-                # inline, so the lane's "did you see it all" invariant
-                # holds by construction
-                t0 = time.perf_counter()
-                etag = md5_fold.hexdigest()
-                stagestats.add("etag", time.perf_counter() - t0, 0)
-            else:
-                hout = self.hash.wait(hash_pending, timeout)
-                if hash_span is not None:
-                    tracing.graft(hout.get("trace"), hash_span)
-                    hash_span.finish()
-                st = hout.get("stage", {})
-                for stage, secs in st.items():
-                    stagestats.add(stage, secs, 0)
-                etag = hout.get("md5", "")
-                if not etag or hout.get("total") != total:
-                    raise WorkerDied(
-                        "hash lane did not observe the full payload "
-                        f"({hout.get('total')} != {total})")
+            hout = self.hash.wait(hash_pending, timeout)
+            if hash_span is not None:
+                tracing.graft(hout.get("trace"), hash_span)
+                hash_span.finish()
+            st = hout.get("stage", {})
+            for stage, secs in st.items():
+                stagestats.add(stage, secs, 0)
+            etag = hout.get("md5", "")
+            if not etag or hout.get("total") != total:
+                raise WorkerDied(
+                    "hash lane did not observe the full payload "
+                    f"({hout.get('total')} != {total})")
             now = time.perf_counter()
             # per-phase wall of the last job (debugging/bench aid):
             # feed = producing into the ring (incl. slot waits),

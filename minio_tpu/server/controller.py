@@ -11,8 +11,8 @@ on the knob.  The reference self-regulates the same surfaces from
 in-process heuristics (adaptive API throttling in cmd/handler-api.go,
 dynamic scanner/heal cycles); here the feedback signal is the burn
 rate itself, so the loop answers regime shifts (flash crowds, tenant-
-mix flips, stacked faults) the static config fails — proven closed-
-loop by `bench.py controller`.
+mix flips, stacked faults) a static config fails (no record from the
+chip says so yet: ROADMAP D6).
 
 Each tick the controller SAMPLES a snapshot (SLO status with the per-
 tenant split, QoS stats, the QoS reconfigure generation), then DECIDES

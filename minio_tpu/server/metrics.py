@@ -226,13 +226,6 @@ class MetricsMixin:
                      "# TYPE minio_dataplane_stage_wall_seconds_total "
                      "gauge"]
             for stage, d in snap.items():
-                if (stage == "fused_hash" and not d["seconds"]
-                        and not d["bytes"]):
-                    # the fused-hash stage only exists while
-                    # MINIO_TPU_FUSED_HASH routes work into it: a
-                    # gate-off scrape stays byte-identical to before
-                    # the lane existed (the 0<->1 differential pins it)
-                    continue
                 lbl = _fmt_labels(("stage",), (stage,))
                 srows.append("minio_dataplane_stage_seconds_total"
                              f"{lbl} {round(d['seconds'], 6)}")

@@ -4,9 +4,8 @@ Declarative scenarios replayed against the REAL HTTP server with
 seeded-deterministic arrival schedules; each scenario asserts its SLOs
 through the server's own SLO plane (``GET /minio/admin/v3/slo``) and a
 violated scenario pulls the retained trace store to attribute the
-violation to the dominant span stage.  ``python bench.py sim`` drives
-the builtin scenario set and writes the SIM_r01.json regression
-surface.
+violation to the dominant span stage.  tests/test_simulator.py replays
+short scenarios of every family.
 """
 
 from .engine import ScenarioEngine, build_schedule, schedule_digest

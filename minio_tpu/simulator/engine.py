@@ -4,7 +4,7 @@
 arrival schedule — a pure function of the scenario (seeded
 ``random.Random``, no wall clock): same seed, same Poisson arrival
 times, same op/key/size sequence.  ``schedule_digest`` pins that
-(SIM_r01.json records it; a re-run must reproduce it bit-exact).
+(a re-run must reproduce it bit-exact).
 
 :class:`ScenarioEngine` replays a schedule against a REAL HTTP server:
 one persistent SigV4-signing connection per simulated client, open-loop
@@ -191,7 +191,7 @@ def build_schedule(sc) -> list[dict]:
 
 
 def schedule_digest(schedule: list[dict]) -> str:
-    """The reproducibility pin recorded per scenario in SIM_r01.json."""
+    """The reproducibility pin of a scenario's schedule."""
     return hashlib.sha256(json.dumps(
         schedule, sort_keys=True, separators=(",", ":")).encode()
     ).hexdigest()

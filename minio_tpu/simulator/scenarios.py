@@ -5,10 +5,10 @@ a deterministic arrival schedule (see ``engine.build_schedule`` — same
 seed, same schedule, same request sequence) plus the SLOs the scenario
 asserts after replay and the chaos hook it arms mid-run.
 
-``builtin_scenarios()`` is the production mix ``python bench.py sim``
-replays: zipf read fan-in, multipart ingest storm, list-heavy
-analytics, a multi-tenant QoS mix, and two chaos variants (flaky-drive
-brownout, pool drain under live traffic — the PR 14 harness shape).
+``builtin_scenarios()`` is the production mix: zipf read fan-in,
+multipart ingest storm, list-heavy analytics, a multi-tenant QoS mix,
+and two chaos variants (flaky-drive brownout, pool drain under live
+traffic — the PR 14 harness shape).
 Scenario SLO grammar::
 
     slo = {
@@ -85,11 +85,10 @@ class Scenario:
 
 
 def builtin_scenarios(scale: float = 1.0) -> list[Scenario]:
-    """The ``bench.py sim`` set.  ``scale`` multiplies durations
-    (rates are part of each scenario's identity and stay fixed) so a
-    short tier can exercise the same shapes in less wall time; seeds
-    are fixed — the schedule digests in SIM_r01.json are the
-    reproducibility pin."""
+    """The builtin set.  ``scale`` multiplies durations (rates are part
+    of each scenario's identity and stay fixed) so a short tier can
+    exercise the same shapes in less wall time; seeds are fixed, so a
+    scenario's schedule digest is its reproducibility pin."""
     d = lambda s: max(3.0, s * scale)  # noqa: E731
 
     return [
@@ -172,9 +171,9 @@ def builtin_scenarios(scale: float = 1.0) -> list[Scenario]:
 
 def controller_scenarios(scale: float = 1.0) -> list[Scenario]:
     """The regime-shift family (ISSUE 18): each scenario is replayed
-    TWICE by ``bench.py controller`` — once with the static config only
+    TWICE by its harness: once with the static config only
     (``MINIO_TPU_CONTROLLER=0``) and once with the overload controller
-    on — against a deliberately scarce server (4 admission slots,
+    on, against a deliberately scarce server (4 admission slots,
     600ms request deadline, hot cache off, a ~40ms floor on every
     drive op) so saturation is a property of the schedule, not of box
     noise.
@@ -293,8 +292,7 @@ def georep_scenarios(scale: float = 1.0) -> list[Scenario]:
     joined site peer.  The engine grades the primary-facing SLO (the
     whole point of the async push queue is that the client never waits
     on the WAN); cross-site convergence and read-your-writes are graded
-    AFTER replay by the harness polling the secondary for byte-identity
-    (``bench.py sim`` records both next to the scenario verdicts).
+    AFTER replay by the harness polling the secondary for byte-identity.
 
     Each scenario owns its bucket so convergence checks can't bleed
     across scenarios.  Chaos hooks the harness must register:

@@ -319,28 +319,28 @@ def _racecheck_report():
 
 # ------------------------------------------------------- shm leak check
 # The multi-process data plane (minio_tpu/parallel/workers.py) creates
-# named /dev/shm segments (mtpu-ring-*) and spawns worker processes.  A
-# test that leaks either would silently tax every later test (and a
-# SIGKILL'd run would litter /dev/shm for the whole machine), so the
-# session asserts both are gone at teardown — after shutting the plane
-# down itself, which is also what guarantees the check runs even when a
-# test forgot its own cleanup.
+# named /dev/shm segments (mtpu-ring-<pid>-*) and spawns worker
+# processes.  A test that leaks either would silently tax every later
+# test (and a SIGKILL'd run would litter /dev/shm for the whole
+# machine), so the session asserts both are gone at teardown — after
+# shutting the plane down itself, which is also what guarantees the
+# check runs even when a test forgot its own cleanup.  Only segments
+# this process created count: tier-1 runs several pytest processes at
+# once (xdist workers, the serial drills' children), and a session that
+# read another's live rings as litter failed for no fault of its own
+# and unlinked them under a running test.
 
 @pytest.fixture(scope="session", autouse=True)
 def _mp_plane_leak_check():
-    def shm_litter():
-        try:
-            return sorted(f for f in os.listdir("/dev/shm")
-                          if f.startswith("mtpu-"))
-        except OSError:
-            return []
-
-    before = set(shm_litter())
     yield
     from minio_tpu.parallel import workers as _workers
 
     _workers.shutdown_plane()
-    leaked = [f for f in shm_litter() if f not in before]
+    try:
+        leaked = sorted(f for f in os.listdir("/dev/shm")
+                        if f.startswith(_workers.segment_prefix()))
+    except OSError:
+        leaked = []
     import multiprocessing as _mp
 
     kids = [p for p in _mp.active_children()

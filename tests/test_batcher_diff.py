@@ -493,152 +493,3 @@ class TestGatePins:
         assert a is b
         assert after["hits"] == before["hits"] + 1
         assert after["misses"] == before["misses"]
-
-
-# ------------------------------------------------- fused hash+encode gate
-class TestFusedHashGate:
-    """MINIO_TPU_FUSED_HASH (ISSUE 20) must be exactly as invisible as
-    the batcher gate above: every shard file/xl.meta/etag, every GET
-    body, every healed frame byte-identical between gate on and off —
-    the fused kernel's frame hashes land on disk, so bit-exactness IS
-    data integrity here, not a nicety.  Same matrix as the PR 11 gate:
-    inline/aligned/unaligned/multipart/degraded-GET/heal."""
-
-    @pytest.mark.parametrize("size", [
-        100,                 # inline: shards live in xl.meta
-        200_000,             # non-inline single block
-        (1 << 20) * 3 + 17,  # unaligned multi-block (tail frame)
-        (4 << 20),           # aligned multi-block
-    ])
-    def test_put_object_identical(self, two_sets, monkeypatch, size):
-        roots, apis = two_sets
-        data = _rng(size).integers(0, 256, size, dtype=np.uint8).tobytes()
-        opts = PutObjectOptions(mod_time=1_700_000_000.0)
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "1")
-        oi_on = apis[0].put_object("bkt", "o", io.BytesIO(data), size,
-                                   opts)
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "0")
-        oi_off = apis[1].put_object("bkt", "o", io.BytesIO(data), size,
-                                    opts)
-        assert oi_on.etag == oi_off.etag == hashlib.md5(data).hexdigest()
-        files_on = _drive_files(roots[0])
-        files_off = _drive_files(roots[1])
-        assert files_on.keys() == files_off.keys()
-        for name in files_on:
-            assert files_on[name] == files_off[name], name
-        # the fused-written frames read back through the VERIFYING
-        # bitrot reader with the gate still on
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "1")
-        _, stream = apis[0].get_object("bkt", "o")
-        assert b"".join(bytes(c) for c in stream) == data
-
-    def test_fused_rides_the_batcher(self, two_sets, monkeypatch):
-        """Both gates on: the fused encode+hash tick ('ench' signature)
-        goes THROUGH the batcher and still lands byte-identical vs
-        both-gates-off."""
-        roots, apis = two_sets
-        size = (2 << 20) + 4097
-        data = _rng(77).integers(0, 256, size, dtype=np.uint8).tobytes()
-        opts = PutObjectOptions(mod_time=1_700_000_000.0)
-        monkeypatch.setenv("MINIO_TPU_BATCHER", "1")
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "1")
-        st0 = batcher_mod.get().stats_snapshot()
-        apis[0].put_object("bkt", "o", io.BytesIO(data), size, opts)
-        st1 = batcher_mod.get().stats_snapshot()
-        assert st1["items"] > st0["items"], "fused PUT bypassed batcher"
-        monkeypatch.setenv("MINIO_TPU_BATCHER", "0")
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "0")
-        apis[1].put_object("bkt", "o", io.BytesIO(data), size, opts)
-        assert _drive_files(roots[0]) == _drive_files(roots[1])
-
-    def test_multipart_identical(self, two_sets, monkeypatch):
-        roots, apis = two_sets
-        rng = _rng(13)
-        p1 = rng.integers(0, 256, 6 << 20, dtype=np.uint8).tobytes()
-        p2 = rng.integers(0, 256, (5 << 20) + 313, dtype=np.uint8).tobytes()
-        etags = []
-        for gate, api in (("1", apis[0]), ("0", apis[1])):
-            monkeypatch.setenv("MINIO_TPU_FUSED_HASH", gate)
-            up = api.new_multipart_upload("bkt", "mp")
-            pi1 = api.put_object_part("bkt", "mp", up, 1,
-                                      io.BytesIO(p1), len(p1))
-            pi2 = api.put_object_part("bkt", "mp", up, 2,
-                                      io.BytesIO(p2), len(p2))
-            oi = api.complete_multipart_upload(
-                "bkt", "mp", up, [(1, pi1.etag), (2, pi2.etag)])
-            etags.append((pi1.etag, pi2.etag, oi.etag))
-            _, stream = api.get_object("bkt", "mp")
-            assert b"".join(bytes(c) for c in stream) == p1 + p2
-        assert etags[0] == etags[1]
-        vals_on = sorted(v for k, v in _drive_files(roots[0]).items()
-                         if k.endswith(("part.1", "part.2")))
-        vals_off = sorted(v for k, v in _drive_files(roots[1]).items()
-                          if k.endswith(("part.1", "part.2")))
-        assert vals_on == vals_off
-
-    def test_degraded_get_and_heal_identical(self, two_sets, monkeypatch):
-        """Fused-written objects survive the failure paths: a
-        reconstructing GET returns exact bytes, and a deep heal (which
-        REWRITES frames — with the gate on, through the fused lane)
-        converges to the same files as the gate-off twin."""
-        roots, apis = two_sets
-        size = (1 << 20) + 137 * 4
-        data = _rng(17).integers(0, 256, size, dtype=np.uint8).tobytes()
-        opts = PutObjectOptions(mod_time=1_700_000_000.0)
-        snaps = {}
-        for gate, api, root in (("1", apis[0], roots[0]),
-                                ("0", apis[1], roots[1])):
-            monkeypatch.setenv("MINIO_TPU_FUSED_HASH", gate)
-            api.put_object("bkt", "h", io.BytesIO(data), size, opts)
-            # degraded GET: drop a data shard file, read, restore via heal
-            fi, _, _ = api._quorum_info("bkt", "h")
-            victim = next(
-                i for i, pos in enumerate(fi.erasure.distribution)
-                if pos - 1 < fi.erasure.data_blocks)
-            for p in glob.glob(os.path.join(root, f"d{victim}", "bkt",
-                                            "**", "part.*"),
-                               recursive=True):
-                os.unlink(p)
-            _, stream = api.get_object("bkt", "h")
-            assert b"".join(bytes(c) for c in stream) == data, gate
-            res = api.heal_object("bkt", "h", deep=True)
-            assert not res.failed and res.healed_drives == 1, gate
-            snaps[gate] = _drive_files(root)
-        assert snaps["1"] == snaps["0"]
-
-    def test_metrics_row_absent_when_off(self, monkeypatch):
-        """Gate-off scrape identity: with no fused work ever booked the
-        stage families carry NO stage="fused_hash" row — a pre-ISSUE-20
-        dashboard sees an unchanged scrape.  Once the lane books bytes,
-        the row appears."""
-        import types
-
-        from minio_tpu.erasure import stagestats
-        from minio_tpu.server.metrics import MetricsMixin
-
-        class _Reg:
-            def render(self):
-                return ""
-
-        srv = types.SimpleNamespace(metrics=_Reg(), api=None)
-        monkeypatch.setitem(stagestats._seconds, "fused_hash", 0.0)
-        monkeypatch.setitem(stagestats._bytes, "fused_hash", 0)
-        text = MetricsMixin._render_metrics(srv)
-        assert 'stage="fused_hash"' not in text
-        assert 'stage="encode"' in text  # the family itself renders
-        stagestats.add("fused_hash", 0.0, 4096)
-        text = MetricsMixin._render_metrics(srv)
-        assert ('minio_dataplane_stage_bytes_total{stage="fused_hash"}'
-                in text)
-
-    def test_fused_sources_pragma_free(self):
-        """ISSUE 20 satellite: the fused kernel module joins the
-        analysis gate (worker processes import it through coding.py)
-        with zero pragmas, like the rest of the erasure plane."""
-        path = os.path.join(REPO, "minio_tpu", "ops", "hh_device.py")
-        with open(path, encoding="utf-8") as fh:
-            assert "# lint: allow" not in fh.read(), (
-                "pragma crept into ops/hh_device.py")
-        from minio_tpu.analysis.rules.shared_state import WORKER_SURFACE
-
-        assert "ops/hh_device.py" in WORKER_SURFACE

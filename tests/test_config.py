@@ -165,3 +165,30 @@ class TestStartupApply:
             assert s2.server.services.scanner.interval == 7
         finally:
             s2.close()
+
+
+def test_every_gate_is_listed():
+    """The `MINIO_TPU_*` names the package reads are the names of the
+    README's table, both ways: a gate cannot arrive unlisted, a row
+    cannot outlive its gate, and the count (ROADMAP D1) only goes down."""
+    import re
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = re.compile(r"MINIO_TPU_[A-Z0-9_]+")
+    read = set()
+    for dirpath, _dirs, files in os.walk(os.path.join(repo, "minio_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(dirpath, f), encoding="utf-8") as fh:
+                    read |= set(name.findall(fh.read()))
+    with open(os.path.join(repo, "README.md"), encoding="utf-8") as fh:
+        readme = fh.read()
+    start = readme.index("## Environment variables (`MINIO_TPU_*`)")
+    table = readme[start:readme.index("\n## ", start + 1)]
+    listed = [name.search(line).group(0) for line in table.splitlines()
+              if line.startswith("| `")]
+    assert len(listed) == len(set(listed)), "a row is there twice"
+    assert set(listed) == read, (
+        f"read and not listed: {sorted(read - set(listed))}; "
+        f"listed and not read: {sorted(set(listed) - read)}")
+    assert len(read) <= 104, f"{len(read)} gates: ROADMAP D1 counts down"

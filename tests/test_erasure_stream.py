@@ -617,3 +617,142 @@ def test_bitrot_file_size_math():
     assert bitrot.bitrot_shard_file_size(0, e.shard_size) == 0
     # 1 MiB part -> shard 128KiB, one block -> 32 + 131072
     assert bitrot.bitrot_shard_file_size(e.shard_size, e.shard_size) == 32 + e.shard_size
+
+
+# ------------------------------------------ what a PUT leaves on the drives
+# Through the object layer, every shard file read back raw and held to the
+# oracles: where the shard lies (hashOrder), the body's split, the parity
+# (ops/gf256.py, numpy) and each frame's 32-byte prefix (ops/hh_device.py
+# hh256_batch_np, numpy u64).  A writer that frames a wrong digest, or an
+# encode that hands over a wrong row, fails here on the file itself.
+_ONDISK_BLOCK = 1 << 20
+
+
+def _expected_files(body: bytes, k: int, m: int) -> list[bytes]:
+    """The k+m shard files of one erasure stream, hash prefixes and all:
+    the whole blocks (hashed in one call: the oracle loops over packets
+    in Python), then the shorter tail block."""
+    from minio_tpu.ops import gf256, hh_device
+
+    data = np.frombuffer(body, dtype=np.uint8)
+    files = [bytearray() for _ in range(k + m)]
+    nfull = len(body) // _ONDISK_BLOCK
+    for pieces in (data[:nfull * _ONDISK_BLOCK].reshape(nfull, _ONDISK_BLOCK),
+                   data[nfull * _ONDISK_BLOCK:].reshape(1, -1)):
+        nb, length = pieces.shape
+        if not nb or not length:
+            continue
+        shard = -(-length // k)
+        split = np.zeros((nb, k * shard), dtype=np.uint8)
+        split[:, :length] = pieces
+        rows = split.reshape(nb, k, shard)
+        rows = np.concatenate(
+            [rows, np.stack([gf256.encode_np(r, m) for r in rows])], axis=1)
+        digests = hh_device.hh256_batch_np(
+            rows.reshape(nb * (k + m), shard)).reshape(nb, k + m, 32)
+        for b in range(nb):
+            for i in range(k + m):
+                files[i] += digests[b, i].tobytes() + rows[b, i].tobytes()
+    return [bytes(f) for f in files]
+
+
+def _shard_index_of_drive(key: str, n: int) -> list[int]:
+    """0-based shard index that drive 0..n-1 holds (the reference's
+    hashOrder, cmd/erasure-metadata-utils.go:107)."""
+    import zlib
+
+    start = (zlib.crc32(key.encode()) & 0xFFFFFFFF) % n
+    return [(start + i) % n for i in range(1, n + 1)]
+
+
+class TestOnDiskAgainstOracle:
+    GEOMETRIES = [(2, 2), (4, 2), (12, 4)]  # 12+4: 87,382-byte shards
+
+    @pytest.fixture()
+    def drives(self, tmp_path, request):
+        from minio_tpu.erasure import multipart  # noqa: F401  (binds methods)
+        from minio_tpu.erasure.objects import ErasureObjects
+        from minio_tpu.storage.local import LocalStorage
+
+        k, m = request.param
+        disks = [LocalStorage(str(tmp_path / f"d{i}")) for i in range(k + m)]
+        for d in disks:
+            d.make_volume("bkt")
+        return k, m, disks, ErasureObjects(disks, default_parity=m)
+
+    @staticmethod
+    def _body(size, seed):
+        return np.random.default_rng(seed).integers(
+            0, 256, size, dtype=np.uint8).tobytes()
+
+    @staticmethod
+    def _held(disks, key, part):
+        """Raw bytes of `part` on every drive: the part file, or for an
+        inline object the shard inside xl.meta."""
+        import glob
+
+        out = []
+        for d in disks:
+            paths = glob.glob(os.path.join(
+                glob.escape(os.path.join(d.root, "bkt", key)), "*", part))
+            if paths:
+                assert len(paths) == 1, paths
+                with open(paths[0], "rb") as f:
+                    out.append(f.read())
+            else:
+                out.append(d.read_version("bkt", key, read_data=True).data)
+        return out
+
+    def _check(self, disks, key, part, body, k, m):
+        want = _expected_files(body, k, m)
+        held = self._held(disks, key, part)
+        for drive, shard in enumerate(_shard_index_of_drive(key, k + m)):
+            assert held[drive] is not None, f"drive {drive} holds nothing"
+            assert len(held[drive]) == len(want[shard]), (drive, shard)
+            assert held[drive] == want[shard], (
+                f"drive {drive}, shard {shard} of {k}+{m}")
+
+    @pytest.mark.parametrize("size", [
+        100,                 # inline: shards live in xl.meta
+        200_000,             # one short block
+        (1 << 20) * 3 + 17,  # whole blocks and a tail frame
+        (4 << 20),           # whole blocks alone
+    ])
+    @pytest.mark.parametrize("drives", GEOMETRIES, indirect=True,
+                             ids=lambda g: f"{g[0]}+{g[1]}")
+    def test_put_object(self, drives, size):
+        k, m, disks, api = drives
+        body = self._body(size, size)
+        key = f"put/{size}"
+        api.put_object("bkt", key, io.BytesIO(body), size)
+        self._check(disks, key, "part.1", body, k, m)
+
+    @pytest.mark.parametrize("drives", GEOMETRIES, indirect=True,
+                             ids=lambda g: f"{g[0]}+{g[1]}")
+    def test_multipart_upload(self, drives):
+        k, m, disks, api = drives
+        p1 = self._body(5 << 20, 13)
+        p2 = self._body((1 << 20) + 313, 14)
+        up = api.new_multipart_upload("bkt", "mp")
+        e1 = api.put_object_part("bkt", "mp", up, 1, io.BytesIO(p1), len(p1))
+        e2 = api.put_object_part("bkt", "mp", up, 2, io.BytesIO(p2), len(p2))
+        api.complete_multipart_upload(
+            "bkt", "mp", up, [(1, e1.etag), (2, e2.etag)])
+        self._check(disks, "mp", "part.1", p1, k, m)
+        self._check(disks, "mp", "part.2", p2, k, m)
+
+    @pytest.mark.parametrize("drives", GEOMETRIES, indirect=True,
+                             ids=lambda g: f"{g[0]}+{g[1]}")
+    def test_heal_after_a_drive_lost_its_files(self, drives):
+        import shutil
+
+        k, m, disks, api = drives
+        size = (2 << 20) + 137 * 4
+        body = self._body(size, 17)
+        api.put_object("bkt", "h", io.BytesIO(body), size)
+        # the drive that holds data shard 0: the heal rebuilds a data row
+        victim = _shard_index_of_drive("h", k + m).index(0)
+        shutil.rmtree(os.path.join(disks[victim].root, "bkt", "h"))
+        res = api.heal_object("bkt", "h")
+        assert not res.failed and res.healed_drives == 1
+        self._check(disks, "h", "part.1", body, k, m)

@@ -1,23 +1,14 @@
-"""Fused hash+encode kernels (ISSUE 20): bit-exactness of the batched
-HighwayHash-256 implementations against the streaming C reference.
+"""The numpy HighwayHash-256 oracle against the C hasher, and the bitrot
+writer's batched frame path.
 
-Three implementations must agree byte-for-byte with ops/host.py::hh256
-(itself golden-pinned against the reference bitrot self-test,
-cmd/bitrot.go:37):
-
-* hh256_batch_np — the vectorized numpy oracle (also the no-C-library
-  fallback on the host fused path);
-* hh256_jax — the XLA kernel the fused encode+hash device program uses;
-* fused_encode_hash — the one-launch program: parity must equal the
-  host codec's, per-shard frame hashes must equal hh256 of the rows.
-
-The JAX kernels compile ~30s PER DISTINCT (N, L) SHAPE on a CPU box
-(lax.scan over packets), so the broad jax sweeps are `slow`; tier-1
-keeps the full numpy-oracle sweep, the reference-self-test extension,
-the Md5Fold differential and the write_frames(hashes=) plumbing.
+`hh_device.hh256_batch_np` must agree byte-for-byte with ops/host.py
+`hh256` (itself golden-pinned against the reference bitrot self-test,
+cmd/bitrot.go:37): it is the reference the on-disk tests hold every
+frame's 32-byte prefix to (tests/test_erasure_stream.py
+TestOnDiskAgainstOracle).  `BitrotWriter.write_frames` is the one way a
+PUT's shard rows reach a shard file; it always hashes them itself.
 """
 
-import hashlib
 import io
 
 import numpy as np
@@ -84,115 +75,58 @@ class TestOracle:
             np.empty((0, 64), dtype=np.uint8)).shape == (0, 32)
 
 
-# ------------------------------------------------------ JAX kernels
-class TestJaxKernel:
-    def test_one_shape_matches_oracle(self):
-        """ONE thin tier-1 shape so the device lane never regresses
-        silently; the broad sweep is `slow` (per-shape XLA compile)."""
-        jax = pytest.importorskip("jax")
-        blocks = _rand(3, 100, 21)
-        np.testing.assert_array_equal(
-            hh_device.hh256_jax(blocks), hh_device.hh256_batch_np(blocks))
-
-    @pytest.mark.slow
-    def test_shape_sweep_matches_oracle(self):
-        jax = pytest.importorskip("jax")
-        for n, l in ((1, 0), (1, 1), (2, 17), (3, 32), (2, 255),
-                     (4, 1000), (2, 8192)):
-            blocks = _rand(n, l, 31 * n + l)
-            np.testing.assert_array_equal(
-                hh_device.hh256_jax(blocks),
-                hh_device.hh256_batch_np(blocks), err_msg=str((n, l)))
-
-    @pytest.mark.slow
-    def test_fused_encode_hash_parity_and_hashes(self):
-        """The one-launch program: parity == host codec, hashes ==
-        streaming hh256 of every data AND parity row."""
-        jax = pytest.importorskip("jax")
-        k, m, b, s = 4, 2, 3, 1024
-        batch = np.random.default_rng(41).integers(
-            0, 256, size=(b, k, s), dtype=np.uint8)
-        parity, hashes = hh_device.fused_encode_hash(k, m)(batch)
-        parity, hashes = np.asarray(parity), np.asarray(hashes)
-        np.testing.assert_array_equal(
-            parity, host.HostRSCodec(k, m).encode(batch))
-        assert hashes.shape == (b, k + m, 32)
-        rows = np.concatenate([batch, parity], axis=1)
-        for bi in range(b):
-            for si in range(k + m):
-                assert bytes(hashes[bi, si]) == host.hh256(
-                    rows[bi, si].tobytes()), (bi, si)
-
-
-# ------------------------------------------------------ MD5 etag fold
-class TestMd5Fold:
-    @pytest.mark.slow
-    def test_matches_hashlib_across_padding_classes(self):
-        jax = pytest.importorskip("jax")
-        rng = np.random.default_rng(51)
-        for l in (0, 1, 55, 56, 57, 63, 64, 65, 1000, 100_000):
-            data = rng.integers(0, 256, size=l, dtype=np.uint8).tobytes()
-            f = hh_device.Md5Fold()
-            # odd split sizes exercise the tail-carry re-assembly
-            for off in range(0, l, 977):
-                f.update(data[off:off + 977])
-            if l == 0:
-                f.update(b"")
-            assert f.hexdigest() == hashlib.md5(data).hexdigest(), l
-            assert f.digest() == hashlib.md5(data).digest()
-
-    def test_availability_gate(self, monkeypatch):
-        monkeypatch.setenv("MINIO_TPU_FUSED_ETAG", "0")
-        assert not hh_device.fused_etag_available()
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "0")
-        monkeypatch.setenv("MINIO_TPU_FUSED_ETAG", "1")
-        assert not hh_device.fused_etag_available()  # fused gate off
-        monkeypatch.setenv("MINIO_TPU_FUSED_HASH", "1")
-        assert hh_device.fused_etag_available()      # explicit opt-in
-
-
-# ------------------------------------------------ writer-side plumbing
-class TestWriteFramesPrecomputed:
-    def _frames(self, blocks, hashes=None):
+# ------------------------------------------------ the writer's frame path
+class TestWriteFrames:
+    def test_frames_verify_through_the_reader(self):
+        """A full batch and a short last row, each frame prefixed with
+        the oracle's digest, read back through the verifying reader."""
+        blocks = _rand(3, 256, 64)
+        last = _rand(1, 100, 65)
         buf = io.BytesIO()
-        w = bitrot.BitrotWriter(buf, shard_size=blocks.shape[1])
-        w.write_frames(blocks, hashes=hashes) if hashes is not None \
-            else w.write_frames(blocks)
-        return buf.getvalue()
+        w = bitrot.BitrotWriter(buf, shard_size=256)
+        w.write_frames(blocks)
+        w.write_frames(last)
+        raw = buf.getvalue()
+        assert w.written == len(raw) == 3 * (32 + 256) + 32 + 100
+        want = hh_device.hh256_batch_np(blocks)
+        for i in range(3):
+            frame = raw[i * 288:(i + 1) * 288]
+            assert frame[:32] == bytes(want[i]), i
+            assert frame[32:] == blocks[i].tobytes(), i
+        assert raw[864:896] == bytes(hh_device.hh256_batch_np(last)[0])
+        r = bitrot.BitrotReader(io.BytesIO(raw), till_offset=3 * 256 + 100,
+                                shard_size=256)
+        np.testing.assert_array_equal(r.read_blocks(0, 3, 256), blocks)
+        assert bytes(r.read_at(3 * 256, 100)) == last.tobytes()
 
-    def test_precomputed_hashes_byte_identical(self):
-        blocks = _rand(4, 512, 61)
-        hashes = host.hh256_batch(blocks)
-        assert self._frames(blocks, hashes) == self._frames(blocks)
+    @pytest.mark.parametrize("algo", ["sha256", "blake2b512"])
+    def test_other_algorithm_takes_the_write_loop(self, algo):
+        """An algorithm the batched C hasher does not serve goes row by
+        row through write(): same frames, and they verify."""
+        blocks = _rand(2, 128, 63)
+        one, many = io.BytesIO(), io.BytesIO()
+        w1 = bitrot.BitrotWriter(one, 128, algo=algo)
+        for row in blocks:
+            w1.write(row)
+        wn = bitrot.BitrotWriter(many, 128, algo=algo)
+        wn.write_frames(blocks)
+        assert many.getvalue() == one.getvalue()
+        assert wn.written == w1.written == len(one.getvalue())
+        r = bitrot.BitrotReader(io.BytesIO(many.getvalue()),
+                                till_offset=2 * 128, shard_size=128,
+                                algo=algo)
+        np.testing.assert_array_equal(r.read_blocks(0, 2, 128), blocks)
 
-    def test_bad_hash_shape_rejected(self):
-        blocks = _rand(2, 128, 62)
+    def test_refuses_rows_it_cannot_place(self):
+        """A row longer than the shard, a batch of several short rows
+        (they would land where the reader never seeks) and a batch that
+        is no (nblocks, L) matrix: refused before a byte is written."""
         buf = io.BytesIO()
         w = bitrot.BitrotWriter(buf, shard_size=128)
         with pytest.raises(errors.InvalidArgument):
-            w.write_frames(blocks, hashes=np.zeros((2, 16), np.uint8))
+            w.write_frames(_rand(1, 129, 66))
         with pytest.raises(errors.InvalidArgument):
-            w.write_frames(blocks, hashes=np.zeros((3, 32), np.uint8))
-        assert buf.getvalue() == b""  # nothing partial hit the file
-
-    def test_non_highway_algo_ignores_hashes(self):
-        blocks = _rand(2, 128, 63)
-        buf1, buf2 = io.BytesIO(), io.BytesIO()
-        w1 = bitrot.BitrotWriter(buf1, 128, algo="sha256")
-        w2 = bitrot.BitrotWriter(buf2, 128, algo="sha256")
-        w1.write_frames(blocks, hashes=np.zeros((2, 32), np.uint8))
-        w2.write_frames(blocks)
-        assert buf1.getvalue() == buf2.getvalue()
-
-    def test_precomputed_roundtrip_verifies(self):
-        """Frames written with fused hashes read back through the
-        verifying reader."""
-        blocks = _rand(3, 256, 64)
-        hashes = host.hh256_batch(blocks)
-        buf = io.BytesIO()
-        w = bitrot.BitrotWriter(buf, shard_size=256)
-        w.write_frames(blocks, hashes=hashes)
-        r = bitrot.BitrotReader(io.BytesIO(buf.getvalue()),
-                                till_offset=3 * 256, shard_size=256)
-        got = r.read_blocks(0, 3, 256)
-        np.testing.assert_array_equal(got, blocks)
+            w.write_frames(_rand(2, 100, 67))
+        with pytest.raises(errors.InvalidArgument):
+            w.write_frames(_rand(2, 128, 68).reshape(2, 2, 64))
+        assert buf.getvalue() == b"" and w.written == 0

@@ -33,9 +33,11 @@ PINNED_DD = "d1d1d1d1-1111-4111-8111-111111111111"
 
 
 def _shm_count() -> int:
+    """Ring segments of this process (other pytest processes of the
+    same run have planes of their own)."""
     try:
         return sum(1 for f in os.listdir("/dev/shm")
-                   if f.startswith("mtpu-"))
+                   if f.startswith(workers_mod.segment_prefix()))
     except OSError:
         return 0
 

@@ -333,13 +333,39 @@ class TestOverloadDrill:
     deadline, excess load sheds 503 SlowDown before the deadline,
     brownout engages then releases, and no thread leaks.
 
-    `serial`: the 3.0 s p99 ceiling is a wall-clock assertion; conftest
-    runs this drill last, in an isolated subprocess, so concurrent-load
-    noise from the rest of tier-1 cannot flake it."""
+    Tier-1 runs what does not depend on how fast this box is: every
+    answer is the object or a 503 SlowDown, the hedge engaged, sheds
+    were counted, the brownout engaged and released, the metrics are
+    there, no thread leaked.  The three clauses that read this box's
+    clock (how many of the 16 were served inside the budget, the worst
+    served GET, how late a shed was answered) are in the `slow` twin.
+
+    `serial`: conftest runs the drills last, each in a subprocess of its
+    own, so that the rest of tier-1 does not compete with them."""
 
     DEADLINE_S = 3.0
 
     def test_overload_drill(self, tmp_path, monkeypatch):
+        self._drill(tmp_path, monkeypatch)
+
+    @pytest.mark.slow
+    def test_overload_drill_latency(self, tmp_path, monkeypatch):
+        got = self._drill(tmp_path, monkeypatch)
+        # >= 12 on a quiet box; an admission-plane regression serves
+        # ~0-4 (one wave)
+        assert got["phase_a_served"] >= 10, got
+        # the budget plane bounds queue wait and time to first byte; a
+        # served request's payload streams budget-free by design, so
+        # the ceiling has a second of grace.  A deadline-plane
+        # regression (requests queueing unshed) blows far past 4 s.
+        assert got["served_max_s"] <= self.DEADLINE_S + 1.0, \
+            f"served GET p100 blew the deadline: {got}"
+        assert got["worst_shed_latency_s"] < 1.0, \
+            f"shed answered late (deadline 0.2s): {got}"
+
+    def _drill(self, tmp_path, monkeypatch) -> dict:
+        """The drill with its deterministic clauses; returns what it
+        measured."""
         monkeypatch.setenv("MINIO_API_REQUESTS_MAX", "4")
         monkeypatch.setenv("MINIO_API_REQUESTS_DEADLINE",
                            f"{self.DEADLINE_S:g}s")
@@ -352,7 +378,6 @@ class TestOverloadDrill:
         pools, chaos = _chaos_pools(tmp_path, n=8)
         srv = S3TestServer(str(tmp_path / "drill"), pools=pools,
                            start_services=True, scan_interval=3600)
-        record = {}
         try:
             assert srv.request("PUT", "/bkt").status == 200
             payload = os.urandom(1 << 20)  # > inline threshold: real shards
@@ -394,28 +419,12 @@ class TestOverloadDrill:
                 t.join(30)
             served = [d for d, s in zip(lat, statuses) if s == 200]
             shed_a = sum(1 for s in statuses if s == 503)
-            assert len(served) + shed_a == 16
-            # >= 12 on a quiet box; CPU steal on this shared container
-            # can push one extra client wave past the 3s budget into a
-            # (correct!) shed — same noisy-box reasoning as the p100
-            # grace below.  An admission-plane regression serves ~0-4
-            # (one wave) and still fails this hard.
-            assert len(served) >= 10, f"statuses={statuses}"
+            # every client was answered, with the object or a 503
+            assert len(served) + shed_a == 16, f"statuses={statuses}"
+            assert served, f"statuses={statuses}"
             served.sort()
             p99 = served[max(0, int(len(served) * 0.99) - 1)]
             worst = served[-1]
-            # noisy-box grace on the hard ceiling (same reasoning as
-            # the PR 6 MRF-window widening): the budget plane bounds
-            # queue wait and time-to-first-byte work, but a served
-            # request's payload STREAMING runs budget-free by design,
-            # so CPU steal on this shared 2-core container can push a
-            # legitimately-admitted request somewhat past the wire
-            # budget — no admission policy can pre-shed steal that
-            # lands mid-stream.  BENCH_r08.json records the measured
-            # p99/p100 honestly either way; a real deadline-plane
-            # regression (requests queueing unshed) blows far past 4s.
-            assert worst <= self.DEADLINE_S + 1.0, \
-                f"served GET p100 {worst:.2f}s blew the deadline"
             assert eobj.hedge_stats["hedged"] > hedges0, \
                 "hedge never engaged"
 
@@ -457,8 +466,6 @@ class TestOverloadDrill:
             assert sheds >= 4, f"expected sheds, got {shed_status}"
             worst_shed = max(d for d, s in zip(shed_lat, shed_status)
                              if s == 503)
-            assert worst_shed < 1.0, \
-                f"shed answered after {worst_shed:.2f}s (deadline 0.2s)"
 
             # ---- brownout engaged under pressure, releases after -----
             bo = srv.server.services.brownout
@@ -484,11 +491,6 @@ class TestOverloadDrill:
                 assert metric in text, f"{metric} missing from /metrics"
 
             record = {
-                "pass": True,
-                "deadline_s": self.DEADLINE_S,
-                "drives": 8, "slow_drives": 4,
-                "injected_latency_s": 0.5,
-                "oversubscription": "4x (16 clients / 4 slots)",
                 "phase_a_served": len(served),
                 "phase_a_shed": shed_a,
                 "served_p99_s": round(p99, 3),
@@ -505,25 +507,8 @@ class TestOverloadDrill:
                 c.restore()
             srv.close()
             leaked = _leaked(baseline_threads)
-            record["thread_leaks"] = sorted(leaked)
-            if record.get("pass"):
-                record["pass"] = not leaked
-            # acceptance: pass/fail line recorded in BENCH_r08.json
-            try:
-                bench_path = os.path.join(
-                    os.path.dirname(os.path.dirname(
-                        os.path.abspath(__file__))), "BENCH_r08.json")
-                doc = {}
-                if os.path.exists(bench_path):
-                    with open(bench_path, encoding="utf-8") as f:
-                        doc = json.load(f)
-                doc["overload_drill"] = record
-                with open(bench_path, "w", encoding="utf-8") as f:
-                    json.dump(doc, f, indent=2)
-                    f.write("\n")
-            except Exception:
-                pass
-            assert not leaked, f"leaked threads: {leaked}"
+        assert not leaked, f"leaked threads: {leaked}"
+        return record
 
 
 @pytest.mark.serial
@@ -539,8 +524,16 @@ class TestNoisyNeighborDrill:
     503s), and the hot tenant's bandwidth bucket pacing never touches
     the quiet tenant.
 
-    `serial`: wall-clock p99 assertion — conftest runs it at session
-    end in an isolated subprocess, like the overload drill."""
+    Tier-1 runs what does not depend on how fast this box is: the hot
+    tenant is shed by its queue bound and still served, its egress
+    keeps to its cap, the quiet tenant gets the object or a 503 and
+    never a full queue or a pacing debt, the metrics are there, no
+    thread leaked.  That the quiet tenant's requests all came back
+    inside the 3 s budget (no shed by deadline, p99) reads this box's
+    clock and is in the `slow` twin.
+
+    `serial`: conftest runs it at session end in a subprocess of its
+    own, like the overload drill."""
 
     BUDGET_S = 3.0
     DRILL_S = 4.0
@@ -548,6 +541,19 @@ class TestNoisyNeighborDrill:
     HOT_BW = 8 << 20          # 8 MiB/s egress cap for the hot tenant
 
     def test_noisy_neighbor_drill(self, tmp_path, monkeypatch):
+        self._drill(tmp_path, monkeypatch)
+
+    @pytest.mark.slow
+    def test_noisy_neighbor_drill_latency(self, tmp_path, monkeypatch):
+        got = self._drill(tmp_path, monkeypatch)
+        assert got["quiet_sheds"] == 0, f"quiet tenant shed: {got}"
+        assert got["quiet_shed_deadline"] == 0, got
+        assert got["quiet_p99_s"] <= self.BUDGET_S, \
+            f"quiet p99 blew the {self.BUDGET_S}s budget: {got}"
+
+    def _drill(self, tmp_path, monkeypatch) -> dict:
+        """The drill with its deterministic clauses; returns what it
+        measured."""
         monkeypatch.setenv("MINIO_TPU_QOS", "1")
         monkeypatch.setenv("MINIO_API_REQUESTS_MAX", "4")
         monkeypatch.setenv("MINIO_API_REQUESTS_DEADLINE",
@@ -562,7 +568,6 @@ class TestNoisyNeighborDrill:
         baseline_threads = _threads()
         os.environ["MINIO_TPU_FSYNC"] = "0"
         srv = S3TestServer(str(tmp_path / "nn"), n_drives=8)
-        record = {}
         try:
             assert srv.request("PUT", "/hotb").status == 200
             assert srv.request("PUT", "/quietb").status == 200
@@ -622,12 +627,9 @@ class TestNoisyNeighborDrill:
 
             # ---- the acceptance clauses ------------------------------
             quiet_sheds = sum(1 for s in quiet_status if s != 200)
-            assert quiet_sheds == 0, \
-                f"quiet tenant shed {quiet_sheds}: {quiet_status}"
+            assert set(quiet_status) <= {200, 503}, quiet_status
             lat_sorted = sorted(quiet_lat)
             p99 = lat_sorted[max(0, int(len(lat_sorted) * 0.99) - 1)]
-            assert p99 <= self.BUDGET_S, \
-                f"quiet p99 {p99:.2f}s blew the {self.BUDGET_S}s budget"
             assert hot_shed[0] > 0, \
                 "hot tenant was never shed despite 10x oversubscription"
             assert hot_served[0] > 0, \
@@ -641,7 +643,6 @@ class TestNoisyNeighborDrill:
             st = srv.server.qos.stats()["tenants"]
             assert st["bucket:hotb"]["shedQueueFull"] > 0
             assert st["bucket:quietb"]["shedQueueFull"] == 0
-            assert st["bucket:quietb"]["shedDeadline"] == 0
             # the quiet tenant runs WITHOUT a bucket: pacing debt from
             # the hot tenant structurally cannot leak onto it
             assert st["bucket:quietb"]["bandwidth"] == 0
@@ -658,41 +659,21 @@ class TestNoisyNeighborDrill:
                 assert metric in text, f"{metric} missing from /metrics"
 
             record = {
-                "pass": True,
-                "budget_s": self.BUDGET_S,
-                "slots": 4,
-                "hot_clients": self.HOT_CLIENTS,
-                "oversubscription": "10x (40 clients / 4 slots)",
-                "hot_bandwidth_cap_mbs": self.HOT_BW / 1e6,
                 "hot_served": hot_served[0],
                 "hot_shed": hot_shed[0],
                 "hot_egress_mbs": round(hot_rate / 1e6, 2),
                 "quiet_requests": len(quiet_lat),
                 "quiet_sheds": quiet_sheds,
+                "quiet_shed_deadline":
+                    st["bucket:quietb"]["shedDeadline"],
                 "quiet_p99_s": round(p99, 3),
                 "quiet_max_s": round(lat_sorted[-1], 3),
             }
         finally:
             srv.close()
             leaked = _leaked(baseline_threads)
-            record["thread_leaks"] = sorted(leaked)
-            if record.get("pass"):
-                record["pass"] = not leaked
-            try:
-                bench_path = os.path.join(
-                    os.path.dirname(os.path.dirname(
-                        os.path.abspath(__file__))), "BENCH_r15.json")
-                doc = {}
-                if os.path.exists(bench_path):
-                    with open(bench_path, encoding="utf-8") as f:
-                        doc = json.load(f)
-                doc["qos_noisy_neighbor_drill"] = record
-                with open(bench_path, "w", encoding="utf-8") as f:
-                    json.dump(doc, f, indent=2)
-                    f.write("\n")
-            except Exception:
-                pass
-            assert not leaked, f"leaked threads: {leaked}"
+        assert not leaked, f"leaked threads: {leaked}"
+        return record
 
 
 # ------------------------------------------------- deadline-gated storage

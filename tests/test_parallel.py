@@ -246,8 +246,8 @@ class TestMeshPipeline:
 def test_mesh_concurrent_dispatch_no_wedge():
     """ISSUE 11 regression: concurrent request threads launching
     collective mesh programs used to interleave per-device enqueues and
-    deadlock (observed as a hard wedge on a (2,2) virtual mesh —
-    BENCH_r13); MeshRSCodec._run now serializes launches.  Four
+    deadlock (observed as a hard wedge on a (2,2) virtual mesh);
+    MeshRSCodec._run now serializes launches.  Four
     threads x four encodes must complete, byte-correct."""
     import threading
 

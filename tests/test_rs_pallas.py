@@ -112,52 +112,3 @@ def test_odd_shard_length_books_pad_bytes_and_no_seconds():
     after = stagestats.snapshot()["pad"]
     assert after["seconds"] == before["seconds"]
     assert after["bytes"] - before["bytes"] == 2 * (k * 90112 + m * s)
-
-
-def test_flat_encode_matches_numpy():
-    k, m = 8, 4
-    shards = _rand(1, k, S, seed=7)[0]  # (k, S)
-    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
-    words = np.ascontiguousarray(shards).view(np.int32).reshape(k, S // 4)
-    got = np.asarray(codec.encode_flat(words)).view(np.uint8).reshape(m, S)
-    np.testing.assert_array_equal(got, gf256.encode_np(shards, m))
-
-
-def test_flat_seed_zero_is_identity_and_seeded_differs():
-    import jax.numpy as jnp
-
-    k, m = 4, 2
-    shards = _rand(1, k, S, seed=9)[0]
-    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
-    words = np.ascontiguousarray(shards).view(np.int32).reshape(k, S // 4)
-    base = np.asarray(codec.encode_flat(words))
-    seeded = np.asarray(
-        rs_pallas._flat_coding_call(
-            codec._enc, jnp.asarray(words), jnp.asarray([0], jnp.int32),
-            interpret=codec._interpret,
-        )
-    )
-    np.testing.assert_array_equal(base, seeded)
-    # non-zero seed == encode of (words ^ seed)
-    xored = np.asarray(
-        rs_pallas._flat_coding_call(
-            codec._enc, jnp.asarray(words), jnp.asarray([0x5A5A5A5A], jnp.int32),
-            interpret=codec._interpret,
-        )
-    )
-    expect = np.asarray(codec.encode_flat(words ^ np.int32(0x5A5A5A5A)))
-    np.testing.assert_array_equal(xored, expect)
-
-
-def test_flat_reconstruct():
-    k, m = 8, 4
-    data = _rand(1, k, S, seed=11)
-    codec = rs_pallas.PallasRSCodec(k, m, interpret=True)
-    full = np.asarray(codec.encode_blocks(data))[0]  # (k+m, S)
-    kill = (1, 5, 9)
-    avail = tuple(i for i in range(k + m) if i not in kill)
-    src = np.ascontiguousarray(full[list(avail[:k])]).view(np.int32).reshape(k, S // 4)
-    reb = np.asarray(codec.reconstruct_flat(src, avail[:k], kill))
-    reb_bytes = reb.view(np.uint8).reshape(len(kill), S)
-    for j, idx in enumerate(kill):
-        np.testing.assert_array_equal(reb_bytes[j], full[idx], err_msg=f"shard {idx}")

@@ -3,6 +3,7 @@
 ObjectLayer when cache drives are configured)."""
 
 import json
+import time
 
 import pytest
 
@@ -24,6 +25,18 @@ def cached_srv(tmp_path):
     s.close()
 
 
+def _wait_filled(cache, entries=1):
+    """A miss tees the body into the cache and commits the entry once
+    the last chunk has gone out, so the client can hold the whole body
+    before the server gets to the commit: wait for it before counting
+    on a hit."""
+    deadline = time.monotonic() + 10
+    while cache.stats()["entries"] < entries \
+            and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert cache.stats()["entries"] >= entries, "the miss never filled"
+
+
 class TestServerModeCache:
     def test_erasure_get_hits_cache(self, cached_srv):
         srv, cache, pools = cached_srv
@@ -32,6 +45,7 @@ class TestServerModeCache:
         assert srv.request("PUT", "/cbk/obj", data=data).status == 200
         r1 = srv.request("GET", "/cbk/obj")
         assert r1.status == 200 and r1.body == data
+        _wait_filled(cache)
         m0 = cache.misses
         h0 = cache.hits
         r2 = srv.request("GET", "/cbk/obj")
@@ -85,6 +99,7 @@ class TestServerModeCache:
         srv.request("PUT", "/cbk6")
         srv.request("PUT", "/cbk6/x", data=b"stat me")
         srv.request("GET", "/cbk6/x")
+        _wait_filled(cache)
         srv.request("GET", "/cbk6/x")
         r = srv.request("GET", "/minio/admin/v3/info")
         assert r.status == 200

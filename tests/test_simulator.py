@@ -101,7 +101,7 @@ class TestScheduleContract:
         """ISSUE 16: the multi-region family — four named scenarios,
         each owning its bucket (convergence checks must not bleed
         across scenarios), every one graded by server-side SLO
-        classes, chaos limited to the hooks bench.py registers."""
+        classes, chaos limited to the hooks a harness registers."""
         scs = georep_scenarios()
         assert [s.name for s in scs] == [
             "replication_burst", "peer_kill_mid_push", "worker_kill",
@@ -111,8 +111,8 @@ class TestScheduleContract:
         assert all(s.slo.get("classes") for s in scs)
         assert {s.chaos for s in scs if s.chaos} == \
             {"peer_kill", "worker_kill"}
-        # seeds must not collide with the builtin set — SIM_r01.json
-        # keys scenario digests by name but seeds are the identity
+        # seeds must not collide with the builtin set: a schedule's
+        # digest follows its seed
         seeds = {s.seed for s in scs} | \
             {s.seed for s in builtin_scenarios()}
         assert len(seeds) == len(scs) + len(builtin_scenarios())
@@ -171,8 +171,8 @@ class TestScheduleContract:
             == ["tenant_mix_flip"]
         assert [s.name for s in scs if s.chaos] \
             == ["brownout_noisy_stacked"]
-        # seeds are the digest identity in BENCH_r19.json: no
-        # collisions inside the family or with the other sets
+        # a schedule's digest follows its seed: no collisions inside
+        # the family or with the other sets
         seeds = {s.seed for s in scs} \
             | {s.seed for s in builtin_scenarios()} \
             | {s.seed for s in georep_scenarios()}

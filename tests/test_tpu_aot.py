@@ -31,10 +31,12 @@ PROGRAMS = [
     "pallas_encode_words_8+4_B32",
     "pallas_reconstruct_8+4_r1_B32",
     "pallas_reconstruct_8+4_r3_B32",
-    "pallas_flat_8+4",
+    "pallas_reconstruct_8+4_r2_B32",
     "gf_bitmatmul_8+4_B32",
-    "fused_encode_hash_8+4_B32",
-    "md5_scan_32MiB",
+    # EC 2+2: 512 KiB shards; a degraded GET rebuilds one row, a heal two
+    "pallas_encode_2+2_B32",
+    "pallas_reconstruct_2+2_r1_B32",
+    "pallas_reconstruct_2+2_r2_B32",
     # EC 12+4: K no power of two in the kernel's unpack, shards of
     # 87,382 bytes widened to whole tiles inside the program
     "pallas_encode_12+4_B32",
@@ -50,7 +52,7 @@ def _compile_all() -> dict:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from minio_tpu.ops import hh_device, rs_pallas, rs_tpu
+    from minio_tpu.ops import rs_pallas, rs_tpu
 
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
@@ -77,17 +79,16 @@ def _compile_all() -> dict:
             (rs_pallas._coding_call_bytes, (mat(1, 8), shards(8))),
         "pallas_reconstruct_8+4_r3_B32":
             (rs_pallas._coding_call_bytes, (mat(3, 8), shards(8))),
-        "pallas_flat_8+4":
-            (rs_pallas._flat_coding_call,
-             (mat(4, 8), spec((8, B * BLOCK // 8 // 4), jnp.int32))),
+        "pallas_reconstruct_8+4_r2_B32":
+            (rs_pallas._coding_call_bytes, (mat(2, 8), shards(8))),
         "gf_bitmatmul_8+4_B32":
             (rs_tpu.gf_bitmatmul, (mat(4, 8), shards(8))),
-        "fused_encode_hash_8+4_B32":
-            (hh_device.fused_encode_hash(8, 4), (shards(8),)),
-        "md5_scan_32MiB":
-            (hh_device._md5_scan_fn(),
-             (spec((4,), jnp.uint32),
-              spec((B * BLOCK // 64, 16), jnp.uint32))),
+        "pallas_encode_2+2_B32":
+            (rs_pallas._coding_call_bytes, (mat(2, 2), shards(2))),
+        "pallas_reconstruct_2+2_r1_B32":
+            (rs_pallas._coding_call_bytes, (mat(1, 2), shards(2))),
+        "pallas_reconstruct_2+2_r2_B32":
+            (rs_pallas._coding_call_bytes, (mat(2, 2), shards(2))),
         "pallas_encode_12+4_B32":
             (rs_pallas._coding_call_bytes, (mat(4, 12), shards(12))),
         "pallas_reconstruct_12+4_r1_B32":
