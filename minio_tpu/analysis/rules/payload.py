@@ -40,9 +40,10 @@ WHOLE_PAYLOAD = frozenset({
 #: quick metadata ops: bounded work that MUST stay under the deadline
 #: plane (`_run`) so a hung drive sheds instead of hanging the request
 FAST_METADATA = frozenset({
-    "get_object_info", "new_multipart_upload", "abort_multipart_upload",
-    "delete_object", "delete_objects", "list_object_parts",
-    "bucket_exists", "list_buckets", "make_bucket", "delete_bucket",
+    "get_object_info", "open_object", "new_multipart_upload",
+    "abort_multipart_upload", "delete_object", "delete_objects",
+    "list_object_parts", "bucket_exists", "list_buckets", "make_bucket",
+    "delete_bucket",
 })
 
 

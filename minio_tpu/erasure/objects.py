@@ -121,6 +121,25 @@ class ObjectInfo:
         )
 
 
+# `read(offset=0, length=-1)` of an opened object (open_object)
+ObjectReadFn = Callable[..., Iterator[bytes]]
+
+
+def open_by_info(layer, bucket: str, obj: str, version_id: str = ""
+                 ) -> tuple[ObjectInfo, ObjectReadFn]:
+    """`open_object` for a layer that cannot read info and bytes in one
+    go (a gateway: HEAD, then GET against the remote; the disk cache):
+    the info now, the layer's `get_object` once `read`'s iterator is
+    first advanced."""
+    oi = layer.get_object_info(bucket, obj, version_id)
+
+    def read(offset: int = 0, length: int = -1) -> Iterator[bytes]:
+        _, stream = layer.get_object(bucket, obj, offset, length, version_id)
+        yield from stream
+
+    return oi, read
+
+
 @dataclass
 class PutObjectOptions:
     user_metadata: dict = field(default_factory=dict)
@@ -1003,16 +1022,44 @@ class ErasureObjects:
             return False
 
     # ------------------------------------------------------------------- GET
-    def get_object_info(self, bucket: str, obj: str, version_id: str = ""
-                        ) -> ObjectInfo:
+    def _open(self, bucket: str, obj: str, version_id: str,
+              read_data: bool
+              ) -> tuple[ObjectInfo, FileInfo, list[FileInfo | None]]:
+        """The one quorum read of a read request: `xl.meta` of every
+        drive under the namespace read lock, one election.  Headers and
+        bytes of a GET both come from what this returns."""
         with self.ns.read(f"{bucket}/{obj}"):
-            fi, _, _ = self._quorum_info(bucket, obj, version_id, hedge=True)
+            fi, fis, _ = self._quorum_info(bucket, obj, version_id,
+                                           read_data=read_data, hedge=True)
         if fi.deleted:
             if not version_id:
                 raise errors.ObjectNotFound(f"{bucket}/{obj}")
             oi = ObjectInfo.from_file_info(fi, bucket, obj, True)
             raise MethodNotAllowedDeleteMarker(oi)
-        return ObjectInfo.from_file_info(fi, bucket, obj, bool(version_id))
+        oi = ObjectInfo.from_file_info(fi, bucket, obj, bool(version_id))
+        return oi, fi, fis
+
+    def open_object(self, bucket: str, obj: str, version_id: str = ""
+                    ) -> tuple[ObjectInfo, ObjectReadFn]:
+        """-> (info, read): the elected version's ObjectInfo, and
+        `read(offset=0, length=-1)`, which streams that range of that
+        same election (its `fi` and `data_dir`) however often it is
+        called.  `read` returns at once; no shard file is opened before
+        its iterator is first advanced, so a caller that answers from
+        the info alone (304, 412, a bad Range, a tier stub) drops the
+        handle and touches no drive again (the reference's
+        GetObjectNInfo: metadata read once, reader and info together)."""
+        opened = self._open(bucket, obj, version_id, read_data=True)
+
+        def read(offset: int = 0, length: int = -1) -> Iterator[bytes]:
+            return self.get_object(bucket, obj, offset, length, version_id,
+                                   opened=opened)[1]
+
+        return opened[0], read
+
+    def get_object_info(self, bucket: str, obj: str, version_id: str = ""
+                        ) -> ObjectInfo:
+        return self._open(bucket, obj, version_id, read_data=False)[0]
 
     def object_health(self, bucket: str, obj: str, version_id: str = ""
                       ) -> tuple[FileInfo, int]:
@@ -1028,14 +1075,19 @@ class ErasureObjects:
         return fi, missing
 
     def get_object(self, bucket: str, obj: str, offset: int = 0,
-                   length: int = -1, version_id: str = ""
+                   length: int = -1, version_id: str = "", *,
+                   opened: tuple | None = None
                    ) -> tuple[ObjectInfo, Iterator[bytes]]:
-        with self.ns.read(f"{bucket}/{obj}"):
-            fi, fis, _ = self._quorum_info(bucket, obj, version_id,
-                                           read_data=True, hedge=True)
-        if fi.deleted:
-            raise errors.ObjectNotFound(f"{bucket}/{obj}")
-        oi = ObjectInfo.from_file_info(fi, bucket, obj, bool(version_id))
+        """-> (info, stream of `[offset, offset + length)`).  The one place
+        where a range of an election becomes a stream: it opens the object
+        itself, or streams what `open_object` elected (`opened`, which
+        that handle's `read` passes)."""
+        if opened is None:
+            try:
+                opened = self._open(bucket, obj, version_id, read_data=True)
+            except MethodNotAllowedDeleteMarker:
+                raise errors.ObjectNotFound(f"{bucket}/{obj}") from None
+        oi, fi, fis = opened
         if length < 0:
             length = fi.size - offset
         if offset < 0 or offset + length > fi.size:

@@ -227,6 +227,9 @@ class ErasureSets:
         return self.get_hashed_set(obj).get_object(bucket, obj, offset, length,
                                                    version_id)
 
+    def open_object(self, bucket, obj, version_id=""):
+        return self.get_hashed_set(obj).open_object(bucket, obj, version_id)
+
     def get_object_info(self, bucket, obj, version_id="") -> ObjectInfo:
         return self.get_hashed_set(obj).get_object_info(bucket, obj, version_id)
 
@@ -655,11 +658,13 @@ class ErasureServerPools:
             pool = self._pool_for_new(obj, max(size, 0), bucket=bucket)
         return pool.put_object(bucket, obj, reader, size, opts)
 
-    def get_object(self, bucket, obj, offset=0, length=-1, version_id=""):
+    def _read_pools_first(self, bucket, obj, call):
+        """`call(pool)` of the first pool, in read order, that holds the
+        object (the routing of every read entry point)."""
         last: Exception = errors.ObjectNotFound(f"{bucket}/{obj}")
         for p in self._read_pools():
             try:
-                return p.get_object(bucket, obj, offset, length, version_id)
+                return call(p)
             except (errors.ObjectNotFound, errors.VersionNotFound) as ex:
                 last = ex
         # error path only: a miss in a bucket that does not exist is
@@ -668,16 +673,17 @@ class ErasureServerPools:
             raise errors.BucketNotFound(bucket)
         raise last
 
+    def get_object(self, bucket, obj, offset=0, length=-1, version_id=""):
+        return self._read_pools_first(bucket, obj, lambda p: p.get_object(
+            bucket, obj, offset, length, version_id))
+
+    def open_object(self, bucket, obj, version_id=""):
+        return self._read_pools_first(
+            bucket, obj, lambda p: p.open_object(bucket, obj, version_id))
+
     def get_object_info(self, bucket, obj, version_id="") -> ObjectInfo:
-        last: Exception = errors.ObjectNotFound(f"{bucket}/{obj}")
-        for p in self._read_pools():
-            try:
-                return p.get_object_info(bucket, obj, version_id)
-            except (errors.ObjectNotFound, errors.VersionNotFound) as ex:
-                last = ex
-        if not self.bucket_exists(bucket):
-            raise errors.BucketNotFound(bucket)
-        raise last
+        return self._read_pools_first(
+            bucket, obj, lambda p: p.get_object_info(bucket, obj, version_id))
 
     def delete_objects(self, bucket, dels: list) -> list:
         if not self.bucket_exists(bucket):

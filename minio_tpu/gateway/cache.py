@@ -107,6 +107,14 @@ class CacheLayer:
                     continue
 
     # -- read path -----------------------------------------------------------
+    def open_object(self, bucket: str, obj: str, version_id: str = ""):
+        # through get_object below, not past it to the inner layer's own
+        # open (which __getattr__ would hand out): a served GET reads
+        # the cache
+        from minio_tpu.erasure.objects import open_by_info
+
+        return open_by_info(self, bucket, obj, version_id)
+
     def get_object(self, bucket: str, obj: str, offset: int = 0,
                    length: int = -1, version_id: str = ""):
         if version_id:

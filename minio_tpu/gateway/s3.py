@@ -16,7 +16,9 @@ import xml.etree.ElementTree as ET
 from typing import Iterator
 
 from minio_tpu.erasure.listing import ListEntry
-from minio_tpu.erasure.objects import ObjectInfo, PutObjectOptions
+from minio_tpu.erasure.objects import (
+    ObjectInfo, PutObjectOptions, open_by_info,
+)
 from minio_tpu.erasure.multipart import PartInfo
 from minio_tpu.storage import errors
 from minio_tpu.storage.api import VolInfo
@@ -232,6 +234,9 @@ class S3Gateway:
         except S3ClientError as e:
             raise _map_err(e, bucket, obj)
         return self._oi_from_headers(bucket, obj, rh)
+
+    def open_object(self, bucket: str, obj: str, version_id: str = ""):
+        return open_by_info(self, bucket, obj, version_id)
 
     @staticmethod
     def _oi_from_headers(bucket: str, obj: str, rh: dict) -> ObjectInfo:

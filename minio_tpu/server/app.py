@@ -2491,11 +2491,13 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                                                    oi.version_id)
         return repl.PENDING
 
-    async def _obj_stream(self, bucket: str, key: str, vid: str,
-                          offset: int, length: int, oi):
-        """Stored-bytes stream for GET/Select: local shards normally, the
-        warm tier for transitioned stubs (reference getTransitionedObject
-        read-through, cmd/bucket-lifecycle.go)."""
+    async def _obj_stream(self, oi, read, offset: int, length: int):
+        """Stored-bytes stream for GET/Select, of the object that
+        `api.open_object` gave as `(oi, read)`: local shards normally
+        (`read` returns at once and opens its shard files on the
+        executor, when the pump first advances it), the warm tier for
+        transitioned stubs, whose `read` is never called (reference
+        getTransitionedObject read-through, cmd/bucket-lifecycle.go)."""
         svcs = self.services
         if svcs is not None and getattr(svcs, "tier", None) is not None:
             from minio_tpu.services.tier import TierManager
@@ -2505,9 +2507,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                 return await self._run(
                     svcs.tier.read, oi.metadata, offset,
                     length if length >= 0 else -1)
-        _, stream = await self._run(
-            self.api.get_object, bucket, key, offset, length, vid)
-        return stream
+        return read(offset, length)
 
     @staticmethod
     def _check_copy_source_conditions(request: web.Request, soi) -> None:
@@ -2912,17 +2912,8 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                     return await self._serve_hot(request, bucket, key,
                                                  vid, oi, payload)
                 # ineligible object (SSE/compressed/tiered/oversized):
-                # classic path, reusing the oi the leader already read
-                return await self._get_uncached(request, bucket, key,
-                                                vid, oi)
-        try:
-            oi = await self._run(self.api.get_object_info, bucket, key, vid)
-        except (st.ObjectNotFound, st.FileNotFound) as e:
-            resp = await self._replication_proxy(request, bucket, key, vid)
-            if resp is not None:
-                return resp
-            raise e
-        return await self._get_uncached(request, bucket, key, vid, oi)
+                # the classic path below opens it
+        return await self._get_uncached(request, bucket, key, vid)
 
     async def _serve_hot(self, request: web.Request, bucket: str,
                          key: str, vid: str, oi, payload,
@@ -2981,9 +2972,23 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         return resp
 
     async def _get_uncached(self, request: web.Request, bucket: str,
-                            key: str, vid: str, oi) -> web.StreamResponse:
+                            key: str, vid: str) -> web.StreamResponse:
+        """The GET that reads drives.  It opens its object once: one
+        executor hop, one quorum read of `xl.meta` and one election give
+        the `oi` that the preconditions, the Range, the SSE and
+        compression branches and the headers need, and the `read` that
+        streams the bytes of that same election.  A 304, a 412 or a bad
+        Range leaves before `read` is called: no shard file is opened."""
         from minio_tpu.crypto import sse as sse_mod
 
+        try:
+            oi, read = await self._run(self.api.open_object, bucket, key,
+                                       vid)
+        except (st.ObjectNotFound, st.FileNotFound) as e:
+            resp = await self._replication_proxy(request, bucket, key, vid)
+            if resp is not None:
+                return resp
+            raise e
         if vid == "null":
             oi.version_id = "null"
         self.check_preconditions(request, oi)
@@ -3021,8 +3026,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                 offset, length, size)
             nonce_prefix = base64.b64decode(
                 oi.metadata.get(sse_mod.META_NONCE, ""))
-            ct_stream = await self._obj_stream(bucket, key, vid,
-                                               ct_off, ct_len, oi)
+            ct_stream = await self._obj_stream(oi, read, ct_off, ct_len)
             stream = sse_mod.decrypt_chunks(
                 iter(ct_stream), obj_key, nonce_prefix,
                 f"{bucket}/{key}".encode(), first_seq, skip, length)
@@ -3031,12 +3035,11 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
             # stored frames are opaque: decompress from the start and
             # skip to the requested range (reference non-indexed
             # compressed reads)
-            raw = await self._obj_stream(bucket, key, vid, 0, -1, oi)
+            raw = await self._obj_stream(oi, read, 0, -1)
             stream = compress_mod.decompress_range(iter(raw), offset, length)
             closer = raw
         else:
-            stream = await self._obj_stream(bucket, key, vid,
-                                            offset, length, oi)
+            stream = await self._obj_stream(oi, read, offset, length)
             closer = stream
         from minio_tpu.events.event import EventName
 
@@ -3222,7 +3225,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         except SQLError as e:
             raise S3Error("InvalidArgument", str(e))
         vid = request.rel_url.query.get("versionId", "")
-        oi = await self._run(self.api.get_object_info, bucket, key, vid)
+        oi, read = await self._run(self.api.open_object, bucket, key, vid)
 
         # plaintext source stream (decompress / decrypt like GET)
         if oi.metadata.get(sse_mod.META_ALGO):
@@ -3231,19 +3234,19 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
             nonce_prefix = base64.b64decode(
                 oi.metadata.get(sse_mod.META_NONCE, ""))
             plain = sse_mod.plain_size_of(oi.size)
-            raw = await self._obj_stream(bucket, key, vid, 0, -1, oi)
+            raw = await self._obj_stream(oi, read, 0, -1)
             chunks = sse_mod.decrypt_chunks(
                 iter(raw), obj_key, nonce_prefix,
                 f"{bucket}/{key}".encode(), 0, 0, plain)
             src_size = plain
         elif oi.metadata.get(
                 compress_mod.META_COMPRESSION) == compress_mod.SCHEME:
-            raw = await self._obj_stream(bucket, key, vid, 0, -1, oi)
+            raw = await self._obj_stream(oi, read, 0, -1)
             chunks = compress_mod.decompress_stream(iter(raw))
             src_size = int(oi.metadata.get(
                 compress_mod.META_ACTUAL_SIZE, oi.size))
         else:
-            raw = await self._obj_stream(bucket, key, vid, 0, -1, oi)
+            raw = await self._obj_stream(oi, read, 0, -1)
             chunks = iter(raw)
             src_size = oi.size
 
