@@ -46,6 +46,18 @@ BACKENDS = ("auto", "host", "tpu", "mesh")
 
 # Batch this many erasure blocks per device dispatch on the hot path.
 DEVICE_BATCH_BLOCKS = 32
+# The batch sizes a dispatch of the single-chip codec can have, ascending
+# and ending in DEVICE_BATCH_BLOCKS.  Boot compiles and self-tests the
+# device programs at these and at no other (selftest.device_self_test),
+# so a group of g blocks is carried by the smallest of them that holds
+# it (`carrier_blocks`): no object size compiles inside a request.  Each
+# further size is (1 + m) more programs a geometry at boot (cold 4-10 s
+# each, 65 s at 2+2).  16 is there because the chip said so (PERF.md
+# section 6, PR 32): 20 concurrent GETs of 10 MiB objects, each a
+# 10-block dispatch carried at 32, read 5-12% under their own exact
+# program; a carrier's transfer, device time and read-back are serial
+# among streams that run in step.
+DEVICE_BATCH_SIZES = (16, DEVICE_BATCH_BLOCKS)
 # Use the device only when at least this many bytes are in flight.
 DEVICE_MIN_BYTES = 8 << 20
 # Encoded batches kept in flight on the device pipeline (double
@@ -103,6 +115,8 @@ def _arena_acquire(nbytes: int) -> np.ndarray:
 def _arena_release(arr: np.ndarray) -> None:
     # lint: allow(shared-state): per-process arena pool by design — see _arena_acquire
     global _arena_pool_bytes
+    # the head of a carrier gives back the whole of it
+    arr = _carrier_of(arr, arr)
     if arr.ndim != 1:
         # the pool hands out flat arrays, whatever shape a user gave
         # its arena (contiguous: a view)
@@ -121,6 +135,83 @@ def _arena_release(arr: np.ndarray) -> None:
         bucket.append(arr)
         _arena_pool[arr.nbytes] = bucket
         _arena_pool_bytes += arr.nbytes
+
+
+def carrier_blocks(g: int) -> int:
+    """The compiled batch size that carries a single-chip dispatch of g
+    blocks; g itself beyond the largest (the request batcher's merged
+    batches, which it sizes itself)."""
+    return next((b for b in DEVICE_BATCH_SIZES if b >= g), g)
+
+
+def _dispatch_blocks(dev, g: int) -> int:
+    """Blocks of the array that a dispatch of g blocks hands the codec
+    `dev` (None: the host's): more than g where the single-chip codec
+    carries them at a compiled batch size."""
+    return carrier_blocks(g) if _backend_name(dev) == "device" else g
+
+
+class _Head(np.ndarray):
+    """The first g blocks of a (B, K, S) carrier, B one of
+    DEVICE_BATCH_SIZES: a batch that a device dispatch takes as it
+    lies, whole carrier and all, instead of copying it into one.  What
+    the carrier holds beyond block g is whatever its buffer last held;
+    the rows made of it are dropped where the output arrives
+    (`_on_device`).  Views of a head are plain batches again."""
+
+    carrier: np.ndarray | None = None
+
+
+def _head(carrier: np.ndarray, g: int) -> np.ndarray:
+    if g == carrier.shape[0]:
+        return carrier
+    head = carrier[:g].view(_Head)
+    head.carrier = carrier
+    return head
+
+
+def _carrier_of(batch: np.ndarray, default=None):
+    carrier = getattr(batch, "carrier", None)
+    return default if carrier is None else carrier
+
+
+def _on_device(dev, batch: np.ndarray, nrows: int, code):
+    """Start one dispatch of the device codec `dev` on the g blocks of
+    `batch` (g, K, S); returns resolve() -> their (g, nrows, S) rows on
+    the host.  `code(shards)` is the codec's entry, `encode` or
+    `reconstruct` with its matrix bound.
+
+    The single-chip codec's program is compiled per batch size, so a
+    batch of another size than DEVICE_BATCH_SIZES holds goes inside the
+    next larger one: in place where the batch is the head of its carrier
+    already (the staged reads' arenas, a PUT's slots), else copied into
+    a pooled one.  The codec books its stages for the g blocks
+    (`blocks=`), `batch_fill` takes the input bytes carried beyond them,
+    and of the output only the g blocks' rows are kept: a view, nothing
+    of the rest is copied anywhere."""
+    g, k, s = batch.shape
+    size = _dispatch_blocks(dev, g)
+    own = None
+    if size == g:
+        out = code(batch)
+    else:
+        carrier = _carrier_of(batch)
+        if carrier is None or carrier.shape[0] != size:
+            with stagestats.timed("assemble", batch.nbytes):
+                own = carrier = _arena_acquire(size * k * s).reshape(
+                    size, k, s)
+                carrier[:g] = batch
+        stagestats.add("batch_fill", 0.0, (size - g) * k * s)
+        out = code(carrier, blocks=g)
+
+    def resolve() -> np.ndarray:
+        with stagestats.timed("fetch", g * nrows * s):
+            rows = np.asarray(out)[:g]
+        if own is not None:
+            _arena_release(own)
+        return rows
+
+    return resolve
 
 
 def _io_pool() -> cf.ThreadPoolExecutor:
@@ -477,13 +568,10 @@ class Erasure:
         actual dispatch; the batcher feeds MERGED cross-request batches
         through here, so `_device` prices the fused size (small
         per-request dispatches coalesce their way onto the device)."""
-        b, k, s = batch.shape
-        dev = self._device(batch.nbytes, s)
+        dev = self._device(batch.nbytes, batch.shape[2])
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
-            out = dev.encode(batch)
-            with stagestats.timed("fetch", b * self.m * s):
-                return np.asarray(out)
+            return _on_device(dev, batch, self.m, dev.encode)()
         with stagestats.timed("host_codec", batch.nbytes):
             return self._host.encode(batch)
 
@@ -531,11 +619,10 @@ class Erasure:
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
             t0 = time.perf_counter()
-            out = dev.encode(batch)
+            resolve = _on_device(dev, batch, self.m, dev.encode)
 
             def resolve_dev():
-                with stagestats.timed("fetch", b * self.m * s):
-                    arr = np.asarray(out)
+                arr = resolve()
                 stagestats.add("encode", time.perf_counter() - t0,
                                batch.nbytes)
                 return arr
@@ -573,13 +660,13 @@ class Erasure:
 
     def _reconstruct_shards_raw(self, batch: np.ndarray, available: tuple,
                                 wanted: tuple) -> np.ndarray:
-        b, k, s = batch.shape
-        dev = self._device(batch.nbytes, s)
+        dev = self._device(batch.nbytes, batch.shape[2])
         _count(_backend_name(dev), batch.nbytes)
         if dev is not None:
-            out = dev.reconstruct(batch, available, wanted)
-            with stagestats.timed("fetch", b * len(wanted) * s):
-                return np.asarray(out)
+            return _on_device(
+                dev, batch, len(wanted),
+                lambda shards, **kw: dev.reconstruct(
+                    shards, available, wanted, **kw))()
         with stagestats.timed("host_codec", batch.nbytes):
             return self._host.reconstruct(batch, available, wanted)
 
@@ -734,6 +821,11 @@ class Erasure:
                 slot_bytes = min(slot_bytes, max(total_size, 1))
                 nslots = max(1, min(
                     nslots, -(-max(total_size, 1) // slot_bytes)))
+                if aligned and slot_bytes >= bs:
+                    # where the device carries the slot's full blocks at
+                    # a compiled batch size, the slot is that carrier
+                    slot_bytes = max(slot_bytes, bs * self._carrier_blocks(
+                        slot_bytes // bs, self.shard_size))
             slot_bufs = [_arena_acquire(slot_bytes) for _ in range(nslots)]
             slot_refs = [0] * nslots
             free_slots = list(range(nslots))
@@ -893,11 +985,17 @@ class Erasure:
                 total += got
                 nfull = got // bs
                 first = True
+                # the blocks the dispatch hands over: nfull, or those of
+                # the carrier the full blocks are the head of
+                size = self._carrier_blocks(nfull, self.shard_size) \
+                    if nfull else 0
                 if nfull and aligned:
+                    if size * bs > data_arr.size:
+                        size = nfull  # no arena: the dispatch copies
                     flush_batch(
                         slot,
-                        data_arr[: nfull * bs].reshape(
-                            nfull, self.k, self.shard_size),
+                        _head(data_arr[: size * bs].reshape(
+                            size, self.k, self.shard_size), nfull),
                         bs, hfut)
                     first = False
                 elif nfull:
@@ -907,12 +1005,14 @@ class Erasure:
                     # copies and nfull python round trips)
                     per = -(-bs // self.k)
                     with stagestats.timed("pad", nfull * bs):
-                        batch = np.zeros((nfull, self.k * per),
+                        batch = np.zeros((size, self.k * per),
                                          dtype=np.uint8)
-                        batch[:, :bs] = data_arr[: nfull * bs].reshape(
+                        batch[:nfull, :bs] = data_arr[: nfull * bs].reshape(
                             nfull, bs)
-                    flush_batch(slot, batch.reshape(nfull, self.k, per),
-                                bs, hfut)
+                    flush_batch(
+                        slot,
+                        _head(batch.reshape(size, self.k, per), nfull),
+                        bs, hfut)
                     first = False
                 tail = got - nfull * bs
                 if tail:
@@ -950,6 +1050,21 @@ class Erasure:
         return total, dead
 
     # -- streaming decode (cmd/erasure-decode.go:206) -----------------------
+    def _carrier_blocks(self, nblocks: int, shard_len: int) -> int:
+        """_dispatch_blocks of the codec that a dispatch of `nblocks`
+        blocks of this shard length will go to."""
+        return _dispatch_blocks(
+            self._device(nblocks * self.k * shard_len, shard_len), nblocks)
+
+    def _staging(self, nblocks: int, shard_len: int) -> np.ndarray:
+        """A pooled (nblocks, k, shard_len) batch for one codec
+        dispatch: the head of its carrier where the dispatch will have
+        one, so that what is read into it is copied nowhere.
+        _arena_release takes it back."""
+        size = self._carrier_blocks(nblocks, shard_len)
+        return _head(_arena_acquire(size * self.k * shard_len).reshape(
+            size, self.k, shard_len), nblocks)
+
     def _read_group(self, readers: Sequence, broken: set[int],
                     shard_off: int, read_len: int, nblocks: int,
                     shard_len: int, pool,
@@ -992,8 +1107,7 @@ class Erasure:
             # one-off size class like a small PUT's slot: the pool's LRU
             # evicts those before the full groups' class, and the copy
             # that a tail would take instead asks the pool for the same
-            arena = _arena_acquire(nblocks * self.k * shard_len).reshape(
-                nblocks, self.k, shard_len)
+            arena = self._staging(nblocks, shard_len)
             # columns ascending, as every codec's matrices have been
             # keyed; a spare takes the failed read's column
             active.sort()
@@ -1086,8 +1200,7 @@ class Erasure:
                     # the group turned degraded after its reads began (a
                     # frame failed its hash, a drive timed out): what
                     # was read into frame buffers is copied to an arena
-                    arena = _arena_acquire(self.k * shard_bytes).reshape(
-                        nblocks, self.k, shard_len)
+                    arena = self._staging(nblocks, shard_len)
                     got = {i: got[i] for i in sorted(got)}
                     for j, rows in enumerate(got.values()):
                         arena[:, j, :] = rows

@@ -469,7 +469,9 @@ def _dispatch_raw(e, src: np.ndarray, helpers: tuple[int, ...],
     dev = e._device(src.nbytes, blen)
     coding_mod._count(coding_mod._backend_name(dev), src.nbytes)
     if dev is not None:
-        return np.asarray(dev.reconstruct(src, helpers, lost))
+        return coding_mod._on_device(
+            dev, src, len(lost), lambda shards, **kw: dev.reconstruct(
+                shards, helpers, lost, **kw))()
     mat = repair_matrix(e.k, e.m, helpers, lost)
     return e._host.matmul(mat, src)
 

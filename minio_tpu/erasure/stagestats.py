@@ -73,6 +73,12 @@ STAGES = (
     # program widens on the device or another copy takes the fill along,
     # the bytes alone
     "pad",
+    # input bytes a single-chip dispatch carried beyond its own g
+    # blocks, because the program is compiled at a few batch sizes only
+    # (erasure/coding.py DEVICE_BATCH_SIZES): (size - g) * k * S, what
+    # the carrier's buffer last held.  Counter only: the transfer's
+    # seconds are in h2d, the program's on the device
+    "batch_fill",
     # quorum write / read of xl.meta; signature + policy; admission wait
     "commit", "meta_read", "auth", "admit",
     # inside `read`, the HTTP front's body pipe (server/app.py

@@ -182,7 +182,7 @@ class PallasRSCodec:
             ("pallas-enc", k, m),
             lambda: jnp.asarray(_permute_mat(rs_tpu.encode_bits_matrix(k, m))))
 
-    def _run(self, mat, shards) -> jax.Array:
+    def _run(self, mat, shards, blocks=None) -> jax.Array:
         # the host side of a dispatch, timed as the calls are made: no
         # wait is added to tell transfer from enqueue, so `h2d` is the
         # hand-over as far as the call blocks and `launch` the jit call
@@ -193,15 +193,20 @@ class PallasRSCodec:
         b, k, s = shards.shape
         if s % SHARD_TILE:
             # widened and cut back inside the program: the bytes of the
-            # batch the kernel reads and of the rows cut, no host time
-            stagestats.add("pad", 0.0, b * (
+            # batch the kernel reads and of the rows cut, no host time.
+            # Of the real blocks: what a carrier holds beyond them is
+            # the engine's `batch_fill`
+            stagestats.add("pad", 0.0, (b if blocks is None else blocks) * (
                 k * kernel_width(s) + mat.shape[0] // 8 * s))
         with stagestats.timed("launch", shards.nbytes):
             return _coding_call_bytes(mat, shards, interpret=self._interpret)
 
-    def encode(self, data_shards) -> jax.Array:
-        """(B, K, S) uint8 -> (B, M, S) parity."""
-        return self._run(self._enc, data_shards)
+    def encode(self, data_shards, blocks=None) -> jax.Array:
+        """(B, K, S) uint8 -> (B, M, S) parity.  `blocks`: how many of
+        the B blocks are real, where the batch is a carrier of fewer
+        (erasure/coding.py `_on_device`); the program codes all B, the
+        stages book the real ones."""
+        return self._run(self._enc, data_shards, blocks)
 
     def encode_words(self, words) -> jax.Array:
         """(B, K, W) int32 (4 packed bytes per word) -> (B, M, W) int32.
@@ -225,8 +230,10 @@ class PallasRSCodec:
         d = jnp.asarray(data_shards, dtype=jnp.uint8)
         return jnp.concatenate([d, self.encode(d)], axis=1)
 
-    def reconstruct(self, src_shards, available, wanted) -> jax.Array:
-        return self._run(self._rec_mat(available, wanted), src_shards)
+    def reconstruct(self, src_shards, available, wanted,
+                    blocks=None) -> jax.Array:
+        return self._run(self._rec_mat(available, wanted), src_shards,
+                         blocks)
 
     def decode_data(self, src_shards, available) -> jax.Array:
         return self.reconstruct(src_shards, available, tuple(range(self.k)))

@@ -97,14 +97,17 @@ def bitrot_self_test() -> None:
 
 def device_self_test(k: int, m: int, block_size: int) -> float:
     """Encode and reconstruct through the device codec on the chip, at
-    the shape a deployment's steady state dispatches — one batch of
-    DEVICE_BATCH_BLOCKS blocks — and compare with the gf256 oracle.
+    every shape a request can dispatch — one batch of full blocks at
+    each of coding.DEVICE_BATCH_SIZES — and compare with the gf256
+    oracle.
 
     The device half of the boot self-test: a kernel that fails to
     compile, or computes wrong bytes, must stop the server as a broken
-    host codec does.  It is also the warm-up: the encode program and the
-    reconstruct programs for 1..m lost shards are compiled (or read from
-    the persistent cache) here, not inside the first request.  Returns
+    host codec does.  It is also the warm-up: at each batch size the
+    encode program and the reconstruct programs for 1..m lost shards
+    are compiled (or read from the persistent cache) here, and a
+    dispatch of any other number of blocks is carried at the next of
+    these sizes (coding._on_device), so no request compiles.  Returns
     the seconds it took.  The caller has established that this geometry
     dispatches to the device."""
     import time
@@ -117,28 +120,33 @@ def device_self_test(k: int, m: int, block_size: int) -> float:
     t0 = time.perf_counter()
     codec = coding._DeviceCodec.get(k, m, probe=False)
     # a full block's shard (cmd/erasure-coding.go:122): for 12+4 no
-    # multiple of the kernel's tile, which is what has to be seen here
-    b, s = coding.DEVICE_BATCH_BLOCKS, -(-block_size // k)
+    # multiple of the kernel's tile, which is what has to be seen here.
+    # One batch and one oracle parity at the largest size; a smaller
+    # size takes their first blocks (coding is per block)
+    sizes = coding.DEVICE_BATCH_SIZES
+    s = -(-block_size // k)
     batch = np.random.default_rng(k * 256 + m).integers(
-        0, 256, size=(b, k, s), dtype=np.uint8)
-    parity = np.asarray(codec.encode(batch))
-    flat = np.ascontiguousarray(batch.transpose(1, 0, 2)).reshape(k, b * s)
-    want = gf256.encode_np(flat, m).reshape(m, b, s).transpose(1, 0, 2)
-    if not np.array_equal(parity, want):
-        raise SelfTestError(
-            f"device erasure self-test failed for {k}+{m}: parity from "
-            f"the device differs from the gf256 oracle")
-    full = np.concatenate([batch, parity], axis=1)
-    for lost in range(1, m + 1):
-        wanted = tuple(range(lost))
-        avail = tuple(range(lost, lost + k))
-        rebuilt = np.asarray(codec.reconstruct(
-            np.ascontiguousarray(full[:, lost:lost + k]), avail, wanted))
-        if not np.array_equal(rebuilt, batch[:, :lost]):
+        0, 256, size=(sizes[-1], k, s), dtype=np.uint8)
+    flat = np.ascontiguousarray(batch.transpose(1, 0, 2)).reshape(k, -1)
+    want = gf256.encode_np(flat, m).reshape(m, -1, s).transpose(1, 0, 2)
+    full = np.concatenate([batch, want], axis=1)
+    for b in sizes:
+        parity = np.asarray(codec.encode(batch[:b]))
+        if not np.array_equal(parity, want[:b]):
             raise SelfTestError(
-                f"device erasure self-test failed for {k}+{m}: "
-                f"reconstructing {lost} lost shard(s) on the device does "
-                f"not round-trip")
+                f"device erasure self-test failed for {k}+{m} at {b} "
+                f"blocks: parity from the device differs from the gf256 "
+                f"oracle")
+        for lost in range(1, m + 1):
+            wanted = tuple(range(lost))
+            avail = tuple(range(lost, lost + k))
+            rebuilt = np.asarray(codec.reconstruct(
+                np.ascontiguousarray(full[:b, lost:lost + k]), avail, wanted))
+            if not np.array_equal(rebuilt, batch[:b, :lost]):
+                raise SelfTestError(
+                    f"device erasure self-test failed for {k}+{m} at {b} "
+                    f"blocks: reconstructing {lost} lost shard(s) on the "
+                    f"device does not round-trip")
     return time.perf_counter() - t0
 
 

@@ -141,6 +141,8 @@ def _boot_erasure_plane(pools) -> dict:
     REDUCED_REDUNDANCY parity of every pool), where steady-state batches
     are coded, and run the device self-test + warm-up for each geometry
     that reaches the chip.  Returns {"geometry": {"k+m": where},
+    "batchSizes": the block counts the device programs were compiled at
+    (what any dispatch of the single chip is carried at),
     "deviceSelfTestSeconds": float} for the banner and admin info."""
     from minio_tpu.erasure import coding
     from minio_tpu.erasure.objects import PutObjectOptions
@@ -158,7 +160,9 @@ def _boot_erasure_plane(pools) -> dict:
         where[f"{k}+{m}"] = coding.steady_state_backend(k, m)
         if where[f"{k}+{m}"] == "device":
             seconds += device_self_test(k, m, coding.BLOCK_SIZE_V2)
-    return {"geometry": where, "deviceSelfTestSeconds": round(seconds, 3)}
+    return {"geometry": where,
+            "batchSizes": list(coding.DEVICE_BATCH_SIZES),
+            "deviceSelfTestSeconds": round(seconds, 3)}
 
 
 def main(argv=None) -> int:
@@ -349,7 +353,8 @@ def main(argv=None) -> int:
         f"minio-tpu: erasure backend {backend} on {on}: {geometry}; "
         f"host codec "
         f"{'native AVX2' if host_codec.available() else 'numpy fallback'}; "
-        f"device self-test + warm-up {boot['deviceSelfTestSeconds']} s",
+        f"device self-test + warm-up {boot['deviceSelfTestSeconds']} s "
+        f"at batches of {boot['batchSizes']} blocks",
         file=sys.stderr,
     )
     if node.distributed:

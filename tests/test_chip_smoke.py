@@ -160,7 +160,7 @@ def test_device_self_test_catches_wrong_bytes(monkeypatch):
     from minio_tpu.ops import rs_pallas
 
     good = rs_pallas.PallasRSCodec(4, 2, interpret=True)
-    monkeypatch.setattr(coding, "DEVICE_BATCH_BLOCKS", 2)
+    monkeypatch.setattr(coding, "DEVICE_BATCH_SIZES", (2,))
     monkeypatch.setitem(coding._DeviceCodec._cache, (4, 2), (good, None))
     assert selftest.device_self_test(4, 2, 64 << 10) > 0
 

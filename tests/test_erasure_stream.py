@@ -137,13 +137,13 @@ class _CountingCodec:
         self.encodes = 0
         self.reconstructs = 0
 
-    def encode(self, batch):
+    def encode(self, batch, **kw):
         self.encodes += 1
-        return self.inner.encode(batch)
+        return self.inner.encode(batch, **kw)
 
-    def reconstruct(self, batch, available, wanted):
+    def reconstruct(self, batch, available, wanted, **kw):
         self.reconstructs += 1
-        return self.inner.reconstruct(batch, available, wanted)
+        return self.inner.reconstruct(batch, available, wanted, **kw)
 
 
 def test_device_codec_stream_roundtrip(tmp_path):

@@ -314,11 +314,11 @@ class TestCodecBackendObservability:
                 self._h = host_mod.HostRSCodec(k, m)
                 self.calls = 0
 
-            def encode(self, batch):
+            def encode(self, batch, blocks=None):
                 self.calls += 1
                 return self._h.encode(batch)
 
-            def reconstruct(self, batch, available, wanted):
+            def reconstruct(self, batch, available, wanted, blocks=None):
                 self.calls += 1
                 return self._h.reconstruct(batch, available, wanted)
 

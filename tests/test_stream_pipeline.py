@@ -29,7 +29,7 @@ class _RecordingCodec:
         self.max_outstanding = 0
         self._lock = threading.Lock()
 
-    def encode(self, batch):
+    def encode(self, batch, blocks=None):
         with self._lock:
             self.outstanding += 1
             self.max_outstanding = max(self.max_outstanding,
