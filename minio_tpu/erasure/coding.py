@@ -25,11 +25,13 @@ device dispatch into a host one.
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures as cf
 import os
 
 import threading
 import time
+import weakref
 from typing import BinaryIO, Sequence
 
 import numpy as np
@@ -85,36 +87,82 @@ def pipeline_enabled() -> bool:
 _pool_lock = threading.Lock()
 _shared_pool: cf.ThreadPoolExecutor | None = None
 
-# Reusable read arenas for encode_stream: a fresh 32 MiB np.empty per
-# slot per PUT costs ~100 MiB of page faults per request; the pool keeps
-# recently-used arenas warm.  Keyed by exact size, LRU across size
-# classes (dict preserves insertion order; a touch reinserts the key):
-# small streams clamp slot size to the stream, so a varied-size workload
-# mints many one-off classes — without eviction those would pin the
-# whole budget and lock the hot full-batch arenas out of the pool.
+# Reusable buffers of the streams: a PUT's read slots, a degraded read's
+# staging arenas and a GET's response blocks.  A fresh 32 MiB np.empty
+# per slot per PUT costs ~100 MiB of page faults per request (an mmap,
+# a first touch of every page, a munmap; on the chip's machines ten
+# times the copy into warm pages, PERF.md section 6, PR 29); the pool
+# keeps recently-used buffers warm.  Keyed by exact size, LRU across
+# size classes (dict preserves insertion order; a touch reinserts the
+# key): small streams clamp slot size to the stream, so a varied-size
+# workload mints many one-off classes — without eviction those would pin
+# the whole budget and lock the hot full-batch arenas out of the pool.
+# The budget is what may lie idle, and is sized so that the steady state
+# of eight streams of 64 MiB GETs (one arena and two blocks of 32 MiB
+# each, 768 MiB when all of it lies idle at once) and of twenty 10 MiB
+# GETs (a 16-block arena and a 10 MiB block each, 521 MiB) is taken
+# from the pool and not from the allocator.
 _arena_lock = threading.Lock()
 _arena_pool: dict[int, list] = {}
-_ARENA_POOL_MAX_BYTES = 256 << 20
+_ARENA_POOL_MAX_BYTES = 1 << 30
 _arena_pool_bytes = 0
+# buffers of response blocks that nothing refers to any more, on their
+# way back to the pool.  A block's finalizer runs wherever its last
+# reference goes, inside a garbage collection that an allocation under
+# _arena_lock set off too, so it never waits for the lock: it appends
+# here (atomic) and takes the lock only if that is free; what it leaves
+# behind the pool's next caller takes in
+_blocks_dropped: collections.deque = collections.deque()
+
+
+def _pool_put(arr: np.ndarray) -> None:
+    """Under _arena_lock: a flat buffer becomes the most recent of its
+    size class, and the least recently touched classes make room."""
+    # lint: allow(shared-state): per-process arena pool by design — each data-plane worker recycles its own read buffers
+    global _arena_pool_bytes
+    if arr.nbytes > _ARENA_POOL_MAX_BYTES:
+        return
+    while _arena_pool_bytes + arr.nbytes > _ARENA_POOL_MAX_BYTES:
+        size, bucket = next(iter(_arena_pool.items()))
+        bucket.pop()
+        _arena_pool_bytes -= size
+        if not bucket:
+            del _arena_pool[size]
+    bucket = _arena_pool.pop(arr.nbytes, [])
+    bucket.append(arr)
+    _arena_pool[arr.nbytes] = bucket
+    _arena_pool_bytes += arr.nbytes
+
+
+def _pool_take_in() -> None:
+    """Under _arena_lock: the dropped blocks' buffers join the pool."""
+    while _blocks_dropped:
+        _pool_put(_blocks_dropped.popleft())
+
+
+def _pool_take(nbytes: int) -> np.ndarray | None:
+    """A pooled flat buffer of exactly `nbytes`, its pages there
+    already, or None."""
+    # lint: allow(shared-state): per-process arena pool by design — see _pool_put
+    global _arena_pool_bytes
+    with _arena_lock:
+        _pool_take_in()
+        bucket = _arena_pool.pop(nbytes, None)
+        if not bucket:
+            return None
+        arr = bucket.pop()
+        if bucket:
+            _arena_pool[nbytes] = bucket  # reinsert: now most-recent
+        _arena_pool_bytes -= nbytes
+        return arr
 
 
 def _arena_acquire(nbytes: int) -> np.ndarray:
-    # lint: allow(shared-state): per-process arena pool by design — each data-plane worker recycles its own read buffers
-    global _arena_pool_bytes
-    with _arena_lock:
-        bucket = _arena_pool.pop(nbytes, None)
-        if bucket:
-            arr = bucket.pop()
-            if bucket:
-                _arena_pool[nbytes] = bucket  # reinsert: now most-recent
-            _arena_pool_bytes -= nbytes
-            return arr
-    return np.empty(nbytes, dtype=np.uint8)
+    arr = _pool_take(nbytes)
+    return np.empty(nbytes, dtype=np.uint8) if arr is None else arr
 
 
 def _arena_release(arr: np.ndarray) -> None:
-    # lint: allow(shared-state): per-process arena pool by design — see _arena_acquire
-    global _arena_pool_bytes
     # the head of a carrier gives back the whole of it
     arr = _carrier_of(arr, arr)
     if arr.ndim != 1:
@@ -122,19 +170,43 @@ def _arena_release(arr: np.ndarray) -> None:
         # its arena (contiguous: a view)
         arr = arr.reshape(-1)
     with _arena_lock:
-        if arr.nbytes > _ARENA_POOL_MAX_BYTES:
-            return
-        while _arena_pool_bytes + arr.nbytes > _ARENA_POOL_MAX_BYTES:
-            # evict from the least-recently-touched size class
-            size, bucket = next(iter(_arena_pool.items()))
-            bucket.pop()
-            _arena_pool_bytes -= size
-            if not bucket:
-                del _arena_pool[size]
-        bucket = _arena_pool.pop(arr.nbytes, [])
-        bucket.append(arr)
-        _arena_pool[arr.nbytes] = bucket
-        _arena_pool_bytes += arr.nbytes
+        _pool_put(arr)
+
+
+def _block_dropped(raw: np.ndarray) -> None:
+    _blocks_dropped.append(raw)
+    if _arena_lock.acquire(blocking=False):
+        try:
+            _pool_take_in()
+        finally:
+            _arena_lock.release()
+
+
+def _block_acquire(nblocks: int, block_len: int) -> np.ndarray:
+    """A (nblocks, block_len) response block on a pooled buffer, which
+    goes back to the pool when nothing refers to the block's memory any
+    more.  The writer keeps what it is handed for a time this module
+    cannot see (the sink's queue, the pump's read-ahead, the socket's
+    buffer after the write returned, a consumer's own list), so no call
+    gives a block back: the pool keeps the raw buffer, every take wraps
+    it in a new array, and that array's finalizer returns the buffer.
+    The wrap goes over a memoryview because numpy then stops a view's
+    chain of bases at the new array (a plain view of the raw array
+    would hand its views the raw array as their base, and the block
+    could die before them): every slice, reshape and memoryview of the
+    block keeps it alive.
+
+    The buffer holds what its last user left: the caller writes every
+    byte before it hands the block on."""
+    nbytes = nblocks * block_len
+    raw = _pool_take(nbytes)
+    if raw is None:
+        raw = np.empty(nbytes, dtype=np.uint8)
+    else:
+        stagestats.add("block_reuse", 0.0, nbytes)
+    block = np.frombuffer(memoryview(raw), dtype=np.uint8)
+    weakref.finalize(block, _block_dropped, raw)
+    return block.reshape(nblocks, block_len)
 
 
 def carrier_blocks(g: int) -> int:
@@ -1176,12 +1248,20 @@ class Erasure:
         shards are reconstructed in one batched dispatch of that arena
         as it is.  A data shard is copied once, straight to its place
         in the block; where k does not divide the block, the zeros that
-        fill up the last shards stay behind in that same copy.  The
-        block is a fresh array that the writer owns; the arena goes
-        back to the pool on every exit."""
+        fill up the last shards stay behind in that same copy.  A
+        group of full blocks lands on a pooled block, which holds
+        another request's bytes until `place` has covered
+        [0, block_len) for all k shards, and which goes back when the
+        writer and whoever it handed views to have dropped it
+        (_block_acquire).  A tail block and an inline object are
+        one-off sizes that leave as a copy: a fresh array.  The arena
+        goes back to the pool on every exit."""
         missing = tuple(i for i in range(self.k) if i not in got)
         shard_bytes = nblocks * shard_len
-        data = np.empty((nblocks, block_len), dtype=np.uint8)
+        if block_len == self.block_size:
+            data = _block_acquire(nblocks, block_len)
+        else:
+            data = np.empty((nblocks, block_len), dtype=np.uint8)
 
         def place(i: int, rows: np.ndarray) -> None:
             lo = min(i * shard_len, block_len)

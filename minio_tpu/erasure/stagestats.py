@@ -79,6 +79,12 @@ STAGES = (
     # the carrier's buffer last held.  Counter only: the transfer's
     # seconds are in h2d, the program's on the device
     "batch_fill",
+    # bytes of a GET's response blocks that came from the arena pool,
+    # their pages there already (erasure/coding.py _block_acquire); a
+    # block on a fresh array books nothing.  Counter only: over
+    # `respond`'s bytes 1 where every group of full blocks hit the pool,
+    # 0 where none did or the objects have no full block
+    "block_reuse",
     # quorum write / read of xl.meta; signature + policy; admission wait
     "commit", "meta_read", "auth", "admit",
     # inside `read`, the HTTP front's body pipe (server/app.py

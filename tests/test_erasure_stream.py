@@ -5,8 +5,13 @@ Mirrors the reference's codec-vs-tmpdir-drive tests
 flips, offline drives, quorum failures.
 """
 
+import concurrent.futures as cf
+import gc
 import io
 import os
+import threading
+import time
+import weakref
 
 import numpy as np
 import pytest
@@ -533,6 +538,23 @@ def test_reader_with_read_at_only_is_copied_into_its_column(tmp_path):
     assert after["assemble"] - before["assemble"] == (k + 2) * col
 
 
+@pytest.fixture
+def empty_pool(monkeypatch):
+    """The process's arena pool, emptied for one test."""
+    from minio_tpu.erasure import coding
+
+    gc.collect()  # an earlier test's dropped blocks come back first
+    with coding._arena_lock:
+        coding._pool_take_in()
+        saved = dict(coding._arena_pool)
+        coding._arena_pool.clear()
+    monkeypatch.setattr(coding, "_arena_pool_bytes", 0)
+    yield
+    with coding._arena_lock:
+        coding._arena_pool.clear()
+        coding._arena_pool.update(saved)
+
+
 class _AliasSink:
     """A writer that keeps what it was handed, as the HTTP front's
     queue does while the stream's thread reads the next group."""
@@ -545,26 +567,82 @@ class _AliasSink:
         return len(data)
 
 
+class _BlockWatch:
+    """Every response block the engine takes: a weak reference to the
+    array whose death gives its buffer back, and where its bytes lie."""
+
+    def __init__(self, monkeypatch):
+        from minio_tpu.erasure import coding
+
+        self.refs, self.addresses = [], []
+        acquire = coding._block_acquire
+
+        def acq(nblocks, block_len):
+            data = acquire(nblocks, block_len)
+            self.refs.append(weakref.ref(data.base))
+            self.addresses.append(data.ctypes.data)
+            return data
+
+        monkeypatch.setattr(coding, "_block_acquire", acq)
+
+    @property
+    def out(self) -> int:
+        gc.collect()
+        return sum(r() is not None for r in self.refs)
+
+
+def _block_reuse_bytes() -> int:
+    from minio_tpu.erasure import stagestats
+
+    return stagestats.snapshot()["block_reuse"]["bytes"]
+
+
 def test_arenas_go_back_to_the_pool_and_no_response_aliases_one(
-        tmp_path, monkeypatch):
+        tmp_path, monkeypatch, empty_pool):
+    """No live chunk shares memory with a staging arena that was taken
+    while it lived, nor with a block handed out after it (at 2+2 an
+    arena and a block are one size class: a buffer serves as both, one
+    after the other)."""
     from minio_tpu.erasure import coding
 
     k, m = 2, 2
     e, paths, payload = _stored(tmp_path, k, m, size=100 * _BS)
     size = len(payload)
     watch = _ArenaWatch(monkeypatch)
+    blocks = _BlockWatch(monkeypatch)
+    # a PUT's slot, an arena and a block are one size class at 2+2
+    pooled = len(coding._arena_pool[32 * _BS])
+
+    class Sink(_AliasSink):
+        def __init__(self):
+            super().__init__()
+            self.arenas_before = []
+
+        def write(self, data):
+            self.arenas_before.append(len(watch.seen))
+            return super().write(data)
+
     for _ in range(3):  # twelve degraded groups
-        sink = _AliasSink()
+        sink = Sink()
         assert e.decode_stream(sink, _open(e, paths, size, (0, 3)), 0,
                                size, size) == size
         assert watch.out == 0
         assert b"".join(bytes(c) for c in sink.chunks) == payload
-        for chunk in sink.chunks:
-            flat = np.frombuffer(chunk, np.uint8)
-            assert not any(np.shares_memory(flat, a) for a in watch.seen)
+        flats = [np.frombuffer(c, np.uint8) for c in sink.chunks]
+        for n, flat in enumerate(flats):
+            later = watch.seen[sink.arenas_before[n]:]
+            assert not any(np.shares_memory(flat, a) for a in later)
+            assert not any(np.shares_memory(flat, f) for f in flats[:n])
+        assert blocks.out == len(sink.chunks) == 4
+        del flats, flat
+    del sink
+    assert blocks.out == 0
     assert watch.most == 1 and len(watch.seen) == 12
-    # the pool gave the same few arenas out again
-    assert len({a.ctypes.data for a in watch.seen}) <= 2
+    # the pool gave the same few buffers out again, as arenas and as
+    # blocks: 24 takes, of which one arena and a request's four blocks
+    # are out at a time
+    assert len({a.ctypes.data for a in watch.seen}
+               | set(blocks.addresses)) <= 5 + pooled
     assert 0 < coding._arena_pool_bytes <= coding._ARENA_POOL_MAX_BYTES
 
     # a group that loses its quorum while its reads are out: shard 1
@@ -573,7 +651,7 @@ def test_arenas_go_back_to_the_pool_and_no_response_aliases_one(
     with pytest.raises(errors.ErasureReadQuorum):
         e.decode_stream(_AliasSink(), _open(e, paths, size, (0, 3)), 0,
                         size, size)
-    assert watch.out == 0 and watch.most == 1
+    assert watch.out == 0 and watch.most == 1 and blocks.out == 0
     # and one that never had it takes no arena
     taken = len(watch.seen)
     with pytest.raises(errors.ErasureReadQuorum):
@@ -585,6 +663,320 @@ def test_arenas_go_back_to_the_pool_and_no_response_aliases_one(
                 None, None], _open(e, paths, size, (0, 3)), size)
     assert watch.out == 0
     assert coding._arena_pool_bytes <= coding._ARENA_POOL_MAX_BYTES
+
+
+# -- the response block of a group of full blocks (ISSUE 34) -----------------
+def test_kept_chunk_keeps_its_block_out_of_the_pool(tmp_path, monkeypatch):
+    """A consumer holds chunk 0 (and a slice, a memoryview of a slice
+    and an array over it) while the same stream and later requests
+    decode: its bytes stay the payload's, and the pool hands that
+    memory out again only once the last of them is gone."""
+    from minio_tpu.erasure import coding
+
+    k, m = 2, 2
+    e, paths, payload = _stored(tmp_path, k, m, size=100 * _BS)
+    size = len(payload)
+    blocks = _BlockWatch(monkeypatch)
+    first = _AliasSink()
+    e.decode_stream(first, _open(e, paths, size, (0,)), 0, size, size)
+    kept = first.chunks[0]
+    assert len(kept) == 32 * _BS
+    where = np.frombuffer(kept, np.uint8).ctypes.data
+    holders = {
+        "chunk": kept,
+        "slice": kept[5:5 + _BS],
+        "array": np.frombuffer(kept, np.uint8)[7 * _BS:],
+        "view of a view": np.frombuffer(kept[3:], np.uint8).reshape(-1)[9:].data,
+    }
+    del first, kept
+    for name in list(holders):
+        taken = len(blocks.addresses)
+        for gone in ((), (0, 3)):  # healthy and degraded requests
+            out = io.BytesIO()
+            e.decode_stream(out, _open(e, paths, size, gone), 0, size, size)
+            assert out.getvalue() == payload
+        assert len(blocks.addresses) == taken + 8
+        assert where not in blocks.addresses[taken:], name
+        for held, off in (("chunk", 0), ("slice", 5), ("array", 7 * _BS),
+                          ("view of a view", 12)):
+            if held in holders:
+                got = bytes(holders[held])
+                assert got == payload[off:off + len(got)], (name, held)
+        del holders[name], got
+    # nothing refers to it any more: the class's most recent buffer
+    gc.collect()
+    raw = coding._pool_take(32 * _BS)
+    assert raw.ctypes.data == where
+    coding._arena_release(raw)
+    assert blocks.out == 0
+
+
+@pytest.mark.parametrize("gone", [(), "data", "mixed"],
+                         ids=["healthy", "two-data-lost", "data-and-parity"])
+@pytest.mark.parametrize("k,m", [(2, 2), (8, 4), (12, 4)])
+def test_no_served_byte_is_one_the_request_did_not_write(
+        tmp_path, monkeypatch, k, m, gone):
+    """Every buffer the pool gives out, hit or miss, is full of a
+    pattern; each served byte is the payload's all the same, whole and
+    ranged."""
+    from minio_tpu.erasure import coding
+
+    e, paths, payload = _stored(tmp_path, k, m)
+    size = len(payload)
+    gone = {(): (), "data": (0, k - 1), "mixed": (1, k + m - 1)}[gone]
+    take = coding._pool_take
+
+    def patterned(nbytes):
+        arr = take(nbytes)
+        if arr is None:
+            arr = np.empty(nbytes, np.uint8)
+        arr[:] = 0xA5
+        return arr
+
+    monkeypatch.setattr(coding, "_pool_take", patterned)
+    reused = _block_reuse_bytes()
+    for off, ln in ((0, size), (3 * _BS + 77, 5 * _BS + 1),
+                    (31 * _BS + 5, 2 * _BS), (_BS, 39 * _BS),
+                    (40 * _BS - 1, 4322)):
+        sink = _AliasSink()
+        assert e.decode_stream(sink, _open(e, paths, size, gone), off, ln,
+                               size) == ln
+        assert b"".join(bytes(c) for c in sink.chunks) \
+            == payload[off:off + ln], (off, ln)
+    # every full-block group was told it came from the pool
+    assert _block_reuse_bytes() - reused == (40 + 6 + 3 + 39 + 1) * _BS
+
+
+def test_abandoned_and_failed_streams_leave_no_block_out(tmp_path,
+                                                         monkeypatch):
+    from minio_tpu.erasure.objects import ErasureObjects, _IterSink
+
+    k, m = 2, 2
+    e, paths, payload = _stored(tmp_path, k, m, size=200 * _BS)
+    size = len(payload)
+    blocks = _BlockWatch(monkeypatch)
+
+    # the consumer goes away after the first chunk, as a client that
+    # disconnects does (_stream_object: GeneratorExit -> abandon)
+    sink = _IterSink(maxsize=2)
+    worker = threading.Thread(
+        target=ErasureObjects._decode_to_sink,
+        args=(e, sink, _open(e, paths, size, (0, 3)), 0, size, size),
+        daemon=True)
+    worker.start()
+    chunks = iter(sink)
+    head = next(chunks)
+    assert bytes(head) == payload[:32 * _BS]
+    sink.abandon()
+    worker.join(30)
+    assert not worker.is_alive()
+    assert isinstance(sink.error, BrokenPipeError)
+    assert blocks.refs and blocks.out >= 1  # `head` is still held
+    sink.abandon()  # what the producer put after the drain
+    del head, chunks, sink, worker
+    assert blocks.out == 0
+
+    # a group loses its quorum in the middle of the stream, with the
+    # chunks before it in a consumer's hands
+    _flip(paths[1], 70, 32 + e.shard_size)
+    kept = _AliasSink()
+    with pytest.raises(errors.ErasureReadQuorum):
+        e.decode_stream(kept, _open(e, paths, size, (0, 3)), 0, size, size)
+    assert b"".join(bytes(c) for c in kept.chunks) == payload[:64 * _BS]
+    assert blocks.out == 2
+    del kept
+    assert blocks.out == 0
+
+
+@pytest.mark.parametrize("size", [4321, _BS - 1, 3 * _BS + 4321],
+                         ids=["inline", "under-a-block", "tail"])
+@pytest.mark.parametrize("gone", [(), (0,)], ids=["healthy", "degraded"])
+def test_tail_blocks_and_small_objects_take_no_block(tmp_path, monkeypatch,
+                                                     size, gone):
+    """A tail and an object under one block are one-off sizes that
+    leave as a copy: their block is a fresh array, and the healthy ones
+    ask the pool for nothing at all."""
+    from minio_tpu.erasure import coding
+
+    k, m = 4, 2
+    e, paths, payload = _stored(tmp_path, k, m, size=size)
+    blocks = _BlockWatch(monkeypatch)
+    asked = []
+    take = coding._pool_take
+    monkeypatch.setattr(coding, "_pool_take",
+                        lambda n: asked.append(n) or take(n))
+    reused = _block_reuse_bytes()
+    full = size // _BS
+    off = full * _BS  # the tail alone, then the whole object
+    for lo, ln in ((off, size - off), (0, size)):
+        out = io.BytesIO()
+        assert e.decode_stream(out, _open(e, paths, size, gone), lo, ln,
+                               size) == ln
+        assert out.getvalue() == payload[lo:lo + ln]
+    assert len(blocks.refs) == (1 if full else 0)
+    tail_arena = [n for n in asked if n < _BS + k]
+    assert len(tail_arena) == (2 if gone else 0)  # the staged read's
+    assert len(asked) - len(tail_arena) == (len(gone) + 1 if full else 0)
+    assert _block_reuse_bytes() - reused in (0, full * _BS)
+
+
+def test_put_slots_stay_pooled_through_a_burst_of_gets(tmp_path, monkeypatch,
+                                                       empty_pool):
+    """Eight streams of two-group objects at 12+4, every chunk held to
+    the end (the most a closed loop keeps alive), with the budget cut
+    as the blocks are (64 KiB for 1 MiB): the slots of the PUT before
+    them are still in the pool afterwards, and the next PUT takes them
+    from there."""
+    from minio_tpu.erasure import coding
+
+    scale = coding.BLOCK_SIZE_V2 // _BS
+    monkeypatch.setattr(coding, "_ARENA_POOL_MAX_BYTES",
+                        coding._ARENA_POOL_MAX_BYTES // scale)
+    k, m = 12, 4
+    e, paths, payload = _stored(tmp_path, k, m, size=64 * _BS)
+    size = len(payload)
+    slot = 32 * _BS  # encode_stream's read slot: a full batch
+    slots = {a.ctypes.data for a in coding._arena_pool[slot]}
+    assert len(slots) == 2
+    sinks = [_AliasSink() for _ in range(8)]
+    streams = [threading.Thread(
+        target=e.decode_stream,
+        args=(s, _open(e, paths, size, (0, 6)), 0, size, size))
+        for s in sinks]
+    for t in streams:
+        t.start()
+    for t in streams:
+        t.join(60)
+    assert not any(t.is_alive() for t in streams)
+    for s in sinks:
+        assert b"".join(bytes(c) for c in s.chunks) == payload
+    del sinks, s
+    gc.collect()
+    # a 12+4 arena is its own class, 32 x 12 x 5,462 bytes; a slot
+    # and a block are one class, so the two slots served as blocks
+    # and came back with the fourteen that the burst added
+    assert coding._arena_pool_bytes <= coding._ARENA_POOL_MAX_BYTES
+    assert 32 * k * e.shard_size in coding._arena_pool
+    assert len(coding._arena_pool[slot]) == 16
+    assert slots <= {a.ctypes.data for a in coding._arena_pool[slot]}
+    # the next PUT took its two slots from there: a fresh pair would
+    # have come back as a seventeenth and an eighteenth
+    _stored(tmp_path, k, m, size=64 * _BS)
+    assert len(coding._arena_pool[slot]) == 16
+
+
+def test_pool_under_threads_hands_no_buffer_out_twice(monkeypatch,
+                                                     empty_pool):
+    """Sixteen threads take blocks and arenas of two size classes from
+    a pool too small for them (so classes are evicted), write their own
+    mark, keep a slice across a switch of threads and find the mark
+    unchanged; afterwards the pool's count of bytes is the bytes in it,
+    within the budget, and no buffer lies in it twice."""
+    import sys
+
+    from minio_tpu.erasure import coding
+
+    sizes = (4096, 12288)
+    monkeypatch.setattr(coding, "_ARENA_POOL_MAX_BYTES", 10 * sizes[1])
+    wrong = []
+
+    def churn(mark: int) -> None:
+        for n in range(300):
+            size = sizes[(n + mark) % 2]
+            if n % 3:
+                block = coding._block_acquire(1, size)
+                kept = block.reshape(-1)[size // 2:].data
+                del block
+            else:
+                kept = arena = coding._arena_acquire(size)
+            kept_view = np.frombuffer(kept, np.uint8)
+            kept_view[:] = mark
+            time.sleep(0)
+            if not (kept_view == mark).all():
+                wrong.append((mark, n))
+            del kept_view
+            if n % 3 == 0:
+                coding._arena_release(arena)
+                del arena
+            del kept
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=churn, args=(mark,))
+                   for mark in range(1, 17)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not wrong
+    gc.collect()
+    with coding._arena_lock:
+        coding._pool_take_in()
+        pooled = [a for bucket in coding._arena_pool.values() for a in bucket]
+        assert coding._arena_pool_bytes == sum(a.nbytes for a in pooled)
+    assert 0 < coding._arena_pool_bytes <= coding._ARENA_POOL_MAX_BYTES
+    assert len({a.ctypes.data for a in pooled}) == len(pooled)
+    assert set(coding._arena_pool) <= set(sizes)
+
+
+def test_slow_client_gets_its_own_bytes_through_the_handler(tmp_path):
+    """The real handler (`_pump_stream`): one client takes its body a
+    little at a time, so its chunks wait in the sink's queue, in the
+    pump's read-ahead and in the socket's buffer, while two others
+    fetch another object of the same size classes over and over, out
+    of the same pool.  Every body is its own object's, byte for byte."""
+    import http.client
+
+    from minio_tpu.server import sigv4
+
+    from .s3_harness import S3TestServer
+
+    size = (34 << 20) + 4321  # groups of 32 and 2 blocks, and a tail
+    rng = np.random.default_rng(34)
+    bodies = {name: rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+              for name in ("slow", "fast")}
+    srv = S3TestServer(str(tmp_path))
+    try:
+        assert srv.request("PUT", "/blocks").status == 200
+        for name, body in bodies.items():
+            assert srv.request("PUT", f"/blocks/{name}",
+                               data=body).status == 200
+
+        def get(name: str, pause_s: float) -> bytes:
+            headers = sigv4.sign_request(
+                "GET", f"/blocks/{name}", [], {"host": srv.host}, b"",
+                srv.ak, srv.sk)
+            conn = http.client.HTTPConnection("127.0.0.1", srv.port,
+                                              timeout=60)
+            try:
+                conn.request("GET", f"/blocks/{name}", headers=headers)
+                resp = conn.getresponse()
+                assert resp.status == 200
+                got = bytearray()
+                while piece := resp.read(1 << 20):
+                    got += piece
+                    time.sleep(pause_s)
+                return bytes(got)
+            finally:
+                conn.close()
+
+        reused = _block_reuse_bytes()
+        with cf.ThreadPoolExecutor(3) as clients:
+            slow = clients.submit(get, "slow", 0.05)
+            fast = [clients.submit(
+                lambda: [get("fast", 0.0) for _ in range(3)])
+                for _ in range(2)]
+            assert slow.result(120) == bodies["slow"]
+            for f in fast:
+                assert f.result(120) == [bodies["fast"]] * 3
+        assert get("slow", 0.0) == bodies["slow"]
+        assert _block_reuse_bytes() - reused >= 34 << 20
+    finally:
+        srv.close()
 
 
 @pytest.mark.parametrize("available,wanted", [
