@@ -38,7 +38,7 @@ import numpy as np
 
 from minio_tpu.ops import device, gf256, host
 from minio_tpu.storage import errors
-from minio_tpu.utils.deadline import ctx_submit
+from minio_tpu.utils.deadline import ctx_submit, service_thread
 from minio_tpu.utils.logger import log
 from . import batcher as batcher_mod
 from . import stagestats
@@ -356,10 +356,29 @@ class _DeviceCodec:
     encode beats the host codec on this machine; the probe can lose on
     time, never on an error.  `get(k, m, probe=False)` (backend "tpu")
     bypasses the verdict and raises BackendUnavailable without a TPU.
+
+    A dispatch takes the codec from `ready`, never from `get`: a
+    geometry's programs exist (compiled, or read from the persistent
+    cache, and compared with the oracle) once `self_test` has passed
+    for it, and nothing compiles them on a request's thread.  Boot runs
+    `self_test` for the geometries a healthy set writes; any other (the
+    upgraded parity of a PUT to a set with drives away, an object
+    another deployment wrote) is coded by the host codec, byte for byte
+    the same, while one background thread runs its `self_test`.
     """
 
     _cache: dict = {}  # (k, m) -> (codec | None, device_wins: bool | None)
     _lock = threading.Lock()
+    # (k, m) -> the codec whose self-test has passed: the one lookup a
+    # dispatch makes
+    _ready: dict = {}
+    # (k, m) -> "warming" | "device" | "host" | "failed", for every
+    # geometry that was asked for (admin info, the ready gauge); a
+    # geometry in here is never asked for again
+    _state: dict = {}
+    _asked: collections.deque = collections.deque()  # (k, m, probe) waiting
+    _warmer: threading.Thread | None = None  # lives while _asked holds any
+    _warming = threading.local()  # .on: this thread is inside self_test
 
     @classmethod
     def _probe(cls, codec, k: int, m: int) -> bool:
@@ -445,18 +464,119 @@ class _DeviceCodec:
                 cls._cache[key] = (codec, wins)
             return codec if wins else None
 
+    @classmethod
+    def ready(cls, k: int, m: int, probe: bool = False, nbytes: int = 0):
+        """The geometry's device codec once its self-test has passed;
+        until then None, the host codec's turn, and the first such call
+        asks for the self-test (`warm`).  `nbytes`: what a dispatch is
+        about to code; the stage `warming` takes them where the host
+        codes them only because the programs are not there."""
+        codec = cls._ready.get((k, m))
+        if codec is None:
+            if (k, m) not in cls._state:
+                cls.warm(k, m, probe)
+            if nbytes and cls._state.get((k, m)) != "host":
+                stagestats.add("warming", 0.0, nbytes)
+        return codec
+
+    @classmethod
+    def warm(cls, k: int, m: int, probe: bool = False) -> None:
+        """Ask, once, for the geometry's self-test on the one background
+        thread; nothing where it is ready, asked for already, or where
+        no TPU is attached (backend "auto": the host codec for good)."""
+        on_tpu = device.info().platform == "tpu"
+        with cls._lock:
+            if (k, m) in cls._state:
+                return
+            if not on_tpu:
+                cls._state[(k, m)] = "host"
+                return
+            cls._state[(k, m)] = "warming"
+            cls._asked.append((k, m, probe))
+            if cls._warmer is None:
+                # lint: allow(shared-state): per-process by design — the warm-up thread belongs to the one process that holds the chip (data-plane workers are pinned to the host codec and never ask)
+                cls._warmer = service_thread(cls._warm_loop,
+                                             name="codec-warm")
+
+    @classmethod
+    def _warm_loop(cls) -> None:
+        while True:
+            with cls._lock:
+                if not cls._asked:
+                    # lint: allow(shared-state): per-process by design — see warm()
+                    cls._warmer = None
+                    return
+                k, m, probe = cls._asked.popleft()
+            geometry = f"{k}+{m}"
+            try:
+                if probe and cls.get(k, m) is None:
+                    with cls._lock:
+                        cls._state[(k, m)] = "host"
+                    continue
+                with stagestats.annotation("codec.warm"):
+                    seconds = cls.self_test(k, m)
+                log.info("erasure geometry ready on the device",
+                         geometry=geometry, seconds=round(seconds, 3))
+            except Exception as e:
+                # a serving node goes on, this geometry on the host
+                # codec; admin info and the ready gauge say so
+                with cls._lock:
+                    cls._state[(k, m)] = "failed"
+                log.error("erasure geometry stays on the host codec: its "
+                          "device self-test failed", geometry=geometry,
+                          error=f"{type(e).__name__}: {e}")
+
+    @classmethod
+    def self_test(cls, k: int, m: int) -> float:
+        """selftest.device_self_test for this geometry, on the calling
+        thread (boot's, or the warm-up's); once it has passed, the
+        geometry's dispatches go to the device.  -> its seconds."""
+        from minio_tpu.selftest import device_self_test
+
+        cls._warming.on = True
+        try:
+            seconds = device_self_test(k, m, BLOCK_SIZE_V2)
+        finally:
+            cls._warming.on = False
+        with cls._lock:
+            cls._ready[(k, m)] = cls._cache[(k, m)][0]
+            cls._state[(k, m)] = "device"
+        return seconds
+
+    @classmethod
+    def self_testing(cls) -> bool:
+        """Whether the calling thread is inside `self_test`: what
+        compiles there is a warm-up, what compiles anywhere else a
+        request waited for (the stage `compile_wait`)."""
+        return getattr(cls._warming, "on", False)
+
+
+def geometry_states() -> dict:
+    """{'k+m': "warming" | "device" | "host" | "failed"} of every
+    geometry whose single-chip codec was asked for: `device` once its
+    self-test has passed, `warming` while the background thread is at
+    it (its dispatches are on the host codec), `failed` where that
+    self-test did not pass, `host` where there is no device codec."""
+    with _DeviceCodec._lock:
+        return {f"{k}+{m}": state
+                for (k, m), state in sorted(_DeviceCodec._state.items())}
+
 
 def steady_state_backend(k: int, m: int,
                          block_size: int = BLOCK_SIZE_V2) -> str:
     """"device" | "mesh" | "host": where a full batch of this geometry's
     blocks is coded under the configured backend — the dispatch
-    encode_stream, degraded reads and heal make in steady state.  Under
-    "auto" on a TPU this runs the calibration probe.  The rule is by
-    shard length and not by geometry: full-width shards of any length go
-    to the device (12+4's 87,382 bytes too: the dispatch program widens
-    them to the kernel's tile on the device, ops/rs_pallas.py), tail
-    blocks and inline objects stay on the host."""
+    encode_stream, degraded reads and heal make in steady state, once
+    the geometry's device programs are there (_DeviceCodec.self_test).
+    Under "auto" on a TPU this runs the calibration probe.  The rule is
+    by shard length and not by geometry: full-width shards of any length
+    go to the device (12+4's 87,382 bytes too: the dispatch program
+    widens them to the kernel's tile on the device, ops/rs_pallas.py),
+    tail blocks and inline objects stay on the host."""
     e = Erasure(k, m, block_size)
+    if m and e.backend in ("tpu", "auto"):
+        return _backend_name(
+            _DeviceCodec.get(k, m, probe=e.backend == "auto"))
     return _backend_name(
         e._device(block_size * DEVICE_BATCH_BLOCKS, e.shard_size))
 
@@ -557,8 +677,10 @@ class Erasure:
         parity = self._encode_shards(shards[None, ...])[0]
         return [shards[i] for i in range(self.k)] + list(parity)
 
-    def _device(self, nbytes: int, shard_len: int):
-        """The device codec to use for this dispatch, or None for host."""
+    def _device(self, nbytes: int, shard_len: int, dispatch: bool = False):
+        """The device codec to use for this dispatch, or None for host.
+        `dispatch`: the caller is about to code these bytes (and does
+        not only ask where they would go)."""
         if self.m == 0 or self.backend == "host":
             return None
         if self.backend == "mesh":
@@ -586,13 +708,29 @@ class Erasure:
         # cannot amortise a round trip, and every distinct shard length
         # is another compile.  (Under "auto" DEVICE_MIN_BYTES already
         # keeps those on the host; this makes "tpu" agree.)
+        # And only a geometry whose programs are there (compiled and
+        # self-tested, at boot or by the warm-up thread): until then
+        # the host codec, never a compile inside a request.
         if shard_len != self.shard_size:
             return None
-        if self.backend == "tpu":
-            return _DeviceCodec.get(self.k, self.m, probe=False)
-        if nbytes < DEVICE_MIN_BYTES:
+        auto = self.backend == "auto"
+        if auto and nbytes < DEVICE_MIN_BYTES:
             return None
-        return _DeviceCodec.get(self.k, self.m)
+        return _DeviceCodec.ready(self.k, self.m, probe=auto,
+                                  nbytes=nbytes if dispatch else 0)
+
+    def warm(self) -> None:
+        """Ask for this geometry's device programs ahead of its first
+        dispatch; nothing where a full batch does not go to the single
+        chip, or where they are there or asked for."""
+        self._device(self.block_size * DEVICE_BATCH_BLOCKS, self.shard_size)
+
+    def _dispatch_codec(self, batch: np.ndarray):
+        """`_device` for the one dispatch that codes `batch` now,
+        counted under the backend it goes to."""
+        dev = self._device(batch.nbytes, batch.shape[2], dispatch=True)
+        _count(_backend_name(dev), batch.nbytes)
+        return dev
 
     # -- batched cross-request dispatch (erasure/batcher.py, ISSUE 11) ------
     def _batcher(self):
@@ -640,8 +778,7 @@ class Erasure:
         actual dispatch; the batcher feeds MERGED cross-request batches
         through here, so `_device` prices the fused size (small
         per-request dispatches coalesce their way onto the device)."""
-        dev = self._device(batch.nbytes, batch.shape[2])
-        _count(_backend_name(dev), batch.nbytes)
+        dev = self._dispatch_codec(batch)
         if dev is not None:
             return _on_device(dev, batch, self.m, dev.encode)()
         with stagestats.timed("host_codec", batch.nbytes):
@@ -687,8 +824,7 @@ class Erasure:
         if routed is not None:
             return routed
         b, k, s = batch.shape
-        dev = self._device(batch.nbytes, s)
-        _count(_backend_name(dev), batch.nbytes)
+        dev = self._dispatch_codec(batch)
         if dev is not None:
             t0 = time.perf_counter()
             resolve = _on_device(dev, batch, self.m, dev.encode)
@@ -732,8 +868,7 @@ class Erasure:
 
     def _reconstruct_shards_raw(self, batch: np.ndarray, available: tuple,
                                 wanted: tuple) -> np.ndarray:
-        dev = self._device(batch.nbytes, batch.shape[2])
-        _count(_backend_name(dev), batch.nbytes)
+        dev = self._dispatch_codec(batch)
         if dev is not None:
             return _on_device(
                 dev, batch, len(wanted),

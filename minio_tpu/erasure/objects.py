@@ -473,6 +473,10 @@ class ErasureObjects:
         # bloom filter so clean buckets skip re-walks (reference NSUpdated
         # feeding dataUpdateTracker, cmd/data-update-tracker.go:59)
         self.ns_updated = None
+        # how many of the drives the set last found online (a PUT's pass
+        # over them): a change asks for the device programs of the
+        # geometry the next PUT writes
+        self._online = n
 
     # ------------------------------------------------------------------ util
     @property
@@ -480,7 +484,42 @@ class ErasureObjects:
         return len(self.disks)
 
     def _online_disks(self) -> list[StorageAPI | None]:
-        return [d if d is not None and d.is_online() else None for d in self.disks]
+        disks = [d if d is not None and d.is_online() else None
+                 for d in self.disks]
+        self._saw_online(sum(d is not None for d in disks))
+        return disks
+
+    def _saw_online(self, online: int) -> None:
+        """A write's pass over the drives found `online` of them
+        present.  Where that is another count than the last pass found,
+        the geometry the set's PUTs are written at is another too
+        (`_write_geometry`), and its device programs are asked for now,
+        off any request's thread, for either storage class: by the time
+        a PUT dispatches they may be there, and until they are the host
+        codec does the work (coding._DeviceCodec.ready).  Reads do not
+        ask: a node that only serves GETs with drives away would compile
+        a geometry it never writes, and a cold compile beside twenty
+        busy streams cost them 5-12% for as long as it ran (PERF.md
+        section 6, PR 35)."""
+        if online == self._online:
+            return
+        self._online = online
+        n = len(self.disks)
+        for sc in ("STANDARD", "REDUCED_REDUNDANCY"):
+            k, m = self._write_geometry(
+                self._parity_for(PutObjectOptions(storage_class=sc)),
+                n - online)
+            if m and online >= k:
+                Erasure(k, m, BLOCK_SIZE_V2, set_id=self.set_index).warm()
+
+    def _write_geometry(self, parity: int, offline: int) -> tuple[int, int]:
+        """(k, m) of a PUT at `parity` with `offline` of the set's
+        drives away: the parity upgrade of degraded writes
+        (cmd/erasure-object.go:770-805)."""
+        n = len(self.disks)
+        if offline > 0 and parity < n // 2:
+            parity = min(n // 2, parity + offline)
+        return n - parity, parity
 
     def _shuffled_disks(self, obj: str) -> list[StorageAPI | None]:
         """Order drives by the object's hashOrder distribution
@@ -617,12 +656,8 @@ class ErasureObjects:
         opts = opts or PutObjectOptions()
         disks, dist = self._shuffled_disks(obj)
         n = len(disks)
-        parity = self._parity_for(opts)
         offline = sum(1 for d in disks if d is None)
-        # parity upgrade on degraded writes (cmd/erasure-object.go:770-805)
-        if offline > 0 and parity < n // 2:
-            parity = min(n // 2, parity + offline)
-        k = n - parity
+        k, parity = self._write_geometry(self._parity_for(opts), offline)
         write_quorum = k + 1 if k == parity else k
         if n - offline < write_quorum:
             raise errors.ErasureWriteQuorum(

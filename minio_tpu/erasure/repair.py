@@ -465,9 +465,7 @@ def _dispatch_raw(e, src: np.ndarray, helpers: tuple[int, ...],
     the configured codec backend: mesh/device codecs for large batches
     (matrices device-resident via ops/residency.py), the cached
     dual-codeword row matmul on host — no per-dispatch Gauss-Jordan."""
-    blen = src.shape[2]
-    dev = e._device(src.nbytes, blen)
-    coding_mod._count(coding_mod._backend_name(dev), src.nbytes)
+    dev = e._dispatch_codec(src)
     if dev is not None:
         return coding_mod._on_device(
             dev, src, len(lost), lambda shards, **kw: dev.reconstruct(

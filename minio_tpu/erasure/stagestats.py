@@ -95,6 +95,17 @@ STAGES = (
     # seconds in XLA compilation (counter only: benchmark/trace.py counts
     # every host span with "compile" in its name as a compilation)
     "compile",
+    # of those, the seconds of a compilation that ran on any thread but
+    # a device self-test's (boot's, the warm-up thread's): what a
+    # request waited for.  0 is the only sound reading.  Counter only
+    "compile_wait",
+    # bytes a dispatch coded on the host codec only because its
+    # geometry's device programs were not there yet
+    # (erasure/coding.py _DeviceCodec.ready): the upgraded parity of a
+    # PUT to a set that has just lost drives, until the background
+    # self-test has passed.  Counter only: 0 in a window that began
+    # ready
+    "warming",
 )
 PARENTS = frozenset(("encode", "decode"))
 

@@ -107,14 +107,19 @@ def _count_compile_seconds() -> None:
     "compile"}`): a batch shape that compiles inside a request is
     seconds of a PUT an operator could not otherwise see.  JAX reports
     the backend's compile, or the persistent cache's answer in its
-    place, per program."""
+    place, per program, on the thread that compiled: outside a device
+    self-test (boot's, the warm-up thread's) that thread is a
+    request's, and the stage `compile_wait` takes the seconds too."""
     import jax.monitoring
 
     from minio_tpu.erasure import stagestats
+    from minio_tpu.erasure.coding import _DeviceCodec
 
     def fold(event: str, seconds: float, **_kw) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             stagestats.add("compile", seconds)
+            if not _DeviceCodec.self_testing():
+                stagestats.add("compile_wait", seconds)
 
     jax.monitoring.register_event_duration_secs_listener(fold)
 
@@ -137,16 +142,20 @@ def _init_device(backend: str):
 
 
 def _boot_erasure_plane(pools) -> dict:
-    """Resolve, per set geometry the deployment can write (STANDARD and
+    """Resolve, per geometry a healthy set writes (STANDARD and
     REDUCED_REDUNDANCY parity of every pool), where steady-state batches
     are coded, and run the device self-test + warm-up for each geometry
-    that reaches the chip.  Returns {"geometry": {"k+m": where},
-    "batchSizes": the block counts the device programs were compiled at
-    (what any dispatch of the single chip is carried at),
-    "deviceSelfTestSeconds": float} for the banner and admin info."""
+    that reaches the chip, here, before the node serves.  A geometry
+    that only a set with drives away writes (the upgraded parity of a
+    degraded PUT: seven more on sixteen drives) is warmed when the set
+    first sees drives missing, on a background thread, and coded by the
+    host codec until then (coding._DeviceCodec.ready).  Returns
+    {"geometry": {"k+m": where}, "batchSizes": the block counts the
+    device programs are compiled at (what any dispatch of the single
+    chip is carried at), "deviceSelfTestSeconds": float} for the banner
+    and admin info, which adds the geometries warmed later."""
     from minio_tpu.erasure import coding
     from minio_tpu.erasure.objects import PutObjectOptions
-    from minio_tpu.selftest import device_self_test
 
     geoms = set()
     for pool in pools.pools:
@@ -159,7 +168,7 @@ def _boot_erasure_plane(pools) -> dict:
     for k, m in sorted(geoms):
         where[f"{k}+{m}"] = coding.steady_state_backend(k, m)
         if where[f"{k}+{m}"] == "device":
-            seconds += device_self_test(k, m, coding.BLOCK_SIZE_V2)
+            seconds += coding._DeviceCodec.self_test(k, m)
     return {"geometry": where,
             "batchSizes": list(coding.DEVICE_BATCH_SIZES),
             "deviceSelfTestSeconds": round(seconds, 3)}
@@ -354,7 +363,10 @@ def main(argv=None) -> int:
         f"host codec "
         f"{'native AVX2' if host_codec.available() else 'numpy fallback'}; "
         f"device self-test + warm-up {boot['deviceSelfTestSeconds']} s "
-        f"at batches of {boot['batchSizes']} blocks",
+        f"at batches of {boot['batchSizes']} blocks (the geometries a "
+        f"healthy set writes; any other, a degraded PUT's raised parity, "
+        f"is warmed in the background when a set first misses drives and "
+        f"coded on the host until then)",
         file=sys.stderr,
     )
     if node.distributed:

@@ -1209,7 +1209,12 @@ class AdminMixin:
         # and warm-up seconds); absent under an in-process harness
         boot = getattr(self, "erasure_boot", None)
         if boot is not None:
-            out["boot"] = boot
+            # and what became of the geometries asked for since (a set
+            # that lost drives writes at a raised parity): `warming`
+            # while their dispatches are on the host codec, then
+            # `device`, or `failed`
+            out["boot"] = {**boot, "geometry": {
+                **boot["geometry"], **ec.geometry_states()}}
         return out
 
     # ---------------------------------------------------------------- info

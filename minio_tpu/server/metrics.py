@@ -199,6 +199,18 @@ class MetricsMixin:
                            f"{lbl} {st['bytes']}")
             g("\n".join(bl) + "\n")
             g("\n".join(byl) + "\n")
+            states = ec.geometry_states()
+            if states:
+                rl = ["# HELP minio_erasure_geometry_ready 1 once the "
+                      "geometry's device programs are compiled and "
+                      "self-tested, 0 while its dispatches are on the "
+                      "host codec (warming, failed, no device)",
+                      "# TYPE minio_erasure_geometry_ready gauge"]
+                rl += ["minio_erasure_geometry_ready"
+                       f"{_fmt_labels(('geometry',), (geom,))} "
+                       f"{int(state == 'device')}"
+                       for geom, state in states.items()]
+                g("\n".join(rl) + "\n")
         except Exception:
             pass
 

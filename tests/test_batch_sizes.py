@@ -19,6 +19,10 @@ import pytest
 from minio_tpu.erasure import bitrot, coding, stagestats
 from minio_tpu.erasure.coding import Erasure
 from minio_tpu.ops import gf256
+from tests import device_codec
+from tests.device_codec import Seen as _Seen
+from tests.device_codec import bytes_of as _bytes_of
+from tests.device_codec import oracle_parity as _oracle_parity
 
 BS = 1 << 16
 GEOMETRIES = [(2, 2), (8, 4), (12, 4)]
@@ -61,6 +65,7 @@ class _Booted:
         coding._DeviceCodec._cache[(k, m)] = (self.codec, None)
         _Compiles.listen()
         selftest.device_self_test(k, m, BS)
+        device_codec.plant(k, m, self.codec, None)  # passed: ready
         self.jits = (rs_pallas._coding_call_bytes, rs_pallas._coding_call)
         self.cache_sizes = [f._cache_size() for f in self.jits]
         self.compiles = _Compiles.n
@@ -71,32 +76,7 @@ class _Booted:
         assert _Compiles.n == self.compiles
 
     def close(self):
-        coding._DeviceCodec._cache.pop((self.k, self.m), None)
-
-
-class _Seen:
-    """The device codec, keeping the shape of every batch it was given
-    and what its blocks beyond the real ones held."""
-
-    backend = "device"
-
-    def __init__(self, inner):
-        self.inner = inner
-        self.shapes = []
-        self.beyond = []
-
-    def _note(self, batch, blocks):
-        self.shapes.append((batch.shape[0], blocks))
-        if blocks is not None:
-            self.beyond.append(np.array(batch[blocks:]))
-
-    def encode(self, batch, blocks=None):
-        self._note(batch, blocks)
-        return self.inner.encode(batch, blocks=blocks)
-
-    def reconstruct(self, batch, available, wanted, blocks=None):
-        self._note(batch, blocks)
-        return self.inner.reconstruct(batch, available, wanted, blocks=blocks)
+        device_codec.unplant(self.k, self.m)
 
 
 @pytest.fixture(scope="module", params=GEOMETRIES, ids=_ids)
@@ -104,14 +84,6 @@ def booted(request):
     b = _Booted(*request.param)
     yield b
     b.close()
-
-
-def _bytes_of(stage):
-    return stagestats.snapshot()[stage]["bytes"]
-
-
-def _oracle_parity(batch, m):
-    return np.stack([gf256.encode_np(block, m) for block in batch])
 
 
 @pytest.mark.parametrize("what", ["encode", "reconstruct"])

@@ -86,6 +86,7 @@ def test_only_full_width_shards_leave_the_host(monkeypatch):
         backend = "device"
 
     monkeypatch.setitem(coding._DeviceCodec._cache, (8, 4), (Fake(), True))
+    monkeypatch.setitem(coding._DeviceCodec._ready, (8, 4), Fake())
     e = Erasure(8, 4, backend="tpu")
     assert e._device(32 << 20, e.shard_size) is not None
     assert e._device(64 << 10, 8192) is None
@@ -93,6 +94,7 @@ def test_only_full_width_shards_leave_the_host(monkeypatch):
     assert coding.steady_state_backend(4, 2) == "host"  # auto, no TPU
     for k, m in ((12, 4), (14, 2), (10, 2)):
         monkeypatch.setitem(coding._DeviceCodec._cache, (k, m), (Fake(), True))
+        monkeypatch.setitem(coding._DeviceCodec._ready, (k, m), Fake())
         odd = Erasure(k, m, backend="tpu")
         assert odd.shard_size % 8192 != 0
         assert odd._device(32 << 20, odd.shard_size) is not None

@@ -19,6 +19,7 @@ import pytest
 from minio_tpu.erasure import bitrot
 from minio_tpu.erasure.coding import Erasure
 from minio_tpu.storage import errors
+from tests import device_codec
 
 
 def _roundtrip(tmp_path, k, m, size, block_size=1 << 20, kill=(), corrupt=()):
@@ -160,7 +161,7 @@ def test_device_codec_stream_roundtrip(tmp_path):
 
     k, m, bs = 8, 4, 1 << 20  # shard 128 KiB: satisfies the 8192-alignment gate
     codec = _CountingCodec(rs_pallas.PallasRSCodec(k, m, interpret=True))
-    coding._DeviceCodec._cache[(k, m)] = (codec, True)
+    device_codec.plant(k, m, codec)
     try:
         e = Erasure(k, m, bs, backend="tpu")
         size = 2 * bs + 12345  # 2 full blocks through the kernel + host tail
@@ -186,7 +187,7 @@ def test_device_codec_stream_roundtrip(tmp_path):
         assert out.getvalue() == payload
         assert codec.reconstructs >= 1
     finally:
-        coding._DeviceCodec._cache.pop((k, m), None)
+        device_codec.unplant(k, m)
 
 
 def test_device_codec_stream_at_12_4(tmp_path):
@@ -200,7 +201,7 @@ def test_device_codec_stream_at_12_4(tmp_path):
 
     k, m, bs = 12, 4, 1 << 20
     codec = _CountingCodec(rs_pallas.PallasRSCodec(k, m, interpret=True))
-    coding._DeviceCodec._cache[(k, m)] = (codec, True)
+    device_codec.plant(k, m, codec)
     try:
         e = Erasure(k, m, bs, backend="tpu")
         assert e.shard_size == 87382
@@ -283,7 +284,7 @@ def test_device_codec_stream_at_12_4(tmp_path):
         for i in stale:
             assert paths[i].read_bytes() == originals[i], f"shard {i}"
     finally:
-        coding._DeviceCodec._cache.pop((k, m), None)
+        device_codec.unplant(k, m)
 
 
 # -- the staged read of a degraded group (ISSUE 29) --------------------------
@@ -458,8 +459,8 @@ def test_bad_frame_in_a_staged_group_hands_its_column_to_a_spare(
 
     k, m = 2, 2
     if codec == "device":
-        coding._DeviceCodec._cache[(k, m)] = (
-            rs_pallas.PallasRSCodec(k, m, interpret=True), True)
+        device_codec.plant(
+            k, m, rs_pallas.PallasRSCodec(k, m, interpret=True))
     try:
         e, paths, payload = _stored(
             tmp_path, k, m, backend="tpu" if codec == "device" else "host")
@@ -482,7 +483,7 @@ def test_bad_frame_in_a_staged_group_hands_its_column_to_a_spare(
         assert broken == {1}
         assert seen == [((1, 2), (0,)), ((3, 2), (0, 1)), ((2, 3), (0, 1))]
     finally:
-        coding._DeviceCodec._cache.pop((k, m), None)
+        device_codec.unplant(k, m)
 
 
 def test_group_that_turns_degraded_after_its_reads_began(tmp_path,

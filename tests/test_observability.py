@@ -325,6 +325,7 @@ class TestCodecBackendObservability:
         monkeypatch.setenv("MINIO_TPU_ERASURE_BACKEND", "auto")
         stub = StubDeviceCodec(2, 2)
         monkeypatch.setitem(ec._DeviceCodec._cache, (2, 2), (stub, True))
+        monkeypatch.setitem(ec._DeviceCodec._ready, (2, 2), stub)
 
         disks = [LocalStorage(str(tmp_path / f"d{i}")) for i in range(4)]
         for d in disks:

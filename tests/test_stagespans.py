@@ -28,6 +28,7 @@ from minio_tpu.erasure.objects import ErasureObjects
 from minio_tpu.storage.instrumented import instrument
 from minio_tpu.storage.local import LocalStorage
 from minio_tpu.utils import tracing
+from tests import device_codec
 
 # leaves a PUT + degraded GET reach on the host codec, and the three more
 # of a device dispatch
@@ -49,8 +50,7 @@ def _device_stream(tmp_path) -> dict:
     from minio_tpu.ops import rs_pallas
 
     k, m, bs = 2, 2, 1 << 20
-    coding._DeviceCodec._cache[(k, m)] = (
-        rs_pallas.PallasRSCodec(k, m, interpret=True), True)
+    device_codec.plant(k, m, rs_pallas.PallasRSCodec(k, m, interpret=True))
     try:
         e = coding.Erasure(k, m, bs, backend="tpu")
         size = 2 * bs
@@ -75,7 +75,7 @@ def _device_stream(tmp_path) -> dict:
         assert out.getvalue() == data
         return _delta(before, after)
     finally:
-        coding._DeviceCodec._cache.pop((k, m), None)
+        device_codec.unplant(k, m)
 
 
 @pytest.fixture(scope="module")

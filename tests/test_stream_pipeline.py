@@ -52,7 +52,7 @@ class _RecordingCodec:
 
 def _patched_erasure(codec, block_size=1 << 18):
     e = Erasure(K, M, block_size, backend="host")
-    e._device = lambda nbytes, shard_len: codec
+    e._device = lambda nbytes, shard_len, dispatch=False: codec
     return e
 
 
