@@ -52,6 +52,8 @@ from minio_tpu.utils import tracing
 STAGES = (
     # PUT: body read, etag fold, erasure encode (parent), frame hash,
     # shard write; GET: decode (parent), hand-over to the HTTP front
+    # (`respond` is the decode thread's put into the sink's queue, not
+    # the response: that is `send`)
     "read", "etag", "encode", "hash", "write", "decode", "respond",
     # GET / heal: the stream's thread waiting for its k shards; in the
     # I/O pool, a drive's bytes arriving and their frame check
@@ -60,6 +62,13 @@ STAGES = (
     # to the device, the jit call until it returns, waiting for the
     # device plus read-back; or the host codec's own compute
     "assemble", "h2d", "launch", "fetch", "host_codec",
+    # a response body's piece written to the connection's socket by the
+    # executor thread that pulled it (server/app.py _BodySender), once a
+    # piece: the copy into the socket and the waits for the reader.
+    # Bytes the event loop wrote (TLS, chunked, a reader that stalled)
+    # book nothing: over `respond`'s bytes 1 where every served byte
+    # left from a worker
+    "send",
     # bytes a drive's read put straight into a dispatch's staging arena
     # (erasure/bitrot.py read_blocks(out=)), so that no host copy stands
     # between the read and the device (counter only, no seconds of its

@@ -6,6 +6,8 @@ Provides:
   klauspost/reedsolomon's AVX2 assembly).
 - hh256 / HH256: bit-exact HighwayHash-256 for bitrot checksums
   (reference: minio/highwayhash used at cmd/bitrot.go:55).
+- sock_send: a response body's piece written to its socket without the
+  interpreter lock (server/app.py _BodySender).
 
 The library is built from the committed sources on the machine that
 loads it, on first use, into a file named by a content hash of those
@@ -118,6 +120,11 @@ def _load():
             ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t,
             ctypes.c_size_t, ctypes.c_size_t, ctypes.c_char_p,
         ]
+        lib.sock_send.restype = ctypes.c_size_t
+        lib.sock_send.argtypes = [
+            ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_int),
+        ]
         _lib = lib
         return _lib
 
@@ -128,6 +135,27 @@ def available() -> bool:
 
 def _as_c(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.c_char_p)
+
+
+def sock_send(fd: int, data, stall_ms: int) -> int:
+    """Write `data` (bytes-like, contiguous) to the non-blocking socket
+    `fd` with the interpreter lock let go for the whole of it: `send`
+    until it is out, `poll(POLLOUT)` where the socket is full.  Returns
+    the bytes the socket took: fewer than `len(data)` where it took
+    nothing for `stall_ms`.  Raises OSError (EPIPE, ECONNRESET, ...)
+    where the connection failed before anything of `data` was sent or
+    after a part of it; the part is lost to the caller, who gives the
+    connection up.  Only where `available()`."""
+    lib = _load()
+    # read-only buffers too (bytes): no copy, and `arr` keeps `data`
+    # alive while native code reads it
+    arr = np.frombuffer(data, dtype=np.uint8)
+    err = ctypes.c_int(0)
+    sent = lib.sock_send(fd, arr.ctypes.data, arr.size, stall_ms,
+                         ctypes.byref(err))
+    if err.value:
+        raise OSError(err.value, os.strerror(err.value))
+    return sent
 
 
 # Column tile for the pure-numpy GF(2^8) fallback matmul: one tile of

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import asyncio
 import base64
+import functools
 import hashlib
 import io
 import os
@@ -32,6 +33,7 @@ from aiohttp import web
 from minio_tpu.storage import errors as st
 from minio_tpu.erasure import stagestats
 from minio_tpu.erasure.objects import PutObjectOptions
+from minio_tpu.ops import host
 from . import sigv4
 from .bucket_meta import BucketMetaHandlers
 from .object_extras import (
@@ -326,6 +328,123 @@ class _QueuePipeReader(io.RawIOBase):
         return out
 
 
+# how long a response's executor thread waits on a socket that takes
+# nothing before it gives the rest of the piece back to the event loop
+_SEND_STALL_MS = 100
+
+
+class _BodySender:
+    """The executor's side of a response body (`S3Server._pump_stream`).
+
+    `run` is the job: it pulls the iterator and, where the connection is
+    a plain socket and the payload writer neither chunks nor compresses,
+    writes each piece to the socket itself, from the thread that pulled
+    it, in one native call (`ops/host.py` `sock_send`: `send` until the
+    piece is out, `poll` where the socket is full).  The call lets go of
+    the interpreter lock once a piece, so every stream's copy into its
+    socket runs on its own thread and a 32 MiB group costs one lock
+    hand-over where the event loop took one per partial send, about a
+    hundred.  Anywhere else (TLS, chunked, compressed, no raw socket, no
+    native library) the job pulls and the loop writes.
+
+    One owner of the socket at a time: a job writes only while the
+    transport's own buffer is empty (`settled`), and the loop writes
+    only what a job handed back, while no job runs.  What a job sent it
+    books into the writer's `length` and `output_size`, so `write_eof`,
+    keep-alive and the access log read as if the loop had written it.
+
+    A job works on a duplicate of the descriptor, made on the loop
+    (`settled`) and closed by the job: the loop may close the
+    transport's socket while the job is inside `write`, and a number it
+    closed may be another connection's by then."""
+
+    def __init__(self, it, resp: web.StreamResponse, writer, debit,
+                 native: bool):
+        self.it = it
+        self.writer = writer  # aiohttp's StreamWriter of `resp`
+        self.debit = debit  # bytes -> seconds of pacing the tenant owes
+        self.stop = False   # the pump is leaving: a job ends at once
+        self.fd = -1        # the duplicate the next job takes over
+        tr = writer.transport
+        self.direct = (
+            tr is not None
+            and tr.get_extra_info("socket") is not None
+            and tr.get_extra_info("sslcontext") is None
+            and not writer.chunked and not resp.compression
+            and native)
+
+    async def settled(self) -> None:
+        """Event loop, before a job: the head is on the wire and the
+        transport holds nothing, so that what the job writes follows
+        what the loop wrote; then the job's descriptor.  `drain` alone
+        returns at the low-water mark; with the mark at 0 it returns
+        once the buffer is empty."""
+        tr = self.writer.transport
+        if not self.direct or tr is None:
+            return
+        self.writer.send_headers()
+        if tr.get_write_buffer_size():
+            low, high = tr.get_write_buffer_limits()
+            tr.set_write_buffer_limits(high=0)
+            try:
+                await self.writer.drain()
+            finally:
+                tr.set_write_buffer_limits(high=high, low=low)
+        sock = tr.get_extra_info("socket")
+        if not tr.is_closing() and sock.fileno() >= 0:
+            self.fd = os.dup(sock.fileno())
+
+    def close(self) -> None:
+        """Event loop, once no job runs: a duplicate no job took."""
+        fd, self.fd = self.fd, -1
+        if fd >= 0:
+            os.close(fd)
+
+    def run(self):
+        """One executor job.  Returns `(pause_s, piece)` for the loop to
+        sleep, write and come back, or None at the iterator's end.  The
+        loop gets a piece where it has to write all of them, where the
+        tenant owes pacing for it (QoS: a paced piece is a slow one),
+        and the unsent rest of one that the socket stopped taking: no
+        executor thread waits for a client that does not read."""
+        fd, self.fd = self.fd, -1
+        try:
+            for chunk in self.it:
+                view = memoryview(chunk)
+                if view.nbytes != len(view):
+                    view = view.cast("B")
+                if not len(view):
+                    continue
+                pause = self.debit(len(view))
+                if fd < 0 or pause > 0:
+                    return pause, view
+                rest = self._send(fd, view)
+                if rest is not None:
+                    return 0.0, rest
+            return None
+        finally:
+            if fd >= 0:
+                os.close(fd)
+
+    def _send(self, fd: int, view: memoryview) -> memoryview | None:
+        """Write `view` to the socket; the rest of it where the socket
+        took nothing for `_SEND_STALL_MS`.  A connection that is gone
+        raises, as a write on a closing transport does."""
+        writer = self.writer
+        if writer.length is not None:
+            # as StreamWriter.write: never past Content-Length
+            view = view[:writer.length]
+        tr = writer.transport
+        if self.stop or tr is None or tr.is_closing():
+            raise ConnectionResetError("Cannot write to closing transport")
+        with stagestats.timed("send") as leaf:
+            leaf.nbytes = sent = host.sock_send(fd, view, _SEND_STALL_MS)
+        writer.output_size += sent
+        if writer.length is not None:
+            writer.length -= sent
+        return view[sent:] if sent < len(view) else None
+
+
 class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                MetricsMixin, ZipExtractMixin):
     def __init__(self, object_layer, access_key: str = "minioadmin",
@@ -455,6 +574,11 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         self.executor = cf.ThreadPoolExecutor(
             max_workers=max_concurrency + 4, thread_name_prefix="s3-api"
         )
+        # a response body goes to its socket from the executor thread
+        # that pulled it only through the native library (_BodySender);
+        # asked here, where loading it (its first use builds it) holds
+        # no event loop
+        self.native_send = host.available()
         self.trace = PubSub()
         from minio_tpu.services.site import SiteReplicationSys
 
@@ -744,36 +868,51 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
                                           lambda: ctx.run(nobudget))
 
     async def _pump_stream(self, resp: web.StreamResponse, stream,
-                           request: web.Request | None = None) -> None:
-        """Stream an iterator's chunks to the response with one chunk of
-        read-ahead: the executor thread pulls chunk N+1 (shard read +
-        verify + decode) while the event loop awaits the socket write of
-        chunk N.  Lock-step produce/consume serialized the two — the
-        decode pipeline sat idle for every client-write round trip
-        (ISSUE 5 overlapped GET).  With `request` and QoS on, each
-        chunk is metered against the tenant's egress bandwidth bucket
-        (pacing overlaps the prefetch, not the decode)."""
-        it = iter(stream)
-        nxt = asyncio.ensure_future(self._run_nobudget(next, it, None))
+                           request: web.Request) -> None:
+        """Stream an iterator's chunks to the prepared response: one
+        executor job a response pulls each chunk and writes it to the
+        connection's socket from that thread, and the event loop awaits
+        the job alone (`_BodySender`).  The producer runs ahead of the
+        job by itself (a decode thread fills `_IterSink`, up to 8
+        groups), so the socket's round trips never hold the decode
+        pipeline (ISSUE 5 overlapped GET).  The loop writes what a job
+        hands back: the rest of a piece a slow reader did not take, a
+        piece its tenant is paced for (QoS: every chunk is debited
+        against the tenant's egress bucket), and every piece of a
+        response that cannot go direct (TLS, chunked, compressed),
+        where the next job pulls chunk N+1 while the loop writes N."""
+        send = _BodySender(
+            iter(stream), resp, await resp.prepare(request),
+            functools.partial(self._qos_debit, request, direction="out"),
+            self.native_send)
+        job = None
         try:
             while True:
-                chunk = await nxt
-                nxt = None
-                if chunk is None:
-                    break
-                nxt = asyncio.ensure_future(self._run_nobudget(next, it, None))
-                if request is not None:
-                    await self._qos_throttle(request, len(chunk), "out")
-                await resp.write(chunk)
+                if job is None:
+                    await send.settled()
+                    job = asyncio.ensure_future(self._run_nobudget(send.run))
+                # shielded: a cancelled pump leaves the thread running,
+                # and waits for it below before anyone closes the stream
+                back = await asyncio.shield(job)
+                job = None
+                if back is None:
+                    return
+                pause, piece = back
+                if not send.direct:
+                    job = asyncio.ensure_future(self._run_nobudget(send.run))
+                if pause > 0:
+                    await asyncio.sleep(pause)
+                await resp.write(piece)
         finally:
-            if nxt is not None:
-                # a client disconnect mid-write leaves one prefetch in
-                # flight; drain it so the generator is not left executing
-                # when the caller's cleanup closes it
+            send.stop = True
+            if job is not None:
+                # a job may still be inside the iterator or the socket:
+                # the caller's cleanup closes both
                 try:
-                    await nxt
+                    await job
                 except Exception:
                     pass
+            send.close()
 
     async def _feed(self, pipe: "_QueuePipeReader", item, task) -> None:
         """Non-blocking queue feed from the event loop; aborts if the
@@ -983,17 +1122,25 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
             if self.controller is not None:
                 self.controller.start()
 
-    async def _qos_throttle(self, request: web.Request, n: int,
-                            direction: str) -> None:
-        """Meter `n` data-plane bytes (PUT-body ingest direction="in",
-        GET streaming direction="out") against the request tenant's
-        bandwidth bucket; paces with asyncio.sleep so a throttled
-        tenant never blocks the event loop.  No-op with QoS off."""
+    def _qos_debit(self, request: web.Request, n: int,
+                   direction: str) -> float:
+        """Charge `n` data-plane bytes (PUT-body ingest direction="in",
+        GET streaming direction="out") to the request tenant's
+        bandwidth bucket: the seconds of pacing it owes.  0.0 with QoS
+        off.  Any thread."""
         qos = self.qos
         if qos is None or n <= 0:
-            return
+            return 0.0
         tenant = request.get("qosTenant") or qos.classify(request)
-        await qos.throttle(tenant, n, direction)
+        return qos.bw_wait(tenant, n, direction)
+
+    async def _qos_throttle(self, request: web.Request, n: int,
+                            direction: str) -> None:
+        """`_qos_debit` for the event loop: paces with asyncio.sleep so
+        a throttled tenant never blocks it."""
+        wait = self._qos_debit(request, n, direction)
+        if wait > 0:
+            await asyncio.sleep(wait)
 
     def _request_budget(self, request: web.Request):
         """Deadline budget for one request: `api.requests_deadline`

@@ -692,11 +692,6 @@ class QosPlane:
         self.monitor.record(tenant, direction, n)
         return bw.debit(n) if bw is not None else 0.0
 
-    async def throttle(self, tenant: str, n: int, direction: str) -> None:
-        wait = self.bw_wait(tenant, n, direction)
-        if wait > 0:
-            await asyncio.sleep(wait)
-
     # -- observability -------------------------------------------------------
     def stats(self) -> dict:
         """Per-tenant live stats + plane totals (metrics + admin)."""

@@ -36,6 +36,9 @@ HOST_PUT = ("read", "etag", "host_codec", "hash", "write", "commit")
 HOST_GET = ("meta_read", "read_wait", "shard_read", "verify", "assemble",
             "host_codec", "respond")
 DEVICE = ("h2d", "launch", "fetch")
+# and the one more of a GET that is served: its body written to the
+# connection's socket by the executor thread that pulled it (ISSUE 36)
+SERVED = ("send",)
 OBJECT_BYTES = 16 << 20
 
 
@@ -78,6 +81,31 @@ def _device_stream(tmp_path) -> dict:
         device_codec.unplant(k, m)
 
 
+def _served_get(root) -> dict:
+    """One object of two full blocks and a tail PUT and fetched over
+    HTTP -> the GET's captured request trace."""
+    from tests.s3_harness import S3TestServer
+
+    body = np.random.default_rng(36).integers(
+        0, 256, (2 << 20) + 36, dtype=np.uint8).tobytes()
+    srv = S3TestServer(str(root))
+    try:
+        assert srv.request("PUT", "/bkt").status == 200
+        assert srv.request("PUT", "/bkt/served", data=body).status == 200
+        tracing.store.clear()
+        assert srv.request("GET", "/bkt/served").body == body
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            docs = [d for d in tracing.store.snapshot()
+                    if "dp.respond" in {s["name"] for s in d["spans"]}]
+            if docs:
+                return docs[0]
+            time.sleep(0.02)
+        raise AssertionError("the served GET's trace was not captured")
+    finally:
+        srv.close()
+
+
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """The run itself, once -> what the tests read."""
@@ -113,6 +141,7 @@ def traced(tmp_path_factory):
         get_doc = tracing.end_request(root)
         get_stages = _delta(before, stagestats.snapshot())
         device_stages = _device_stream(tmp)
+        served_doc = _served_get(tmp / "served")
     finally:
         jax.profiler.stop_trace()
         mp.undo()
@@ -121,11 +150,13 @@ def traced(tmp_path_factory):
         bench_trace.find_xplane(str(tmp / "trace")))
     return types.SimpleNamespace(
         host_spans={name for name, _, _ in events["host"]},
-        put_doc=put_doc, get_doc=get_doc, get_stages=get_stages,
-        device_stages=device_stages, total=stagestats.snapshot())
+        put_doc=put_doc, get_doc=get_doc, served_doc=served_doc,
+        get_stages=get_stages, device_stages=device_stages,
+        total=stagestats.snapshot())
 
 
-@pytest.mark.parametrize("stage", sorted(set(HOST_PUT + HOST_GET + DEVICE)))
+@pytest.mark.parametrize(
+    "stage", sorted(set(HOST_PUT + HOST_GET + DEVICE + SERVED)))
 def test_leaf_lies_in_the_profile_and_counts(traced, stage):
     assert f"dp.{stage}" in traced.host_spans
     row = traced.total[stage]
@@ -175,8 +206,12 @@ def test_owning_threads_leaves_close_on_decode(traced, path, leaves):
         assert stages["host_codec"]["seconds"] == 0
 
 
-@pytest.mark.parametrize("doc,leaves", [("put_doc", HOST_PUT),
-                                        ("get_doc", HOST_GET)])
+@pytest.mark.parametrize("doc,leaves", [
+    ("put_doc", HOST_PUT), ("get_doc", HOST_GET),
+    # over HTTP the handler's one fan-out opens the object: no
+    # host_codec on a healthy set, and the body's `send`
+    ("served_doc", ("meta_read", "read_wait", "shard_read", "verify",
+                    "assemble", "respond") + SERVED)])
 def test_captured_request_holds_leaf_spans(traced, doc, leaves):
     doc = getattr(traced, doc)
     names = {s["name"] for s in doc["spans"]}
@@ -185,6 +220,10 @@ def test_captured_request_holds_leaf_spans(traced, doc, leaves):
     # per-request seconds stay, parents among them
     assert set(leaves) <= set(doc["stages"])
     assert {"encode", "decode"} & set(doc["stages"])
+    if "send" in leaves:
+        # a worker wrote every byte: two pieces, the group and the tail
+        sends = [s for s in doc["spans"] if s["name"] == "dp.send"]
+        assert len(sends) == 2
     # the trace's start on the profiler's clock places its spans there
     assert 0 < doc["startMonotonic"] <= time.perf_counter()
     (root,) = [s for s in doc["spans"] if s["parent"] is None]
@@ -244,6 +283,13 @@ def test_compile_seconds_are_a_counter():
     assert after["wall"] == before["wall"]
 
 
+def test_send_is_a_leaf_beside_respond():
+    """`respond` is the decode thread's put into the sink's queue;
+    the response is `send` (ISSUE 36)."""
+    assert "send" in stagestats.STAGES and "send" not in stagestats.PARENTS
+    assert stagestats._SPAN_NAMES["send"] == "dp.send"
+
+
 def test_scrape_shows_the_wall_family(traced):
     from minio_tpu.server.metrics import MetricsMixin
 
@@ -254,6 +300,7 @@ def test_scrape_shows_the_wall_family(traced):
     text = MetricsMixin._render_metrics(
         types.SimpleNamespace(metrics=_Reg(), api=None))
     for family in ("seconds", "bytes", "wall_seconds"):
-        for stage in ("read_wait", "fetch", "commit", "admit", "compile"):
+        for stage in ("read_wait", "fetch", "commit", "admit", "compile",
+                      "send"):
             assert (f'minio_dataplane_stage_{family}_total'
                     f'{{stage="{stage}"}} ') in text
