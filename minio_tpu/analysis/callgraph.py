@@ -50,8 +50,8 @@ from .core import call_name, expr_source, terminal_name
 #: call names whose callable ARGUMENTS run on another thread/process —
 #: the executor hops that sever loop-reachability (and lock extent).
 HOP_CALLS = {
-    "run_in_executor", "ctx_submit", "submit", "service_thread",
-    "to_thread", "apply_async", "Thread", "Process",
+    "run_in_executor", "ctx_submit", "io_submit", "submit",
+    "service_thread", "to_thread", "apply_async", "Thread", "Process",
 }
 
 # ---------------------------------------------------------------------------

@@ -298,6 +298,23 @@ def _io_pool() -> cf.ThreadPoolExecutor:
         return _shared_pool
 
 
+def io_submit(fn, *args) -> cf.Future:
+    """`fn(*args)` on the shared I/O pool under the caller's context
+    (ctx_submit: its deadline budget and its request trace ride along),
+    with the task's wait for a pool thread booked as the stage
+    `pool_wait`: from here until its first line on a `shard-io` thread,
+    once a task.  A fan-out wider than the pool (MINIO_TPU_IO_THREADS),
+    or tasks that wait inside a pool thread for their predecessor, show
+    here and not in the stage of the work they queue for."""
+    t0 = time.perf_counter()
+
+    def run():
+        stagestats.add("pool_wait", time.perf_counter() - t0)
+        return fn(*args)
+
+    return ctx_submit(_io_pool(), run)
+
+
 # Which codec served erasure work, and how much: operators need to SEE
 # whether PUT/GET/heal bytes ran on the host AVX2 path, the single-chip
 # device path, or the mesh — the auto probe's verdict is useless if
@@ -809,10 +826,10 @@ class Erasure:
         D2H DMA, disk reads, and bitrot hashing all overlap (the
         double-buffered streaming BASELINE.md names as the hard part;
         reference overlaps via per-block goroutines,
-        cmd/erasure-encode.go:73).  Host encodes run on `pool` when one
-        is given (the AVX2 C call releases the GIL, so the encode
-        overlaps the caller's next read); without a pool they compute
-        here and resolve immediately.
+        cmd/erasure-encode.go:73).  Host encodes run on the shared I/O
+        pool (io_submit) when `pool` is given (the AVX2 C call releases
+        the GIL, so the encode overlaps the caller's next read); without
+        one they compute here and resolve immediately.
 
         With the request batcher gate on, the dispatch is handed to the
         batcher instead: the tick thread fuses it with concurrent
@@ -852,7 +869,7 @@ class Erasure:
                 # the GIL is released for the whole span
                 self._host_encode(batch[lo:hi], parity[lo:hi])
 
-            futs = [ctx_submit(pool, enc_range, lo, min(lo + step, b))
+            futs = [io_submit(enc_range, lo, min(lo + step, b))
                     for lo in range(0, b, step)]
 
             def resolve_host():
@@ -862,7 +879,7 @@ class Erasure:
 
             return resolve_host
         if pool is not None:
-            return ctx_submit(pool, self._host_encode, batch).result
+            return io_submit(self._host_encode, batch).result
         out = self._host_encode(batch)
         return lambda: out
 
@@ -1088,6 +1105,12 @@ class Erasure:
                     hfut.result()  # etag fold of this arena view is done
                 release_slot(slot)
 
+        def wait_oldest() -> None:
+            """This thread's wait for the pool's shard writes (and the
+            etag fold) of the oldest batch: read_wait's mirror."""
+            with stagestats.timed("write_wait"):
+                drain_holds(block=True)
+
         def emit_one() -> None:
             slot, batch, block_len, resolve, hfut = pending.pop(0)
             parity = resolve()
@@ -1108,13 +1131,13 @@ class Erasure:
                     for bi in range(rows.shape[0]):
                         writers[i].write(rows[bi, :shard_len])
 
-            # ctx_submit: the caller's deadline budget must ride into
+            # io_submit: the caller's deadline budget must ride into
             # the writer threads so the per-drive gates stay armed
             futs: dict[int, cf.Future] = {}
             for i in range(n):
                 if i in dead or writers[i] is None:
                     continue
-                fut = ctx_submit(pool, write_drive, i, tails.get(i))
+                fut = io_submit(write_drive, i, tails.get(i))
                 tails[i] = fut
                 futs[i] = fut
             holds.append((slot, futs, hfut))
@@ -1125,7 +1148,7 @@ class Erasure:
                 if pending:
                     emit_one()
                 elif holds:
-                    drain_holds(block=True)
+                    wait_oldest()
                     check_quorum()
                 else:  # pragma: no cover - ring accounting invariant
                     raise RuntimeError("arena ring exhausted with no "
@@ -1156,7 +1179,7 @@ class Erasure:
                 # backlog here, or a slow-but-healthy drive lets queued
                 # batches pin fresh ~32 MiB buffers without limit
                 while len(holds) > depth + 1:
-                    drain_holds(block=True)
+                    wait_oldest()
                     check_quorum()
 
         try:
@@ -1233,7 +1256,7 @@ class Erasure:
             while pending:
                 emit_one()
             while holds:
-                drain_holds(block=True)
+                wait_oldest()
             prune_dead()  # final quorum verdict, all futures resolved
             if len(free_slots) == len(slot_bufs):
                 # every batch drained and every etag fold done: no view
@@ -1274,7 +1297,7 @@ class Erasure:
 
     def _read_group(self, readers: Sequence, broken: set[int],
                     shard_off: int, read_len: int, nblocks: int,
-                    shard_len: int, pool,
+                    shard_len: int,
                     prefer: Sequence[int] | None = None,
                     rebuild: bool = False
                     ) -> tuple[dict[int, np.ndarray], np.ndarray | None]:
@@ -1339,8 +1362,8 @@ class Erasure:
         try:
             while len(got) < self.k:
                 futs = {
-                    i: ctx_submit(
-                        pool, read_one, readers[i],
+                    i: io_submit(
+                        read_one, readers[i],
                         None if arena is None else arena[:, column[i], :])
                     for i in active
                 }
@@ -1461,7 +1484,6 @@ class Erasure:
         start_block = offset // self.block_size
         end_block = (offset + length - 1) // self.block_size
         written = 0
-        pool = _io_pool()
         # shard indices that failed mid-stream (bitrot/IO): shared with
         # the caller so the read path can queue a heal — a masked
         # corruption must not stay invisible (reference parallelReader
@@ -1486,7 +1508,7 @@ class Erasure:
                 with stagestats.timed("decode", g * self.block_size):
                     got, arena = self._read_group(
                         readers, broken, block_idx * shard_len,
-                        g * shard_len, g, shard_len, pool, prefer,
+                        g * shard_len, g, shard_len, prefer,
                     )
                     flat = self._assemble_data(
                         got, arena, g, shard_len, self.block_size)
@@ -1506,7 +1528,7 @@ class Erasure:
                 with stagestats.timed("decode", cur_size):
                     got, arena = self._read_group(
                         readers, broken, block_idx * self.shard_size,
-                        shard_len, 1, shard_len, pool, prefer,
+                        shard_len, 1, shard_len, prefer,
                     )
                     block = self._assemble_data(
                         got, arena, 1, shard_len, cur_size).reshape(-1)
@@ -1532,7 +1554,6 @@ class Erasure:
             return
         if sum(1 for r in readers if r is not None) < self.k:
             raise errors.ErasureReadQuorum("not enough shards to heal")
-        pool = _io_pool()
         broken: set[int] = set()
         nblocks = -(-total_length // self.block_size) if total_length else 0
         full_blocks = total_length // self.block_size
@@ -1552,7 +1573,7 @@ class Erasure:
                 got, arena = self._read_group(
                     readers, broken, block_idx * self.shard_size,
                     g * shard_len if shard_len == self.shard_size else shard_len,
-                    g, shard_len, pool, rebuild=True,
+                    g, shard_len, rebuild=True,
                 )
             except errors.ErasureReadQuorum:
                 raise errors.ErasureReadQuorum("healing read quorum lost")

@@ -24,9 +24,8 @@ from minio_tpu.storage.xlmeta import (
     ChecksumInfo, ErasureInfo, FileInfo, ObjectPartInfo,
     find_file_info_in_quorum, new_version_id,
 )
-from minio_tpu.utils import deadline as deadline_mod
 from . import bitrot
-from .coding import BLOCK_SIZE_V2, Erasure, _io_pool
+from .coding import BLOCK_SIZE_V2, Erasure, io_submit
 from .objects import (
     ErasureObjects, ObjectInfo, PutObjectOptions, _HashingReader,
 )
@@ -307,8 +306,7 @@ class MultipartMixin:
 
         # parallel writer opens (serial was one O_DIRECT open + staging
         # setup per drive before the first encoded byte)
-        open_futs = [deadline_mod.ctx_submit(_io_pool(), open_writer, i)
-                     for i in range(n)]
+        open_futs = [io_submit(open_writer, i) for i in range(n)]
         open_errs: list[Exception | None] = [None] * n
         writers = []
         for i, f in enumerate(open_futs):
@@ -434,8 +432,7 @@ class MultipartMixin:
         majority = len(disks) // 2 + 1
         scanned = 0
         for lo in range(0, len(disks), 4):
-            futs = [deadline_mod.ctx_submit(_io_pool(), scan, d)
-                    for d in disks[lo: lo + 4]]
+            futs = [io_submit(scan, d) for d in disks[lo: lo + 4]]
             scanned += len(futs)
             for f in futs:
                 for num, pi in f.result().items():

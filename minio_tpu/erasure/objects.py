@@ -35,7 +35,7 @@ from minio_tpu.utils import tracing
 from minio_tpu.utils.hashing import hash_order
 from . import bitrot, stagestats
 from . import repair as repair_mod
-from .coding import BLOCK_SIZE_V2, Erasure, _io_pool, pipeline_enabled
+from .coding import BLOCK_SIZE_V2, Erasure, io_submit, pipeline_enabled
 
 SMALL_FILE_THRESHOLD = 128 << 10  # inline shards into xl.meta below this
 
@@ -242,10 +242,12 @@ class _LockCtx:
 
     def __enter__(self):
         self.lk = self.ns._get(self.key)
-        if self.write:
-            self.lk.acquire_write()
-        else:
-            self.lk.acquire_read()
+        # the lock's wait alone: what is done under it has its own stages
+        with stagestats.timed("ns_lock"):
+            if self.write:
+                self.lk.acquire_write()
+            else:
+                self.lk.acquire_read()
         return self
 
     def __exit__(self, *exc):
@@ -302,7 +304,7 @@ class _HashingReader(io.RawIOBase):
             with stagestats.timed("etag", len(view)):
                 self.md5.update(view)
 
-        fut = deadline_mod.ctx_submit(_io_pool(), run)
+        fut = io_submit(run)
         self._tail = fut
         return fut
 
@@ -554,8 +556,7 @@ class ErasureObjects:
                     raise errors.DiskNotFound(str(i))
                 return d.read_version(bucket, obj, version_id, read_data)
 
-            futs = {deadline_mod.ctx_submit(_io_pool(), read, i): i
-                    for i in range(n)}
+            futs = {io_submit(read, i): i for i in range(n)}
             budget = deadline_mod.current()
             bounded = budget is not None and budget.t_end is not None
             if not bounded and not hedge:
@@ -769,8 +770,7 @@ class ErasureObjects:
             # parallel writer opens: O_DIRECT open + staging-buffer setup
             # costs milliseconds per drive — serial, that is a full
             # drive-count round before the first byte is encoded
-            open_futs = [deadline_mod.ctx_submit(_io_pool(), open_writer, i)
-                         for i in range(n)]
+            open_futs = [io_submit(open_writer, i) for i in range(n)]
             writers = []
             try:
                 for f in open_futs:
@@ -917,7 +917,7 @@ class ErasureObjects:
         return ObjectInfo.from_file_info(fi, bucket, obj, opts.versioned)
 
     def _fan_out(self, fn: Callable[[int], None], idxs) -> list[Exception | None]:
-        # ctx_submit carries the request's deadline budget into the pool
+        # io_submit carries the request's deadline budget into the pool
         # threads so remote hops clamp their retries; writes still await
         # EVERY drive (quorum accounting needs all outcomes — only the
         # read path returns early).  Budget-free all-local fan-outs are
@@ -934,8 +934,7 @@ class ErasureObjects:
         group_ok = deadline_mod.current() is None and all(
             self.disks[i] is None or self.disks[i].is_local() for i in idxs)
         if not group_ok:
-            futs = {i: deadline_mod.ctx_submit(_io_pool(), fn, i)
-                    for i in idxs}
+            futs = {i: io_submit(fn, i) for i in idxs}
             for i, f in futs.items():
                 try:
                     f.result()
@@ -956,8 +955,7 @@ class ErasureObjects:
             return res
 
         groups = [idxs[lo: lo + step] for lo in range(0, len(idxs), step)]
-        futs = [(g, deadline_mod.ctx_submit(_io_pool(), run_group, g))
-                for g in groups]
+        futs = [(g, io_submit(run_group, g)) for g in groups]
         for g, f in futs:
             for i, err in zip(g, f.result()):
                 out[i] = err
@@ -1021,8 +1019,7 @@ class ErasureObjects:
             return {i: r for (i, _dr), r in zip(lst, res)}
 
         if groups:
-            futs = [(lst, deadline_mod.ctx_submit(
-                _io_pool(), run_batch, leader, lst))
+            futs = [(lst, io_submit(run_batch, leader, lst))
                 for leader, lst in groups]
             for lst, f in futs:
                 res = f.result()
@@ -1238,31 +1235,33 @@ class ErasureObjects:
                     return bitrot.BitrotReader(
                         fh, till, e.shard_size, algo=_bitrot_algo_of(fi))
 
-                # parallel opens: with injected +500 ms latency the cost
-                # is one round, not one round PER drive
-                open_futs = {i: deadline_mod.ctx_submit(
-                    _io_pool(), open_one, i) for i in open_set}
-                for i, f in open_futs.items():
-                    try:
-                        readers[i] = f.result()
-                    except Exception:
-                        heal_needed = True
-                        readers[i] = None
-                if sum(1 for i in open_set if readers[i] is not None) \
-                        < e.k:
-                    # fast opens fell short of k: the hedged-out slow
-                    # drives are the remaining sources — open them now
-                    rest = [i for i in fast + slow if i not in open_set]
-                    futs2 = {i: deadline_mod.ctx_submit(
-                        _io_pool(), open_one, i) for i in rest}
-                    for i, f in futs2.items():
+                def open_round(idxs) -> None:
+                    # parallel opens: with injected +500 ms latency the
+                    # cost is one round, not one round PER drive
+                    nonlocal heal_needed
+                    futs = {i: io_submit(open_one, i) for i in idxs}
+                    for i, f in futs.items():
                         try:
                             readers[i] = f.result()
                         except Exception:
                             heal_needed = True
                             readers[i] = None
-                    prefer = prefer + rest
-                else:
+
+                # what a part costs before its first group: this thread
+                # waiting for the pool to open the shard readers
+                with stagestats.timed("open"):
+                    open_round(open_set)
+                    short = sum(1 for i in open_set
+                                if readers[i] is not None) < e.k
+                    if short:
+                        # fast opens fell short of k: the hedged-out
+                        # slow drives are the remaining sources — open
+                        # them now
+                        rest = [i for i in fast + slow
+                                if i not in open_set]
+                        open_round(rest)
+                        prefer = prefer + rest
+                if not short:
                     # hedged-out drives stay available as LAZY steal
                     # targets: nothing is opened (no latency paid) until
                     # a fast shard fails MID-STREAM and the decode

@@ -5,12 +5,18 @@ sinks.
 
 1. folds the interval into the process-wide per-stage counters:
    *thread-seconds* of work (summed over every thread that was inside
-   the stage, so overlapping threads add up past wall time), bytes, and
-   a *wall-time* union (the clock runs while at least one thread is
-   inside the stage).  server/metrics.py exports them as
-   `minio_dataplane_stage_{seconds,bytes,wall_seconds}_total{stage}`;
-   the benchmark's `program_counter` metrics (benchmark/readers/stage_*)
-   read the first two;
+   the stage, so overlapping threads add up past wall time), bytes, a
+   *wall-time* union (the clock runs while at least one thread is
+   inside the stage), and the *CPU seconds* of those threads inside it
+   (each thread's own CPU clock, `time.thread_time`, read beside the
+   wall clock: thread-seconds less CPU seconds is the time the stage
+   stood still, waiting for the interpreter lock, a core, a drive or a
+   socket; an estimate from one interval in `CPU_EVERY`, below).
+   server/metrics.py exports them as
+   `minio_dataplane_stage_{seconds,bytes,wall_seconds}_total{stage}`,
+   the CPU seconds as the row `<stage>_cpu` of the seconds family
+   (`seconds_rows`); the benchmark's `program_counter` metrics
+   (benchmark/readers/stage_*) read the seconds and the bytes;
 2. lies in the profiler's trace as `dp.<stage>` for the same interval
    (`jax.profiler.TraceAnnotation`), on the clock of the device's own
    lines, so a traced run can name what the host did while the chip was
@@ -33,16 +39,19 @@ hide them.  `PARENTS` enclose other stages (`decode` = read_wait +
 assemble + the codec's leaves on the stream's own thread; `encode` =
 host_codec, or h2d + launch + fetch): they keep their counters and their
 per-request seconds and write no span.  `add()` books a reading taken
-elsewhere (the admission wait, a compile's duration, bytes that arrived)
-into the counters alone; so does the body pipe for what it waited and
-worked inside `read`, once a call and not once a chunk, and `dp.read`
-stays the one span of a batch's body (thousands of chunk-long spans
-would name no gap and fill a request's tree).
+elsewhere (the admission wait, a compile's duration, bytes that arrived,
+a hop between two threads, which no one thread's span can hold) into
+the counters alone; so does the body pipe for what it waited and worked
+inside `read`, once a call and not once a chunk, and `dp.read` stays the
+one span of a batch's body (thousands of chunk-long spans would name no
+gap and fill a request's tree).  A stage that only `add()` books
+(`ADD_ONLY`) has no CPU seconds: nobody was inside it.
 """
 
 from __future__ import annotations
 
 import contextlib
+import random
 import sys
 import threading
 import time
@@ -115,8 +124,43 @@ STAGES = (
     # self-test has passed.  Counter only: 0 in a window that began
     # ready
     "warming",
+    # where a request stands still between stages (ISSUE 37).  The hops,
+    # counters only since each crosses threads: from the event loop's
+    # hand-over to the executor until the job's first line on an
+    # `s3-api` thread; from the job's last line there until the
+    # coroutine's next line on the loop (server/app.py _hop); from a
+    # task's submit to the shared I/O pool until its first line on a
+    # `shard-io` thread, once a task (erasure/coding.py io_submit)
+    "exec_wait", "loop_wait", "pool_wait",
+    # leaves: the namespace lock's wait (erasure/objects.py _LockCtx);
+    # the round that opens a part's shard readers, before the first
+    # group; a PUT's own thread waiting for the pool's shard writes
+    # (encode_stream under slot pressure and its last drain: read_wait's
+    # mirror)
+    "ns_lock", "open", "write_wait",
+    # the handler's whole time, admission included, with the bytes of
+    # the request's and the response's bodies (server/app.py _handle):
+    # what the per-request stages are subtracted from
+    "request",
 )
-PARENTS = frozenset(("encode", "decode"))
+PARENTS = frozenset(("encode", "decode", "request"))
+# booked by add() alone: readings taken elsewhere, with no thread inside
+ADD_ONLY = frozenset((
+    "staged", "batch_fill", "block_reuse", "admit", "body_wait",
+    "body_copy", "compile", "compile_wait", "warming", "exec_wait",
+    "loop_wait", "pool_wait", "request"))
+# booked by timed(): the leaves and the parents that enclose them
+TIMED = tuple(s for s in STAGES if s not in ADD_ONLY)
+
+# The CPU clock is read around one interval in CPU_EVERY, drawn at
+# random, and what it reads counts CPU_EVERY-fold: an unbiased estimate
+# that adds up over scrapes.  A thread's CPU clock has no fast path on
+# the chip's sandboxed host: 6.2 us a read against 0.09 for the wall
+# clock, in 10 ms ticks, and read around every interval it cost the
+# served path 4-13% end to end (PERF.md section 6, PR 37).  Over a
+# window's thousands of intervals the estimate is good to 10-20%; it
+# says nothing of one interval.
+CPU_EVERY = 16
 
 _lock = threading.Lock()
 _seconds = {s: 0.0 for s in STAGES}
@@ -124,6 +168,10 @@ _bytes = {s: 0 for s in STAGES}
 _wall = {s: 0.0 for s in STAGES}
 _inside = {s: 0 for s in STAGES}    # threads inside the stage now
 _since = {s: 0.0 for s in STAGES}   # when _inside last left 0
+_cpu = {s: 0.0 for s in TIMED}      # CPU seconds of the threads inside
+# threads whose whole CPU time is a row of the seconds family:
+# {row: the thread's CPU clock id}
+_watched: dict[str, int] = {}
 
 # what a leaf is called in the profiler's trace and in a request's tree
 _SPAN_NAMES = {s: "dp." + s for s in STAGES if s not in PARENTS}
@@ -161,7 +209,7 @@ class timed:
     """`with timed("write", n): ...` — one interval into all three sinks
     (module docstring)."""
 
-    __slots__ = ("stage", "nbytes", "_t0", "_span")
+    __slots__ = ("stage", "nbytes", "_t0", "_c0", "_span")
 
     def __init__(self, stage: str, nbytes: int = 0):
         self.stage = stage
@@ -178,14 +226,19 @@ class timed:
             if not _inside[stage]:
                 _since[stage] = t0
             _inside[stage] += 1
+        self._c0 = time.thread_time() \
+            if random.random() * CPU_EVERY < 1.0 else None
         return self
 
     def __exit__(self, *exc) -> bool:
         stage = self.stage
+        cpu = 0.0 if self._c0 is None \
+            else (time.thread_time() - self._c0) * CPU_EVERY
         t1 = time.perf_counter()
         dt = t1 - self._t0
         with _lock:
             _seconds[stage] += dt
+            _cpu[stage] += cpu
             _bytes[stage] += self.nbytes
             _inside[stage] -= 1
             if not _inside[stage]:
@@ -201,12 +254,39 @@ class timed:
 
 
 def snapshot() -> dict[str, dict[str, float]]:
-    """{stage: {"seconds": thread-seconds, "bytes": n, "wall": s}} —
-    copied under the lock so a metrics render never sees a half-updated
-    row.  An interval counts once it has ended."""
+    """{stage: {"seconds": thread-seconds, "bytes": n, "wall": s}, and
+    "cpu": estimated CPU seconds for a stage `timed()` books} — copied
+    under the lock so a metrics render never sees a half-updated row.
+    An interval counts once it has ended."""
     with _lock:
-        return {s: {"seconds": _seconds[s], "bytes": _bytes[s],
+        snap = {s: {"seconds": _seconds[s], "bytes": _bytes[s],
                     "wall": _wall[s]} for s in STAGES}
+        for s, cpu in _cpu.items():
+            snap[s]["cpu"] = cpu
+    return snap
+
+
+def watch_thread(row: str, ident: int) -> None:
+    """From now on `seconds_rows()[row]` is the CPU seconds of thread
+    `ident` (the event loop's: one thread that every request crosses and
+    no stage is inside of)."""
+    _watched[row] = time.pthread_getcpuclockid(ident)
+
+
+def seconds_rows(snap: dict | None = None) -> dict[str, float]:
+    """Every row of the seconds family by its `stage` label: each
+    stage's thread-seconds, `<stage>_cpu` for each stage `timed()` books
+    (none for one that `add()` alone books), and the watched threads'
+    CPU clocks, read now."""
+    snap = snapshot() if snap is None else snap
+    rows = {s: d["seconds"] for s, d in snap.items()}
+    rows.update((s + "_cpu", snap[s]["cpu"]) for s in TIMED)
+    for row, clock in list(_watched.items()):
+        try:
+            rows[row] = time.clock_gettime(clock)
+        except OSError:  # the thread has ended: no row
+            pass
+    return rows
 
 
 def delta(before: dict, after: dict) -> dict[str, float]:

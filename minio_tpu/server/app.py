@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import asyncio
 import base64
+import contextvars
 import functools
 import hashlib
 import io
@@ -21,6 +22,7 @@ import os
 import queue as queue_mod
 import re
 import secrets
+import threading
 import time
 import urllib.parse
 import xml.etree.ElementTree as ET
@@ -600,6 +602,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         log.init_audit(queue_dir=os.path.join(os.path.dirname(eq), "audit")
                        if eq else None, config=self.config)
         self.app = web.Application(client_max_size=1 << 30)
+        self.app.on_startup.append(self._watch_loop)
         self.init_metrics()
         # fixed-prefix routes (admin + metrics/health) win over the S3
         # catch-alls
@@ -615,6 +618,14 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         self.app.router.add_route("*", "/", self.dispatch_root)
         self.app.router.add_route("*", "/{bucket}", self.dispatch_bucket)
         self.app.router.add_route("*", "/{bucket}/{key:.*}", self.dispatch_object)
+
+    @staticmethod
+    async def _watch_loop(app) -> None:
+        """Runs on the event loop's thread as it starts to serve: that
+        thread's CPU clock is the row `loop_cpu` of the stage seconds
+        (one loop feeds every PUT's body and answers every small
+        request; near 1 s a second it is full)."""
+        stagestats.watch_thread("loop_cpu", threading.get_ident())
 
     def _emit(self, name, bucket: str, key: str, *, size: int = 0,
               etag: str = "", version_id: str = "", request=None) -> None:
@@ -829,15 +840,38 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
             raise S3Error("XMinioAdminBucketQuotaExceeded", resource=bucket)
 
     # ------------------------------------------------------------------ util
+    async def _hop(self, ctx, job):
+        """`job()` on an `s3-api` thread under the copied context `ctx`
+        (run_in_executor alone drops contextvars), with the hop's two
+        waits booked as stages: `exec_wait`, from the hand-over to the
+        executor until the job's first line on its thread, and
+        `loop_wait`, from its last line there until this coroutine's
+        next line on the loop.  Both are booked inside the request's
+        context, so a captured trace shows them."""
+        loop = asyncio.get_running_loop()
+        done = 0.0
+        t0 = time.perf_counter()
+
+        def on_thread():
+            nonlocal done
+            stagestats.add("exec_wait", time.perf_counter() - t0)
+            try:
+                return job()
+            finally:
+                done = time.perf_counter()
+
+        try:
+            return await loop.run_in_executor(
+                self.executor, lambda: ctx.run(on_thread))
+        finally:
+            if done:  # else cancelled here before the job's end
+                stagestats.add("loop_wait", time.perf_counter() - done)
+
     async def _run(self, fn, *args, **kw):
         # copy_context carries the request's deadline budget into the
-        # executor thread (run_in_executor alone drops contextvars)
-        import contextvars
-
-        loop = asyncio.get_running_loop()
-        ctx = contextvars.copy_context()
-        return await loop.run_in_executor(
-            self.executor, lambda: ctx.run(fn, *args, **kw))
+        # executor thread
+        return await self._hop(contextvars.copy_context(),
+                               functools.partial(fn, *args, **kw))
 
     async def _run_nobudget(self, fn, *args, **kw):
         """_run WITHOUT the request's deadline budget: body streaming and
@@ -850,12 +884,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         trace (utils/tracing.py): a whole-payload phase is budget-free
         by contract but its time must still be attributable, so the
         copied context runs with ONLY the Budget var cleared."""
-        import contextvars
-
         from minio_tpu.utils import deadline as deadline_mod
-
-        loop = asyncio.get_running_loop()
-        ctx = contextvars.copy_context()
 
         def nobudget():
             token = deadline_mod.set_current(None)
@@ -864,8 +893,7 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
             finally:
                 deadline_mod.reset(token)
 
-        return await loop.run_in_executor(self.executor,
-                                          lambda: ctx.run(nobudget))
+        return await self._hop(contextvars.copy_context(), nobudget)
 
     async def _pump_stream(self, resp: web.StreamResponse, stream,
                            request: web.Request) -> None:
@@ -1479,8 +1507,11 @@ class S3Server(BucketMetaHandlers, ObjectExtraHandlers, SSEMixin, AdminMixin,
         finally:
             dt = time.monotonic() - t0
             self._m_inflight.dec()
-            self.record_api(api, status, dt,
-                            rx=request.content_length or 0, tx=tx)
+            rx = request.content_length or 0
+            self.record_api(api, status, dt, rx=rx, tx=tx)
+            # the handler's whole time, admission included: what the
+            # per-request stages are subtracted from
+            stagestats.add("request", dt, rx + tx)
             if slo is not None:
                 # outcome vs the class objective; the tenant label (QoS
                 # on) buys the per-tenant split in /minio/admin/v3/slo

@@ -227,7 +227,9 @@ class MetricsMixin:
             snap = stagestats.snapshot()
             srows = ["# HELP minio_dataplane_stage_seconds_total "
                      "Thread-seconds of work per object data-plane "
-                     "pipeline stage",
+                     "pipeline stage; <stage>_cpu: of those, the "
+                     "seconds on a CPU; loop_cpu: the event loop "
+                     "thread's CPU seconds",
                      "# TYPE minio_dataplane_stage_seconds_total gauge"]
             brows = ["# HELP minio_dataplane_stage_bytes_total Bytes "
                      "processed per object data-plane pipeline stage",
@@ -237,10 +239,13 @@ class MetricsMixin:
                      "inside the pipeline stage",
                      "# TYPE minio_dataplane_stage_wall_seconds_total "
                      "gauge"]
+            srows += ["minio_dataplane_stage_seconds_total"
+                      f"{_fmt_labels(('stage',), (row,))} "
+                      f"{round(seconds, 6)}"
+                      for row, seconds
+                      in stagestats.seconds_rows(snap).items()]
             for stage, d in snap.items():
                 lbl = _fmt_labels(("stage",), (stage,))
-                srows.append("minio_dataplane_stage_seconds_total"
-                             f"{lbl} {round(d['seconds'], 6)}")
                 brows.append("minio_dataplane_stage_bytes_total"
                              f"{lbl} {int(d['bytes'])}")
                 wrows.append("minio_dataplane_stage_wall_seconds_total"

@@ -1,6 +1,7 @@
 """ISSUE 26: the stage timer as the one leaf-span primitive
 (erasure/stagestats.py): counters, the profiler's trace, the request
-tree.
+tree.  ISSUE 37: a stage's seconds on a CPU beside its seconds on the
+clock, and the places where a request waits between the stages.
 
 One PUT and one degraded GET of a 16 MiB object on a 2+2 set run once
 under `jax.profiler` (host codec); a second, small stream runs through
@@ -10,8 +11,10 @@ counters' deltas and the captured request traces.  CPU only: no number
 here is a device number.
 """
 
+import concurrent.futures as cf
 import io
 import os
+import re
 import shutil
 import sys
 import threading
@@ -32,14 +35,24 @@ from tests import device_codec
 
 # leaves a PUT + degraded GET reach on the host codec, and the three more
 # of a device dispatch
-HOST_PUT = ("read", "etag", "host_codec", "hash", "write", "commit")
-HOST_GET = ("meta_read", "read_wait", "shard_read", "verify", "assemble",
-            "host_codec", "respond")
+HOST_PUT = ("read", "etag", "host_codec", "hash", "write", "commit",
+            "ns_lock", "write_wait")
+HOST_GET = ("meta_read", "ns_lock", "open", "read_wait", "shard_read",
+            "verify", "assemble", "host_codec", "respond")
 DEVICE = ("h2d", "launch", "fetch")
 # and the one more of a GET that is served: its body written to the
 # connection's socket by the executor thread that pulled it (ISSUE 36)
 SERVED = ("send",)
+# the hops between threads and the handler's whole time (ISSUE 37):
+# counters only
+HOPS = ("exec_wait", "loop_wait", "pool_wait")
+# what an inline request's `request` is split into
+# (benchmark/metrics/unstaged_ms_per_op.json subtracts the same)
+INLINE = ("admit", "auth", "exec_wait", "loop_wait", "meta_read", "commit",
+          "ns_lock")
 OBJECT_BYTES = 16 << 20
+SECONDS_ROW = re.compile(
+    r'minio_dataplane_stage_seconds_total\{stage="(\w+)"\} (\S+)')
 
 
 def _delta(before: dict, after: dict) -> dict:
@@ -81,27 +94,49 @@ def _device_stream(tmp_path) -> dict:
         device_codec.unplant(k, m)
 
 
-def _served_get(root) -> dict:
-    """One object of two full blocks and a tail PUT and fetched over
-    HTTP -> the GET's captured request trace."""
+def _captured(api: str, holding: str) -> dict:
+    """The captured request trace of `api` that holds the stage."""
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        docs = [d for d in tracing.store.snapshot()
+                if d["name"] == api and holding in d["stages"]]
+        if docs:
+            return docs[0]
+        time.sleep(0.02)
+    raise AssertionError(f"no {api} trace with {holding} was captured")
+
+
+def _served(root) -> types.SimpleNamespace:
+    """One object of two full blocks and a tail, and one inline object,
+    PUT and fetched over HTTP -> the captured request traces of the
+    large GET and the inline pair, and the seconds family's rows in a
+    scrape before and a scrape after them."""
     from tests.s3_harness import S3TestServer
 
-    body = np.random.default_rng(36).integers(
-        0, 256, (2 << 20) + 36, dtype=np.uint8).tobytes()
+    rng = np.random.default_rng(36)
+    body = rng.integers(0, 256, (2 << 20) + 36, dtype=np.uint8).tobytes()
+    inline = rng.integers(0, 256, 37 << 10, dtype=np.uint8).tobytes()
     srv = S3TestServer(str(root))
+
+    def scrape() -> dict:
+        text = srv.request("GET", "/minio/v2/metrics/cluster").body
+        return {row: float(v)
+                for row, v in SECONDS_ROW.findall(text.decode())}
+
     try:
         assert srv.request("PUT", "/bkt").status == 200
+        before = scrape()
         assert srv.request("PUT", "/bkt/served", data=body).status == 200
         tracing.store.clear()
         assert srv.request("GET", "/bkt/served").body == body
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            docs = [d for d in tracing.store.snapshot()
-                    if "dp.respond" in {s["name"] for s in d["spans"]}]
-            if docs:
-                return docs[0]
-            time.sleep(0.02)
-        raise AssertionError("the served GET's trace was not captured")
+        served = _captured("get_object", "respond")
+        tracing.store.clear()
+        assert srv.request("PUT", "/bkt/inline", data=inline).status == 200
+        assert srv.request("GET", "/bkt/inline").body == inline
+        return types.SimpleNamespace(
+            doc=served, inline_put=_captured("put_object", "request"),
+            inline_get=_captured("get_object", "request"),
+            before=before, after=scrape())
     finally:
         srv.close()
 
@@ -116,6 +151,9 @@ def traced(tmp_path_factory):
     mp.setenv("MINIO_TPU_ERASURE_BACKEND", "host")
     mp.setenv("MINIO_TPU_TRACE", "1")
     mp.setenv("MINIO_TPU_TRACE_SLOW_MS", "0")
+    # the CPU clock around every interval, not one in sixteen: a few
+    # requests have to show their CPU seconds
+    mp.setattr(stagestats, "CPU_EVERY", 1)
     disks = instrument([LocalStorage(str(tmp / f"d{i}")) for i in range(4)])
     for d in disks:
         d.make_volume("bkt")
@@ -141,7 +179,7 @@ def traced(tmp_path_factory):
         get_doc = tracing.end_request(root)
         get_stages = _delta(before, stagestats.snapshot())
         device_stages = _device_stream(tmp)
-        served_doc = _served_get(tmp / "served")
+        served = _served(tmp / "served")
     finally:
         jax.profiler.stop_trace()
         mp.undo()
@@ -150,7 +188,8 @@ def traced(tmp_path_factory):
         bench_trace.find_xplane(str(tmp / "trace")))
     return types.SimpleNamespace(
         host_spans={name for name, _, _ in events["host"]},
-        put_doc=put_doc, get_doc=get_doc, served_doc=served_doc,
+        put_doc=put_doc, get_doc=get_doc, served_doc=served.doc,
+        served=served,
         get_stages=get_stages, device_stages=device_stages,
         total=stagestats.snapshot())
 
@@ -165,12 +204,16 @@ def test_leaf_lies_in_the_profile_and_counts(traced, stage):
     assert 0 < row["wall"] <= row["seconds"] + 1e-9
 
 
-@pytest.mark.parametrize("stage", sorted(stagestats.PARENTS) + ["compile"])
-def test_parent_and_compile_write_no_span(traced, stage):
+@pytest.mark.parametrize(
+    "stage", sorted(stagestats.PARENTS) + ["compile"] + list(HOPS))
+def test_parent_compile_and_hop_write_no_span(traced, stage):
     """A span around other spans would take every idle gap's name; a
-    span named compile would count as a compilation."""
+    span named compile would count as a compilation; a hop between two
+    threads lies on neither's line."""
     assert f"dp.{stage}" not in traced.host_spans
     assert stage in stagestats.STAGES  # a counter all the same
+    if stage != "compile":
+        assert traced.total[stage]["seconds"] > 0
 
 
 def test_decode_is_booked_though_it_has_no_span(traced):
@@ -187,7 +230,9 @@ def test_span_names_honour_the_benchmarks_readers(traced):
         assert name != serve.MARK
         assert len(name) <= 80 and " = " not in name
     # the scrape of benchmark/server.py reads stage labels as \w+
-    assert all(s.isidentifier() for s in stagestats.STAGES)
+    rows = stagestats.seconds_rows()
+    assert set(stagestats.STAGES) < set(rows)
+    assert all(row.isidentifier() for row in rows)
 
 
 @pytest.mark.parametrize("path,leaves", [
@@ -233,9 +278,10 @@ def test_captured_request_holds_leaf_spans(traced, doc, leaves):
             assert 0 <= s["t0"] <= root["dur"] + 1e-3
 
 
-def test_wall_time_union_under_threads():
+def test_wall_time_union_under_threads(monkeypatch):
     """More threads than cores inside one stage: the union never passes
     the thread-seconds, counts an overlap once and leaves nobody inside."""
+    monkeypatch.setattr(stagestats, "CPU_EVERY", 1)
     before = stagestats.snapshot()["verify"]
     start = threading.Barrier(8)
 
@@ -267,6 +313,9 @@ def test_wall_time_union_under_threads():
     wall = after["wall"] - before["wall"]
     assert seconds >= 8 * 0.02
     assert 0.02 <= wall <= min(seconds, elapsed) + 1e-9
+    # no interval's CPU seconds lost or counted twice: each is read
+    # inside its wall interval
+    assert 0 < after["cpu"] - before["cpu"] <= seconds
 
 
 def test_compile_seconds_are_a_counter():
@@ -304,3 +353,129 @@ def test_scrape_shows_the_wall_family(traced):
                       "send"):
             assert (f'minio_dataplane_stage_{family}_total'
                     f'{{stage="{stage}"}} ') in text
+
+
+def _spin(cpu_seconds: float) -> None:
+    end = time.thread_time() + cpu_seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("inside,least,most", [
+    # a loop that never leaves the CPU of its own accord: all of its
+    # CPU seconds, which are no more than its seconds on the clock (how
+    # many more those are is the box's other processes taking turns)
+    (_spin, 0.19, None),
+    # a sleep books next to none
+    (time.sleep, 0.0, 0.02),
+])
+def test_cpu_seconds_beside_wall_seconds(monkeypatch, inside, least, most):
+    monkeypatch.setattr(stagestats, "CPU_EVERY", 1)
+    before = stagestats.snapshot()["verify"]
+    with stagestats.timed("verify"):
+        inside(0.2)
+    after = stagestats.snapshot()["verify"]
+    seconds = after["seconds"] - before["seconds"]
+    cpu = after["cpu"] - before["cpu"]
+    assert seconds >= 0.2
+    assert least <= cpu <= (seconds + 1e-3 if most is None else most)
+
+
+def test_cpu_seconds_are_an_estimate_from_one_interval_in_sixteen():
+    """800 intervals of half a millisecond on a CPU: about fifty are
+    read, each counts sixteen-fold, and the sum is near the 0.4 s (four
+    standard deviations of the draw either way)."""
+    assert stagestats.CPU_EVERY == 16
+    before = stagestats.snapshot()["pad"]["cpu"]
+    for _ in range(800):
+        with stagestats.timed("pad"):
+            _spin(0.0005)
+    cpu = stagestats.snapshot()["pad"]["cpu"] - before
+    assert 0.15 <= cpu <= 0.8
+
+
+def test_cpu_rows_only_where_threads_are_inside(traced):
+    """`<stage>_cpu` for every stage timed() books, the parents among
+    them; none for a stage that add() alone books."""
+    rows = set(traced.served.after)
+    assert {s + "_cpu" for s in stagestats.TIMED} <= rows
+    assert not {s + "_cpu" for s in stagestats.ADD_ONLY} & rows
+    assert set(stagestats.TIMED) | stagestats.ADD_ONLY \
+        == set(stagestats.STAGES)
+    assert {"encode", "decode"} <= set(stagestats.TIMED)
+    assert len(stagestats.TIMED) == 23
+    # and the one watched thread's, while that thread lives
+    assert rows - set(stagestats.seconds_rows()) <= {"loop_cpu"} < rows
+    snap = stagestats.snapshot()
+    assert all(("cpu" in snap[s]) == (s in stagestats.TIMED) for s in snap)
+
+
+def test_scrape_holds_the_waits_and_the_loops_cpu(traced):
+    before, after = traced.served.before, traced.served.after
+    for row in HOPS + ("ns_lock", "open", "write_wait", "request",
+                       "meta_read_cpu", "commit_cpu", "send_cpu"):
+        assert after[row] > before[row], row
+    # the event loop's thread answered five requests between the scrapes
+    assert after["loop_cpu"] > before["loop_cpu"] > 0
+    # a served request cannot have waited longer than it took
+    assert after["exec_wait"] - before["exec_wait"] \
+        < after["request"] - before["request"]
+
+
+def test_pool_wait_is_the_queue_for_a_pool_thread(monkeypatch):
+    pool = cf.ThreadPoolExecutor(max_workers=1)
+    monkeypatch.setattr(coding, "_shared_pool", pool)
+    try:
+        before = stagestats.snapshot()["pool_wait"]["seconds"]
+        release = threading.Event()
+        held = coding.io_submit(release.wait, 10)
+        queued = coding.io_submit(lambda a, b: a + b, 3, 4)
+        time.sleep(0.1)
+        release.set()
+        assert queued.result(10) == 7 and held.result(10)
+        waited = stagestats.snapshot()["pool_wait"]["seconds"] - before
+    finally:
+        pool.shutdown()
+    assert 0.1 <= waited < 5
+
+
+def test_ns_lock_is_the_locks_wait():
+    from minio_tpu.erasure.objects import NamespaceLock
+
+    ns = NamespaceLock()
+    before = stagestats.snapshot()["ns_lock"]
+    got = threading.Event()
+
+    def reader():
+        with ns.read("bkt/obj"):
+            got.set()
+
+    parked = threading.Thread(target=reader)
+    with ns.write("bkt/obj"):
+        parked.start()
+        deadline = time.monotonic() + 10
+        while not stagestats._inside["ns_lock"] \
+                and time.monotonic() < deadline:
+            time.sleep(0.001)
+        time.sleep(0.1)
+        assert not got.is_set()
+    parked.join(timeout=10)
+    assert got.is_set()
+    after = stagestats.snapshot()["ns_lock"]
+    seconds = after["seconds"] - before["seconds"]
+    assert 0.1 <= seconds < 15
+    # parked, not spinning
+    assert after["cpu"] - before["cpu"] < 0.5 * seconds
+
+
+@pytest.mark.parametrize("doc", ["inline_put", "inline_get"])
+def test_inline_request_closes_on_its_stages(traced, doc):
+    """`request` is the handler's whole time, so what the handler, its
+    hops and the object layer name of an inline request is no more than
+    it, and no small part of it (loose: no test of the box)."""
+    stages = getattr(traced.served, doc)["stages"]
+    assert all(s in stages for s in ("request", "exec_wait", "loop_wait",
+                                     "ns_lock", "admit", "auth"))
+    named = sum(stages.get(s, 0.0) for s in INLINE)
+    assert 0.1 * stages["request"] <= named <= stages["request"]
+    assert ("commit" if doc == "inline_put" else "meta_read") in stages
