@@ -11,6 +11,7 @@ Reads must be shard-size aligned; each block is verified on read
 
 from __future__ import annotations
 
+import errno
 import hashlib
 import io
 import os
@@ -198,14 +199,17 @@ class BitrotReader:
         self.algo = algo
         self._hash, self._hsize = hasher_of(algo)
 
-    def _seek_to(self, offset: int) -> None:
+    def _file_offset(self, offset: int) -> int:
+        """Where logical `offset`'s frame starts in the file."""
         if offset % self.shard_size != 0:
             raise errors.InvalidArgument(
                 f"bitrot read offset {offset} not aligned to {self.shard_size}"
             )
+        return offset // self.shard_size * (self._hsize + self.shard_size)
+
+    def _seek_to(self, offset: int) -> None:
+        file_off = self._file_offset(offset)
         if self._pos != offset:
-            block_idx = offset // self.shard_size
-            file_off = block_idx * (self._hsize + self.shard_size)
             self.r.seek(file_off)
             self._pos = offset
 
@@ -284,6 +288,43 @@ class BitrotReader:
                 return None
         return hashes
 
+    def _read_native(self, offset: int, out: np.ndarray) -> bool:
+        """The frames from logical `offset` read, placed in `out` and
+        checked in one native call of the stream (`read_frames`: a local
+        shard file's descriptor, the interpreter lock let go once for the
+        group).  False, having read nothing, where the stream has no
+        such call, the native library is not there, the frames are not
+        HighwayHash-256's, or the file system refused O_DIRECT (then for
+        this stream from now on): the caller reads in Python."""
+        rf = None if getattr(self, "_no_native", False) \
+            or self.algo not in ("highwayhash256S", "highwayhash256") \
+            or not out.size else getattr(self.r, "read_frames", None)
+        if rf is None or not host.available():
+            return False
+        nblocks, block_len = out.shape
+        hashes = np.empty((nblocks, self._hsize), dtype=np.uint8)
+        file_off = self._file_offset(offset)
+        # until the rows are checked the stream stands nowhere the next
+        # read may rely on
+        self._pos = -1
+        with stagestats.timed("shard_read",
+                              nblocks * (self._hsize + block_len)):
+            try:
+                status, hash_ns = rf(file_off, hashes, out)
+            except OSError as e:
+                if e.errno != errno.EINVAL:
+                    raise
+                self._no_native = True
+                return False
+        if status == host.FRAMES_SHORT:
+            raise errors.FileCorrupt("bitrot: truncated frame group")
+        # the hash's own time, inside shard_read's interval
+        stagestats.add("verify", hash_ns * 1e-9, out.size)
+        if status == host.FRAMES_MISMATCH:
+            raise errors.FileCorrupt("bitrot: hash mismatch")
+        stagestats.add("native_read", 0.0, out.size)
+        return True
+
     def _verify(self, blocks: np.ndarray, hashes: np.ndarray) -> None:
         """One batched hash call over the (possibly strided) rows
         against their frames' hashes; FileCorrupt where any differs."""
@@ -319,9 +360,11 @@ class BitrotReader:
         With `out`, a (nblocks, block_len) uint8 array whose rows are
         contiguous (any row stride: one shard's column of a dispatch's
         (B, K, S) batch), the rows are read where the caller wants them
-        and `out` is returned: no frame buffer, no copy (_read_rows).
-        Verified before the call returns like any other read; a stream
-        that can place no rows is read as without `out` and copied."""
+        and `out` is returned: no frame buffer, no copy: in one native
+        call where the stream has one (_read_native), else two readinto
+        calls a frame (_read_rows).  Verified before the call returns
+        like any other read; a stream that can place no rows is read as
+        without `out` and copied."""
         if out is not None:
             if (out.dtype != np.uint8 or out.shape != (nblocks, block_len)
                     or not out.flags.writeable
@@ -330,9 +373,13 @@ class BitrotReader:
                 raise ValueError(
                     f"read_blocks: out must be a writable ({nblocks}, "
                     f"{block_len}) uint8 array of contiguous rows")
-            hashes = self._read_rows(offset, out)
-            if hashes is not None:
-                self._verify(out, hashes)
+            placed = self._read_native(offset, out)
+            if not placed:
+                hashes = self._read_rows(offset, out)
+                if hashes is not None:
+                    self._verify(out, hashes)
+                    placed = True
+            if placed:
                 self._pos = offset + nblocks * block_len
                 stagestats.add("staged", 0.0, out.size)
                 return out

@@ -83,6 +83,11 @@ STAGES = (
     # between the read and the device (counter only, no seconds of its
     # own: shard_read and verify hold them)
     "staged",
+    # of those, the bytes that one native call a shard's group read,
+    # placed and checked (erasure/bitrot.py _read_native: a local shard
+    # file's descriptor); the rest took the Python reads.  Counter only:
+    # the call's seconds are shard_read's, its hash's also verify's
+    "native_read",
     # what exists only because a shard is no multiple of the kernel's
     # tile or k does not divide the block: zero columns added, made rows
     # cut back, a shard's fill dropped, with the bytes that passed.
@@ -146,9 +151,9 @@ STAGES = (
 PARENTS = frozenset(("encode", "decode", "request"))
 # booked by add() alone: readings taken elsewhere, with no thread inside
 ADD_ONLY = frozenset((
-    "staged", "batch_fill", "block_reuse", "admit", "body_wait",
-    "body_copy", "compile", "compile_wait", "warming", "exec_wait",
-    "loop_wait", "pool_wait", "request"))
+    "staged", "native_read", "batch_fill", "block_reuse", "admit",
+    "body_wait", "body_copy", "compile", "compile_wait", "warming",
+    "exec_wait", "loop_wait", "pool_wait", "request"))
 # booked by timed(): the leaves and the parents that enclose them
 TIMED = tuple(s for s in STAGES if s not in ADD_ONLY)
 

@@ -8,6 +8,8 @@ Provides:
   (reference: minio/highwayhash used at cmd/bitrot.go:55).
 - sock_send: a response body's piece written to its socket without the
   interpreter lock (server/app.py _BodySender).
+- read_frames: a group of a shard file's bitrot frames read, placed and
+  checked without the interpreter lock (storage/local.py shard streams).
 
 The library is built from the committed sources on the machine that
 loads it, on first use, into a file named by a content hash of those
@@ -88,6 +90,8 @@ def lib_path() -> str | None:
 def _load():
     # lint: allow(shared-state): per-process ctypes handle by design — each worker process must dlopen the codec itself
     global _lib, _lib_tried
+    if _lib is not None:  # loaded: no lock on the calls' path
+        return _lib
     with _lock:
         if _lib is not None or _lib_tried:
             return _lib
@@ -125,6 +129,13 @@ def _load():
             ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
             ctypes.POINTER(ctypes.c_int),
         ]
+        lib.frame_read.restype = ctypes.c_int
+        lib.frame_read.argtypes = [
+            ctypes.c_int, ctypes.c_int64, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.c_char_p, ctypes.c_void_p,
+        ]
         _lib = lib
         return _lib
 
@@ -156,6 +167,53 @@ def sock_send(fd: int, data, stall_ms: int) -> int:
     if err.value:
         raise OSError(err.value, os.strerror(err.value))
     return sent
+
+
+# read_frames statuses (csrc/frame_read.cpp)
+FRAMES_OK, FRAMES_SHORT, FRAMES_MISMATCH = 0, 1, 2
+_FRAMES_ERRNO = 3
+
+
+def read_frames(fd: int, offset: int, hashes: np.ndarray, out: np.ndarray,
+                bounce: np.ndarray | None = None, align: int = 4096
+                ) -> tuple[int, int, int, int, int]:
+    """Read the bitrot frames [32-byte hash | block] x n that start at
+    file offset `offset` of `fd` with the interpreter lock let go for the
+    whole of it: each hash into its row of `hashes` ((n, 32) uint8,
+    contiguous), each block into its row of `out` ((n, L) uint8, rows
+    contiguous, any row stride), then every block hashed with the bitrot
+    key (HighwayHash-256) and compared with its stored hash.
+
+    `bounce` None: a buffered descriptor, read straight into the rows,
+    its offset untouched.  Otherwise an O_DIRECT one: read at multiples
+    of `align` through `bounce` (aligned, a multiple of `align` long),
+    copied to the rows, and its offset left where the last read ended.
+
+    Returns (status, detail, hash_ns, bounce_off, bounce_have): status
+    FRAMES_OK; FRAMES_SHORT where the file ends inside the group;
+    FRAMES_MISMATCH with detail the first frame whose hash differs;
+    hash_ns the time the hashing took; `bounce` holds the file's bytes
+    [bounce_off, bounce_off + bounce_have) (the last read's).  Raises
+    OSError where a read failed.  Only where `available()`."""
+    n, length = out.shape
+    if (out.dtype != np.uint8 or not out.flags.writeable or length < 1
+            or out.strides[1] != 1 or (n > 1 and out.strides[0] < length)
+            or hashes.dtype != np.uint8 or hashes.shape != (n, 32)
+            or not hashes.flags.c_contiguous or not hashes.flags.writeable
+            or (bounce is not None and (
+                bounce.dtype != np.uint8 or not bounce.flags.c_contiguous
+                or bounce.size < align or bounce.size % align))):
+        raise ValueError("read_frames: rows, hashes or bounce unfit")
+    info = np.zeros(4, dtype=np.int64)
+    status = _load().frame_read(
+        fd, offset, n, length, hashes.ctypes.data, out.ctypes.data,
+        out.strides[0], None if bounce is None else bounce.ctypes.data,
+        0 if bounce is None else bounce.size, align, MAGIC_HH256_KEY,
+        info.ctypes.data)
+    if status == _FRAMES_ERRNO:
+        err = int(info[0])
+        raise OSError(err, os.strerror(err))
+    return status, int(info[0]), int(info[1]), int(info[2]), int(info[3])
 
 
 # Column tile for the pure-numpy GF(2^8) fallback matmul: one tile of

@@ -23,6 +23,7 @@ from typing import BinaryIO, Iterator
 
 import numpy as _np
 
+from minio_tpu.ops import host
 from minio_tpu.utils.deadline import service_thread
 
 from . import errors, metajournal
@@ -385,6 +386,36 @@ class _DirectReader:
             got += take
         return got
 
+    def read_frames(self, offset: int, hashes: _np.ndarray,
+                    out: _np.ndarray) -> tuple[int, int]:
+        """The bitrot frames from file `offset` read, placed and checked
+        in one native call (`ops/host.py` `read_frames`) through this
+        reader's aligned buffer, which keeps the call's last read (and
+        the descriptor's offset where that read ended, where `_refill`
+        goes on): the stream then stands where the group ends, or, where
+        the call failed, wherever the buffer says.  Returns (status,
+        hash_ns).  OSError where a read failed (EINVAL: the file system
+        refuses O_DIRECT)."""
+        try:
+            status, _, hash_ns, self._buf_off, self._have = \
+                host.read_frames(self._fd, offset, hashes, out,
+                                 _np.frombuffer(self._buf, dtype=_np.uint8),
+                                 _ALIGN)
+        except OSError:
+            # nothing in the buffer holds; go on from the failed read
+            self._buf_off = self._next_off = os.lseek(self._fd, 0,
+                                                      os.SEEK_CUR)
+            self._have = self._pos = 0
+            self._eof = self._final = False
+            raise
+        end = offset + out.shape[0] * (32 + out.shape[1])
+        self._pos = min(max(end - self._buf_off, 0), self._have)
+        self._next_off = self._buf_off + self._have
+        # a read that ended off the alignment ended at the file's end
+        self._final = self._have % _ALIGN != 0
+        self._eof = False
+        return status, hash_ns
+
     def close(self) -> None:
         if not self._closed:
             self._closed = True
@@ -397,6 +428,24 @@ class _DirectReader:
     def __exit__(self, *a):
         self.close()
         return False
+
+
+class _ShardFile(io.BufferedReader):
+    """A shard file opened buffered (a ranged read, or a drive whose file
+    system refuses O_DIRECT): a plain buffered reader that also reads a
+    group of bitrot frames in one native call."""
+
+    def read_frames(self, offset: int, hashes: _np.ndarray,
+                    out: _np.ndarray) -> tuple[int, int]:
+        """The bitrot frames from file `offset` read straight into their
+        rows, placed and checked in one native call (`ops/host.py`
+        `read_frames`); the stream then stands where the group ends.
+        Returns (status, hash_ns)."""
+        status, _, hash_ns, _, _ = host.read_frames(
+            self.fileno(), offset, hashes, out)
+        if status == host.FRAMES_OK:
+            self.seek(offset + out.shape[0] * (32 + out.shape[1]))
+        return status, hash_ns
 
 
 def _stored_algo(fi: FileInfo) -> str:
@@ -821,7 +870,7 @@ class LocalStorage(StorageAPI):
                             f"{volume}/{path}: size {size} < {length}")
                 return f
         try:
-            f = open(p, "rb")
+            f = _ShardFile(io.FileIO(p, "rb"))
         except FileNotFoundError:
             raise errors.FileNotFound(f"{volume}/{path}")
         except IsADirectoryError:

@@ -28,6 +28,7 @@ from benchmark import serve
 from benchmark import trace as bench_trace
 from minio_tpu.erasure import bitrot, coding, stagestats
 from minio_tpu.erasure.objects import ErasureObjects
+from minio_tpu.ops import host
 from minio_tpu.storage.instrumented import instrument
 from minio_tpu.storage.local import LocalStorage
 from minio_tpu.utils import tracing
@@ -258,9 +259,17 @@ def test_owning_threads_leaves_close_on_decode(traced, path, leaves):
     ("served_doc", ("meta_read", "read_wait", "shard_read", "verify",
                     "assemble", "respond") + SERVED)])
 def test_captured_request_holds_leaf_spans(traced, doc, leaves):
+    # a degraded GET's staged reads check their frames inside one native
+    # call a shard (erasure/bitrot.py _read_native): the hash's seconds
+    # are booked as `verify`, inside `shard_read`'s span, with none of
+    # their own
+    native = doc == "get_doc" and host.available()
     doc = getattr(traced, doc)
     names = {s["name"] for s in doc["spans"]}
-    assert {f"dp.{s}" for s in leaves} <= names
+    spans = set(leaves) - {"verify"} if native else set(leaves)
+    assert {f"dp.{s}" for s in spans} <= names
+    if native:
+        assert "dp.verify" not in names
     assert not {"dp.decode", "dp.encode"} & names
     # per-request seconds stay, parents among them
     assert set(leaves) <= set(doc["stages"])
