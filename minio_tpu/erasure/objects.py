@@ -543,8 +543,9 @@ class ErasureObjects:
                            read_data: bool = False, hedge: bool = False
                            ) -> tuple[list[FileInfo | None], list[Exception | None]]:
         """`read_version` of every drive, until an answer can be elected
-        (the quorum read of `xl.meta`: stage `meta_read`)."""
-        with stagestats.timed("meta_read"):
+        (the quorum read of `xl.meta`: stage `meta_read`, whose bytes are
+        the lengths of the documents its answers were parsed from)."""
+        with stagestats.timed("meta_read") as span:
             disks = self.disks
             n = len(disks)
             fis: list[FileInfo | None] = [None] * n
@@ -567,6 +568,7 @@ class ErasureObjects:
                         fis[i] = f.result()
                     except Exception as e:
                         errs[i] = e
+                span.nbytes = sum(fi.xl_bytes for fi in fis if fi is not None)
                 return fis, errs
             # deadline-aware: return at quorum, abandon stragglers.  A
             # FileInfo must actually be ELECTABLE from the answers in hand
@@ -620,6 +622,7 @@ class ErasureObjects:
                 hedge_stats["abandoned"] += 1
             if pending:
                 tracing.event("read.stragglers_abandoned", count=len(pending))
+            span.nbytes = sum(fi.xl_bytes for fi in fis if fi is not None)
             return fis, errs
 
     def _quorum_info(self, bucket, obj, version_id="", read_data=False,

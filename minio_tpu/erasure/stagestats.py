@@ -116,8 +116,15 @@ STAGES = (
     # `respond`'s bytes 1 where every group of full blocks hit the pool,
     # 0 where none did or the objects have no full block
     "block_reuse",
-    # quorum write / read of xl.meta; signature + policy; admission wait
+    # quorum write / read of xl.meta (a read's bytes: the documents its
+    # answers were parsed from); signature + policy; admission wait
     "commit", "meta_read", "auth", "admit",
+    # one drive's xl.meta that `read_version` read in one native call
+    # (storage/local.py _read_meta): the call's own seconds, open to
+    # close, timed inside it, and the document's bytes; a process
+    # without the library books nothing.  Counter only: over
+    # `meta_read`'s bytes 1 where every answer took the call
+    "meta_native",
     # inside `read`, the HTTP front's body pipe (server/app.py
     # _QueuePipeReader; counters only, booked once a call): waiting for
     # the socket's next chunk; the pipe's own work, with the bytes it
@@ -161,7 +168,7 @@ PARENTS = frozenset(("encode", "decode", "request"))
 ADD_ONLY = frozenset((
     "staged", "native_read", "tail", "batch_fill", "block_reuse", "admit",
     "body_wait", "body_copy", "compile", "compile_wait", "warming",
-    "exec_wait", "loop_wait", "pool_wait", "request"))
+    "exec_wait", "loop_wait", "pool_wait", "request", "meta_native"))
 # booked by timed(): the leaves and the parents that enclose them
 TIMED = tuple(s for s in STAGES if s not in ADD_ONLY)
 

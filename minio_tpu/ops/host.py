@@ -10,6 +10,8 @@ Provides:
   interpreter lock (server/app.py _BodySender).
 - read_frames: a group of a shard file's bitrot frames read, placed and
   checked without the interpreter lock (storage/local.py shard streams).
+- read_file: a drive's xl.meta read whole without the interpreter lock
+  (storage/local.py read_xl, read_version).
 
 The library is built from the committed sources on the machine that
 loads it, on first use, into a file named by a content hash of those
@@ -136,6 +138,11 @@ def _load():
             ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
             ctypes.c_char_p, ctypes.c_void_p,
         ]
+        lib.file_read.restype = ctypes.c_int
+        lib.file_read.argtypes = [
+            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_void_p,
+        ]
         _lib = lib
         return _lib
 
@@ -214,6 +221,53 @@ def read_frames(fd: int, offset: int, hashes: np.ndarray, out: np.ndarray,
         err = int(info[0])
         raise OSError(err, os.strerror(err))
     return status, int(info[0]), int(info[1]), int(info[2]), int(info[3])
+
+
+# a thread's first buffer for read_file; a larger file grows it
+FILE_BUF_BYTES = 64 * 1024
+_file_tls = threading.local()
+
+
+class _FileBuf:
+    """One thread's read_file buffer and the call's info words, with the
+    addresses the call takes (read once, not on every call)."""
+
+    __slots__ = ("buf", "addr", "info", "info_addr")
+
+    def __init__(self, size: int):
+        self.buf = np.empty(size, dtype=np.uint8)
+        self.addr = self.buf.ctypes.data
+        self.info = np.zeros(2, dtype=np.int64)
+        self.info_addr = self.info.ctypes.data
+
+
+def read_file(path: str) -> tuple[memoryview, int]:
+    """The file at `path` read whole in one native call with the
+    interpreter lock let go for the whole of it (csrc/file_read.cpp:
+    open, fstat, the reads up to the size, close), into a buffer the
+    calling thread keeps; where fstat reports a file larger than the
+    buffer, the buffer grows and the call is made once more.
+
+    Returns (the file's bytes, a view into that buffer that holds until
+    the thread's next read_file; the call's own nanoseconds, both calls'
+    where it grew).  Raises OSError with the call's errno and `path`, of
+    the subclass Python's own `open` would raise (IsADirectoryError for
+    a directory).  Only where `available()`."""
+    fb = getattr(_file_tls, "fb", None)
+    if fb is None:
+        fb = _file_tls.fb = _FileBuf(FILE_BUF_BYTES)
+    call = _load().file_read
+    name = os.fsencode(path)
+    ns = 0
+    while True:
+        status = call(name, fb.addr, fb.buf.size, fb.info_addr)
+        got, took = fb.info.tolist()
+        ns += took
+        if status:
+            raise OSError(got, os.strerror(got), path)
+        if got <= fb.buf.size:
+            return memoryview(fb.buf)[:got], ns
+        fb = _file_tls.fb = _FileBuf(1 << (got - 1).bit_length())
 
 
 # Column tile for the pure-numpy GF(2^8) fallback matmul: one tile of

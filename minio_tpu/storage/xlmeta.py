@@ -94,6 +94,10 @@ class FileInfo:
     data: bytes | None = None
     fresh: bool = False
     idx: int = 0
+    # length of the drive's xl.meta this was parsed from (0 where it came
+    # another way, over the wire among them): the bytes of the quorum
+    # read's stage `meta_read`
+    xl_bytes: int = field(default=0, compare=False)
 
     def shard_file_size(self, part_size: int) -> int:
         assert self.erasure is not None
@@ -176,7 +180,7 @@ class XLMeta:
         )
 
     @classmethod
-    def loads(cls, raw: bytes) -> "XLMeta":
+    def loads(cls, raw: bytes | memoryview) -> "XLMeta":
         doc = msgpack.unpackb(raw, raw=False, strict_map_key=False)
         if doc.get("fmt") != XL_META_FORMAT:
             raise ValueError(f"unsupported xl.meta format {doc.get('fmt')}")
@@ -229,7 +233,7 @@ class XLMeta:
         return self.versions[0] if self.versions else None
 
 
-def file_info_from_raw(raw: bytes, volume: str, name: str,
+def file_info_from_raw(raw: bytes | memoryview, volume: str, name: str,
                        version_id: str = "", read_data: bool = False) -> FileInfo:
     xl = XLMeta.loads(raw)
     v = xl.find_version(version_id)
@@ -238,6 +242,7 @@ def file_info_from_raw(raw: bytes, volume: str, name: str,
         raise errors.FileVersionNotFound(f"{volume}/{name}@{version_id}")
     fi = FileInfo.from_obj(volume, name, v)
     fi.is_latest = xl.versions and xl.versions[0].get("v", "") == fi.version_id
+    fi.xl_bytes = len(raw)
     if not read_data:
         fi.data = None
     return fi
