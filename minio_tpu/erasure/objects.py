@@ -776,8 +776,9 @@ class ErasureObjects:
             open_futs = [io_submit(open_writer, i) for i in range(n)]
             writers = []
             try:
-                for f in open_futs:
-                    writers.append(f.result())
+                with stagestats.timed("open"):
+                    for f in open_futs:
+                        writers.append(f.result())
             except BaseException:
                 # a non-StorageError open (EACCES, MemoryError, ...)
                 # aborts the PUT: close the writers that DID open (raw
@@ -800,12 +801,15 @@ class ErasureObjects:
                     hreader, writers, size, write_quorum
                 )
             finally:
-                for w in writers:
-                    if w is not None:
-                        try:
-                            w.close()
-                        except Exception:
-                            pass
+                # one drive after another: each shard file's last flush
+                # and its fdatasync
+                with stagestats.timed("close"):
+                    for w in writers:
+                        if w is not None:
+                            try:
+                                w.close()
+                            except Exception:
+                                pass
             if size >= 0 and total_size != size:
                 self._cleanup_tmp(tmp_prefix)
                 raise errors.InvalidArgument(

@@ -153,11 +153,13 @@ STAGES = (
     # `shard-io` thread, once a task (erasure/coding.py io_submit)
     "exec_wait", "loop_wait", "pool_wait",
     # leaves: the namespace lock's wait (erasure/objects.py _LockCtx);
-    # the round that opens a part's shard readers, before the first
-    # group; a PUT's own thread waiting for the pool's shard writes
-    # (encode_stream under slot pressure and its last drain: read_wait's
-    # mirror)
-    "ns_lock", "open", "write_wait",
+    # the round that opens a part's shard files, readers before a GET's
+    # first group, writers before a PUT's; a PUT's own thread waiting
+    # for the pool's shard writes (encode_stream under slot pressure and
+    # its last drain: read_wait's mirror); a PUT's shard writers closed
+    # after its last group, on its own thread one drive after another:
+    # each file's last flush and its fdatasync
+    "ns_lock", "open", "write_wait", "close",
     # the handler's whole time, admission included, with the bytes of
     # the request's and the response's bodies (server/app.py _handle):
     # what the per-request stages are subtracted from

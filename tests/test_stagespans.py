@@ -37,7 +37,7 @@ from tests import device_codec
 # leaves a PUT + degraded GET reach on the host codec, and the three more
 # of a device dispatch
 HOST_PUT = ("read", "etag", "host_codec", "hash", "write", "commit",
-            "ns_lock", "write_wait")
+            "ns_lock", "write_wait", "open", "close")
 HOST_GET = ("meta_read", "ns_lock", "open", "read_wait", "shard_read",
             "verify", "assemble", "host_codec", "respond")
 DEVICE = ("h2d", "launch", "fetch")
@@ -413,7 +413,7 @@ def test_cpu_rows_only_where_threads_are_inside(traced):
     assert set(stagestats.TIMED) | stagestats.ADD_ONLY \
         == set(stagestats.STAGES)
     assert {"encode", "decode"} <= set(stagestats.TIMED)
-    assert len(stagestats.TIMED) == 23
+    assert len(stagestats.TIMED) == 24
     # and the one watched thread's, while that thread lives
     assert rows - set(stagestats.seconds_rows()) <= {"loop_cpu"} < rows
     snap = stagestats.snapshot()

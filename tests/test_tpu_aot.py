@@ -154,9 +154,12 @@ def compiled():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO
+    # a guard against a hung compiler, not a speed limit: the child
+    # compiles every program one after another on one core, 7.6 min
+    # alone on a shared 8-vCPU box
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__)], env=env, cwd=REPO,
-        capture_output=True, text=True, timeout=420)
+        capture_output=True, text=True, timeout=900)
     # no skip: a box whose libtpu cannot describe a v5e cannot vouch for
     # the kernels, and that must be seen
     assert proc.returncode == 0, (
